@@ -26,13 +26,6 @@ _DEVTYPE_TO_ID = {"cpu": 1, "gpu": 2, "cpu_pinned": 3, "cpu_shared": 5, "tpu": 6
 _ID_TO_DEVTYPE = {v: k for k, v in _DEVTYPE_TO_ID.items()}
 
 
-def _accelerator_platform() -> str:
-    try:
-        return jax.default_backend()
-    except Exception:  # pragma: no cover
-        return "cpu"
-
-
 _CACHE_WIRED = False
 
 
@@ -97,7 +90,7 @@ class Context:
         # gpu is an alias for tpu when the backend is a TPU; both resolve to
         # the same jax device, so they must compare equal.
         dt = self.device_type
-        if dt in ("gpu", "tpu") and _accelerator_platform() != "cpu":
+        if dt in ("gpu", "tpu") and jax.default_backend() != "cpu":
             dt = "accel"
         elif dt in ("cpu_pinned", "cpu_shared"):
             dt = "cpu"
@@ -115,7 +108,7 @@ class Context:
     # -- jax mapping ------------------------------------------------------
     @property
     def jax_device(self) -> jax.Device:
-        plat = _accelerator_platform()
+        plat = jax.default_backend()
         # device ids index PROCESS-LOCAL devices: under multi-process SPMD
         # (jax.distributed), jax.devices() spans all hosts and remote
         # entries are non-addressable from this process.
@@ -173,7 +166,7 @@ def gpu(device_id: int = 0) -> Context:
 
 def num_gpus() -> int:
     """Number of accelerator devices (reference: ``context.py:num_gpus``)."""
-    plat = _accelerator_platform()
+    plat = jax.default_backend()
     return 0 if plat == "cpu" else len(jax.local_devices())
 
 
@@ -186,7 +179,7 @@ def current_context() -> Context:
 
 
 def _default_ctx() -> Context:
-    return Context("tpu", 0) if _accelerator_platform() != "cpu" else Context("cpu", 0)
+    return Context("tpu", 0) if jax.default_backend() != "cpu" else Context("cpu", 0)
 
 
 class _LazyDefault(Context):

@@ -448,7 +448,13 @@ class Trainer:
         if any(g is None for g in grads):
             return no("gradient buffers not attached")
         idx = [self._param2idx[p.name] for p in active]
-        states = [self._restore_fused_state(name, p, i, h.data, rule_init)
+        # seeded states are committed to their weight's placement: a
+        # fresh zeros_like is uncommitted while the state the executable
+        # hands back is committed, and that difference alone is a second
+        # compile of the fused update at step 2 (same HLO, new signature)
+        states = [tuple(jax.device_put(leaf, h.data.sharding) for leaf in
+                        self._restore_fused_state(name, p, i, h.data,
+                                                  rule_init))
                   for p, i, h in zip(active, idx, handles)]
         has_clip = o.clip_gradient is not None
         # the in-graph grad-norm gauge reads the whole gradient set once
@@ -1349,7 +1355,7 @@ class Superstep:
                 o._index_update_count[ix] -= k
             o.num_update = prev_num_update
             if plan["warm"] or _is_execution_error(e):
-                # an EXECUTION failure (OOM, preemption, dead relay —
+                # an EXECUTION failure (OOM, preemption, lost device —
                 # warm or first run alike): donation may have consumed
                 # the live buffers, so surface it rather than silently
                 # single-stepping on possibly-dead handles

@@ -560,7 +560,7 @@ def waitall():
     live = [arr._data_ for arr in list(_LIVE)
             if arr._base is None and arr._data_ is not None]
     try:
-        # one batched sync (one relay round-trip for ALL live arrays)
+        # one batched sync for ALL live arrays
         engine.wait(live)
         return
     except Exception:

@@ -13,7 +13,7 @@ Instrumented hot paths (each behind ONE ``ENABLED`` boolean check):
 - ``kvstore/local.py`` / ``kvstore/dist.py`` — push/pull counts and
   bytes, allreduce latency, barrier count,
 - ``gluon/trainer.py`` — step count/latency spans, grad-norm gauge,
-- ``engine.py::wait`` — sync-probe latency, relay vs native path.
+- ``engine.py::wait`` — sync count and latency.
 
 Switch: ``MXTPU_TELEMETRY=1`` at process start, or
 ``observability.set_enabled(True)`` at runtime. Off by default: the

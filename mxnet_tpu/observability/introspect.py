@@ -152,14 +152,12 @@ def device_peaks():
 # ---------------------------------------------------------------------------
 
 def _cost_dict(compiled):
-    """Normalize ``compiled.cost_analysis()`` → dict or None (older JAX
-    returns a one-element list; some PJRT plugins return None/raise)."""
+    """``compiled.cost_analysis()`` → dict or None (some PJRT plugins
+    return None/raise)."""
     try:
         ca = compiled.cost_analysis()
     except Exception:
         return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
     return ca if isinstance(ca, dict) else None
 
 

@@ -24,6 +24,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .registry import register
 
@@ -31,12 +33,9 @@ _NEG_INF = -1e30
 
 
 def _use_pallas(d):
-    if d > 128:
-        return False
-    try:
-        return jax.default_backend() not in ("cpu",)
-    except Exception:  # pragma: no cover
-        return False
+    """Kernel path: head_dim within the MXU lane width, on any backend
+    but the CPU. A backend that fails to start raises here."""
+    return d <= 128 and jax.default_backend() != "cpu"
 
 
 # ---------------------------------------------------------------------------
@@ -164,15 +163,6 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0] = m_scr[:] + jnp.log(l)
 
 
-try:  # pallas import kept optional so CPU-only environments still import
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
-
-
 def _pallas_flash_fwd(q, k, v, scale, causal, bq=512, bk=512, window=0):
     B, H, T, D = q.shape
     KVH = k.shape[1]
@@ -248,7 +238,7 @@ def _pallas_ready(q, k, causal, block_size):
     divide; the q block is clamped to the TRUE sequence length so the
     flattened-group layout never straddles heads."""
     bq = min(block_size, q.shape[2])
-    return (_HAS_PALLAS and _use_pallas(q.shape[-1])
+    return (_use_pallas(q.shape[-1])
             and (not causal or q.shape[2] == k.shape[2])
             and q.shape[1] % k.shape[1] == 0
             and q.shape[2] % bq == 0
@@ -692,17 +682,18 @@ flash_attention_core.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 def _paged_decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
                          m_scr, l_scr, acc_scr, *, scale, bs, mb, kvh):
-    """One grid step per (sequence-band, kv block): grid
-    ``(B * KVH, max_blocks)``. The block tables and context lengths ride
-    the scalar-prefetch lane, so each step's K/V DMA source address is
-    ``tables[seq, j]`` — the pool block — and Mosaic double-buffers the
-    NEXT block's fetch against THIS block's compute (the explicit DMA
-    overlap the decode band structure exists for). Online softmax in
-    fp32 VMEM scratch, exactly the prefill kernel's recurrence with
-    q_len = group (the GQA query heads of one kv head)."""
-    i = pl.program_id(0)
+    """One grid step per (sequence, kv block): grid ``(B, max_blocks)``.
+    The block tables and context lengths ride the scalar-prefetch lane,
+    so each step's K/V DMA source address is ``tables[seq, j]`` — the
+    pool block, ALL kv heads of it: a ``(1, bs, KVH, D)`` window spans
+    the pool's last two dimensions, which is what Mosaic's tiling rule
+    asks of a block (a one-head ``(1, bs, 1, D)`` window is refused).
+    Mosaic double-buffers the NEXT block's fetch against THIS block's
+    compute. Online softmax in fp32 VMEM scratch per kv head, exactly
+    the prefill kernel's recurrence with q_len = group (the GQA query
+    heads of one kv head)."""
+    seq = pl.program_id(0)
     j = pl.program_id(1)
-    seq = i // kvh
     ctx = lens_ref[seq]
     col0 = j * bs
 
@@ -714,58 +705,53 @@ def _paged_decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(col0 < ctx)
     def _compute():
-        q = q_ref[0, 0]                                  # (group, d)
-        k = k_ref[0, :, 0, :]                            # (bs, d)
-        v = v_ref[0, :, 0, :]                            # (bs, d)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + col0
-        s = jnp.where(cols < ctx, s, _NEG_INF)
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = m_new
+        for h in range(kvh):
+            q = q_ref[0, h]                              # (group, d)
+            k = k_ref[0, :, h, :]                        # (bs, d)
+            v = v_ref[0, :, h, :]                        # (bs, d)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + col0
+            s = jnp.where(cols < ctx, s, _NEG_INF)
+            m_prev = m_scr[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[h] = m_new
 
     @pl.when(j == mb - 1)
     def _finish():
         l = jnp.maximum(l_scr[:], 1e-30)
-        o_ref[0, 0] = (acc_scr[:] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
 
 
-def _pallas_paged_decode(q, k_pool, v_pool, tables, lens, scale):
+def _pallas_paged_decode(q, k_pool, v_pool, tables, lens, scale,
+                         interpret=False):
     B, H, D = q.shape
     _, bs, KVH, _ = k_pool.shape
     mb = tables.shape[1]
     group = H // KVH
     qr = q.reshape(B, KVH, group, D)
+    q_spec = pl.BlockSpec((1, KVH, group, D),
+                          lambda i, j, tables, lens: (i, 0, 0, 0))
+    # the indirection: this grid step's K/V block is whichever POOL
+    # block the sequence's table names for logical block j
+    kv_spec = pl.BlockSpec((1, bs, KVH, D),
+                           lambda i, j, tables, lens: (tables[i, j], 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B * KVH, mb),
-        in_specs=[
-            pl.BlockSpec((1, 1, group, D),
-                         lambda i, j, tables, lens, _kvh=KVH:
-                         (i // _kvh, i % _kvh, 0, 0)),
-            # the indirection: this grid step's K/V block is whichever
-            # POOL block the sequence's table names for logical block j
-            pl.BlockSpec((1, bs, 1, D),
-                         lambda i, j, tables, lens, _kvh=KVH:
-                         (tables[i // _kvh, j], 0, i % _kvh, 0)),
-            pl.BlockSpec((1, bs, 1, D),
-                         lambda i, j, tables, lens, _kvh=KVH:
-                         (tables[i // _kvh, j], 0, i % _kvh, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, group, D),
-                               lambda i, j, tables, lens, _kvh=KVH:
-                               (i // _kvh, i % _kvh, 0, 0)),
+        grid=(B, mb),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, D), jnp.float32),
+            pltpu.VMEM((KVH, group, 1), jnp.float32),
+            pltpu.VMEM((KVH, group, 1), jnp.float32),
+            pltpu.VMEM((KVH, group, D), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -773,6 +759,7 @@ def _pallas_paged_decode(q, k_pool, v_pool, tables, lens, scale):
                           kvh=KVH),
         out_shape=jax.ShapeDtypeStruct((B, KVH, group, D), q.dtype),
         grid_spec=grid_spec,
+        interpret=interpret,
     )(tables, lens, qr, k_pool, v_pool)
     return out.reshape(B, H, D)
 
@@ -815,13 +802,14 @@ def paged_decode_attention(query, k_pool, v_pool, block_tables,
     positions are valid (rows past it — padding and the null block —
     are masked).
 
-    TPU path: one grid step per (sequence-band, kv block) with the
+    TPU path: one grid step per (sequence, kv block) with the
     tables/lengths scalar-prefetched so the index map itself performs
     the block indirection and Mosaic overlaps the next block's DMA with
-    the current block's compute (``PrefetchScalarGridSpec``). GQA is
-    native: the band is a kv head, its ``H/KVH`` query heads form the
-    q-block rows, so each K/V block is fetched once per group. CPU/
-    debug path: the same math via a plain gather (the test oracle).
+    the current block's compute (``PrefetchScalarGridSpec``). A step
+    fetches all kv heads of its pool block; GQA is native: each kv
+    head's ``H/KVH`` query heads form the q rows, so each K/V block is
+    fetched once per group. CPU/debug path: the same math via a plain
+    gather (the test oracle).
 
     Sequences with ``context_lens == 0`` (empty batch slots) return
     zeros. Grows O(1) per generated token — no T×S score matrix, no
@@ -834,7 +822,7 @@ def paged_decode_attention(query, k_pool, v_pool, block_tables,
                          f"{query.shape[1]} vs {k_pool.shape[2]}")
     tables = block_tables.astype(jnp.int32)
     lens = context_lens.astype(jnp.int32)
-    if _HAS_PALLAS and _use_pallas(query.shape[-1]):
+    if _use_pallas(query.shape[-1]):
         return _pallas_paged_decode(query, k_pool, v_pool, tables, lens,
                                     float(scale))
     return _jnp_paged_decode(query, k_pool, v_pool, tables, lens,

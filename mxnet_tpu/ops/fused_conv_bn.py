@@ -147,13 +147,6 @@ def _fwd_kernel(x_ref, w_ref, s_ref, t_ref, o_ref, sum_ref, ssq_ref,
             ssq_ref[...] = stat_ref[1:2, :]
 
 
-def _tpu_compiler_params(pltpu, dimension_semantics):
-    """jax renamed pltpu.TPUCompilerParams -> CompilerParams; accept both."""
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(dimension_semantics=dimension_semantics)
-
-
 @functools.partial(jax.jit, static_argnames=("relu", "bm", "bn", "bk",
                                              "interpret"))
 def _fused_fwd_pallas(x, w, scale, bias, relu=False, bm=None, bn=None,
@@ -206,8 +199,8 @@ def _fused_fwd_pallas(x, w, scale, bias, relu=False, bm=None, bn=None,
         ],
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
                         pltpu.VMEM((2, bn), jnp.float32)],
-        compiler_params=_tpu_compiler_params(
-            pltpu, dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(x, w, s2, t2)
     return y, ysum.reshape(N), yssq.reshape(N)
@@ -334,8 +327,8 @@ def _fused_bwd_pallas(x, w, y, scale, bias, dy, dsum, dssq, relu=False,
         out_specs=pl.BlockSpec((bko, bn), lambda ko, n, m: (ko, n)),
         out_shape=jax.ShapeDtypeStruct((K, N), w.dtype),
         scratch_shapes=[pltpu.VMEM((bko, bn), jnp.float32)],
-        compiler_params=_tpu_compiler_params(
-            pltpu, dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(x, dy, y, ds2, dq2, s2, t2)
 
@@ -369,8 +362,8 @@ def _fused_bwd_pallas(x, w, y, scale, bias, dy, dsum, dssq, relu=False,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
-        compiler_params=_tpu_compiler_params(
-            pltpu, dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(dy, y, w, ds2, dq2, x, s2, t2)
     if apply_input:

@@ -131,7 +131,6 @@ class Composed4DStep:
                  head_fn=None, head_params=None):
         from .. import fusedstep, observability as _obs
         from .spmd import _RULES, _lamb_rule_sharded
-        from .compat import get_shard_map
 
         validate_mesh_axes(mesh, "Composed4DStep")
         if "pp" not in mesh.shape or "dp" not in mesh.shape:
@@ -360,7 +359,6 @@ class Composed4DStep:
                 new_extra[ok] = tdef.unflatten(no_)
             return new_p, new_o, new_extra, loss
 
-        shard_map = get_shard_map()
         if zero_stage >= 3:
             pspec_dev = [self._flat_spec] * n_leaves
         else:
@@ -368,12 +366,12 @@ class Composed4DStep:
         ospec_dev = [_opt_dev_spec(i, st)
                      for i, st in enumerate(self._opt)]
         espec = jax.tree_util.tree_map(lambda _: P(), self._extra)
-        self._mapped = shard_map(
+        self._mapped = jax.shard_map(
             body, mesh=mesh,
             in_specs=(pspec_dev, ospec_dev, espec,
                       P(None, "dp"), P(None, "dp"), P()),
             out_specs=(pspec_dev, ospec_dev, espec, P()),
-            check_rep=False)
+            check_vma=False)
 
         def train(params, opt, extra, x, y, lr):
             xs, ys = _microbatch(x, y, M)

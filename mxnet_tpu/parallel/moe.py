@@ -148,10 +148,8 @@ def moe_apply(params, x, mesh=None, axis_name="ep", capacity_factor=1.5,
             raise MXNetError(
                 f"experts {E} must divide mesh axis {axis_name} "
                 f"({mesh.shape[axis_name]})")
-        from .compat import get_shard_map
-        shard_map = get_shard_map()
 
-        expert_out = shard_map(
+        expert_out = jax.shard_map(
             run_experts, mesh=mesh,
             in_specs=(P(axis_name), P(axis_name), P(axis_name)),
             out_specs=P(axis_name),
@@ -243,12 +241,10 @@ def moe_apply_a2a(params, x, mesh, axis_name="ep", capacity_factor=None,
         out = jnp.einsum("ecd,tec->td", expert_out, combine)
         return out, lax.pmean(aux, axis_name)
 
-    from .compat import get_shard_map
-    shard_map = get_shard_map()
-    fn = shard_map(local_fn, mesh=mesh,
-                   in_specs=(P(), P(axis_name), P(axis_name),
-                             P(axis_name)),
-                   out_specs=(P(axis_name), P()))
+    fn = jax.shard_map(local_fn, mesh=mesh,
+                       in_specs=(P(), P(axis_name), P(axis_name),
+                                 P(axis_name)),
+                       out_specs=(P(axis_name), P()))
     return fn(params["gate"], params["w1"], params["w2"], x)
 
 

@@ -32,12 +32,9 @@ whose body calls these helpers).
 
 from __future__ import annotations
 
-import logging
-
 import jax
 import jax.numpy as jnp
-
-_logger = logging.getLogger("mxnet_tpu.parallel.overlap")
+from jax.extend.core import Var
 
 
 # ---------------------------------------------------------------------------
@@ -54,31 +51,26 @@ def first_use_order(fn, example_args, n_diff):
     DESCENDING first-use index approximates the order grads become
     available during backward. Returns a permutation of
     ``range(n_diff)`` (grad index of the earliest-ready gradient
-    first), or None when tracing fails or yields no signal (e.g. the
-    whole forward collapsed into one fused call) — callers fall back
-    to reversed parameter order, the classic DDP heuristic.
+    first), or None when the trace yields no signal (e.g. the whole
+    forward collapsed into one fused call) — callers fall back to
+    reversed parameter order, the classic DDP heuristic. A trace that
+    raises is the caller's error too (the step traces the same
+    forward next), so it propagates.
     """
-    try:
-        closed = jax.make_jaxpr(fn)(*example_args)
-        jaxpr = closed.jaxpr
-        flat_in = jaxpr.invars
-        # diff params are the FIRST pytree argument: its leaves are the
-        # first n_diff flat invars (callers pass them as a list of raw
-        # arrays, each one leaf)
-        targets = flat_in[:n_diff]
-        first = {}
-        for i, eqn in enumerate(jaxpr.eqns):
-            for v in eqn.invars:
-                if isinstance(v, jax.core.Var) and v not in first:
-                    first[v] = i
-        idxs = [first.get(v, -1) for v in targets]
-        if len(set(idxs)) <= 1:
-            return None  # no signal: one mega-equation consumed all
-        return sorted(range(n_diff), key=lambda k: (-idxs[k], k))
-    except Exception as e:  # pragma: no cover - backend/tracing quirks
-        _logger.debug("first_use_order: trace failed (%s: %s)",
-                      type(e).__name__, e)
-        return None
+    jaxpr = jax.make_jaxpr(fn)(*example_args).jaxpr
+    # diff params are the FIRST pytree argument: its leaves are the
+    # first n_diff flat invars (callers pass them as a list of raw
+    # arrays, each one leaf)
+    targets = jaxpr.invars[:n_diff]
+    first = {}
+    for i, eqn in enumerate(jaxpr.eqns):
+        for v in eqn.invars:
+            if isinstance(v, Var) and v not in first:
+                first[v] = i
+    idxs = [first.get(v, -1) for v in targets]
+    if len(set(idxs)) <= 1:
+        return None  # no signal: one mega-equation consumed all
+    return sorted(range(n_diff), key=lambda k: (-idxs[k], k))
 
 
 # ---------------------------------------------------------------------------

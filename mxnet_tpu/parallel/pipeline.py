@@ -83,9 +83,7 @@ def pipeline_apply(stage_fn, stage_params, x, mesh, axis_name="pp",
         stage = lax.axis_index(axis_name)
 
         def _vary(v):  # mark as varying over pp (shard_map vma check)
-            if hasattr(lax, "pcast"):
-                return lax.pcast(v, (axis_name,), to="varying")
-            return v  # pragma: no cover (older jax)
+            return lax.pcast(v, (axis_name,), to="varying")
 
         state = _vary(jnp.zeros_like(xs_local[0]))   # in-flight activation
         outputs = _vary(jnp.zeros_like(xs_local))    # filled by last stage
@@ -112,14 +110,12 @@ def pipeline_apply(stage_fn, stage_params, x, mesh, axis_name="pp",
             axis_name)
         return outputs
 
-    from .compat import get_shard_map
-    shard_map = get_shard_map()
 
     spec_params = jax.tree_util.tree_map(
         lambda _: P(axis_name), stage_params)
-    fn = shard_map(per_stage, mesh=mesh,
-                   in_specs=(spec_params, P()),
-                   out_specs=P())
+    fn = jax.shard_map(per_stage, mesh=mesh,
+                       in_specs=(spec_params, P()),
+                       out_specs=P())
     ys = fn(stage_params, xs)
     return ys.reshape(B, *x.shape[1:])
 
@@ -405,9 +401,7 @@ def _run_schedule(stage_fn, loss_fn, sched, axis_name, params_local,
         act_shape, act_dtype = a0.shape, a0.dtype
 
     def _vary(val):
-        if hasattr(lax, "pcast"):
-            return lax.pcast(val, (axis_name,), to="varying")
-        return val  # pragma: no cover (older jax)
+        return lax.pcast(val, (axis_name,), to="varying")
 
     def row(col, t):  # this rank's entry of a [T, S] host table
         return _vary(jnp.asarray(tbl[col][t]))[rank]
@@ -453,10 +447,10 @@ def _run_schedule(stage_fn, loss_fn, sched, axis_name, params_local,
             def lf(o, hp):
                 return loss_fn(head_fn(hp, o), y_m)
             val, vjp = jax.vjp(lf, out_m, head_params)
-            g_o, g_h = vjp(inv_m.astype(val.dtype))
+            g_o, g_h = vjp(lax.full_like(val, 1.0 / M))
             return val, g_o.astype(act_dtype), g_h
         val, vjp = jax.vjp(lambda o: loss_fn(o, y_m), out_m)
-        (g_o,) = vjp(inv_m.astype(val.dtype))
+        (g_o,) = vjp(lax.full_like(val, 1.0 / M))
         return val, g_o.astype(act_dtype), None
 
     for t in range(T):
@@ -648,8 +642,6 @@ class PipelineTrainStep:
             self._params = shard_stages(permuted, mesh, axis_name)
             self._opt = jax.tree_util.tree_map(rule_init, self._params)
 
-            from .compat import get_shard_map
-            shard_map = get_shard_map()
             spec_p = jax.tree_util.tree_map(lambda _: P(axis_name),
                                             self._params)
 
@@ -674,7 +666,7 @@ class PipelineTrainStep:
                 lambda leaf: P(axis_name)
                 if getattr(leaf, "ndim", 0) >= 1 else P(),
                 self._opt)
-            mapped = shard_map(
+            mapped = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(spec_p, spec_o, P(), P(), P()),
                 out_specs=(spec_p, spec_o, P()))

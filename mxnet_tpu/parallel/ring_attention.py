@@ -43,8 +43,6 @@ def ring_attention(query, key, value, mesh, axis_name="sp", scale=None,
     across the axis. Returns the global (B, H, T, D) result with the same
     sharding. Jit-able; collectives lower to ICI ppermute.
     """
-    from .compat import get_shard_map
-    shard_map = get_shard_map()
 
     if scale is None:
         scale = 1.0 / (query.shape[-1] ** 0.5)
@@ -88,13 +86,8 @@ def ring_attention(query, key, value, mesh, axis_name="sp", scale=None,
 
         def _vary(x):
             # mark constants as varying over the ring axis so the scan
-            # carry types match shard_map's varying-axes check; the API
-            # was lax.pvary (<=0.8, deprecated) and is lax.pcast in 0.9+
-            if hasattr(lax, "pcast"):
-                return lax.pcast(x, (axis_name,), to="varying")
-            if hasattr(lax, "pvary"):  # pragma: no cover (old jax)
-                return lax.pvary(x, axis_name)
-            return x  # pragma: no cover
+            # carry types match shard_map's varying-axes check
+            return lax.pcast(x, (axis_name,), to="varying")
 
         init = (
             _vary(jnp.zeros((B, H, Tl, D), jnp.float32)),
@@ -106,8 +99,8 @@ def ring_attention(query, key, value, mesh, axis_name="sp", scale=None,
         return (o_acc / jnp.maximum(l_acc, 1e-30)).astype(q.dtype)
 
     spec = P(None, None, axis_name, None)
-    fn = shard_map(per_device, mesh=mesh, in_specs=(spec, spec, spec),
-                   out_specs=spec)
+    fn = jax.shard_map(per_device, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec)
     return fn(query, key, value)
 
 

@@ -108,17 +108,25 @@ def setup_compile_cache(path=None):
     """Enable JAX's persistent compilation cache at ``path`` (or
     ``$MXTPU_COMPILE_CACHE``). Idempotent; called automatically the
     first time a ``Context`` is created. Returns the active cache dir,
-    or None when unconfigured."""
+    or None when unconfigured.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set the cache was placed from
+    outside: JAX reads that variable itself, so this sets NO directory,
+    whatever ``path`` and ``MXTPU_COMPILE_CACHE`` say (a second
+    directory set in code would never be found again by whoever placed
+    the first). The thresholds and the hit/miss listener still apply."""
     from .base import getenv
 
-    path = path or getenv("MXTPU_COMPILE_CACHE")
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    path = placed or path or getenv("MXTPU_COMPILE_CACHE")
     if not path:
         return _CACHE_STATE["dir"]
     path = os.path.abspath(os.path.expanduser(str(path)))
     if _CACHE_STATE["dir"] == path:
         return path
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
+    if not placed:
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
     # cache EVERY executable: the defaults skip sub-second compiles,
     # which is exactly the many-small-executables regime the fused step
     # produces (and the whole of the CPU test/bench tier)

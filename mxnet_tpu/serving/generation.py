@@ -295,7 +295,11 @@ class GenerationEngine:
         self.cache = PagedKVCache(
             dims["layers"], dims["kv_heads"], dims["head_dim"],
             max_seq=self.max_seq, num_blocks=cache_blocks,
-            block_size=cache_block_size, name=self._name)
+            block_size=cache_block_size, name=self._name,
+            # the pool holds what the net's K/V projections emit: a
+            # float32 pool under a bf16 net doubles the cache and hands
+            # the decode kernel mixed operand dtypes
+            dtype=dtype or getattr(net, "dtype", "float32"))
         self._mb = self.cache.max_blocks_per_seq
         self._lock = threading.Lock()
         self._queue = collections.deque()

@@ -71,7 +71,10 @@ loss_r, sum_r = run("ready")
 assert loss_b == loss_r, (loss_b, loss_r)
 assert sum_b == sum_r, (sum_b, sum_r)
 loss_z2, sum_z2 = run("ready", stage=2)
-assert loss_z2 == loss_r, (loss_z2, loss_r)
-assert sum_z2 == sum_r, (sum_z2, sum_r)
+# ZeRO-2 reduces with a reduce-scatter where stage 0 all-reduces: across
+# processes the two collectives may add in another order, so the float32
+# results agree to an ulp or two, not bit for bit (one process does)
+np.testing.assert_allclose(loss_z2, loss_r, rtol=1e-6)
+np.testing.assert_allclose(sum_z2, sum_r, rtol=1e-6)
 print(f"OVERLAP_WORKER_OK rank={rank}/{nprocs} loss={loss_r:.10f} "
       f"checksum={sum_r:.8f}", flush=True)

@@ -479,7 +479,8 @@ import json
 print(json.dumps({{"hits": int(obs.COMPILE_CACHE_HITS.total()),
                    "misses": int(obs.COMPILE_CACHE_MISSES.total()),
                    "dir": __import__("mxnet_tpu.runtime", fromlist=["x"])
-                          .compile_cache_dir()}}))
+                          .compile_cache_dir(),
+                   "jax_dir": jax.config.jax_compilation_cache_dir}}))
 """
 
 
@@ -504,6 +505,24 @@ def test_compile_cache_cold_then_warm(tmp_path):
     warm = run()
     assert warm["misses"] == 0, warm
     assert warm["hits"] > 0
+
+
+def test_compile_cache_placed_from_outside_wins(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: JAX reads it itself and the
+    library sets no directory in code, whatever MXTPU_COMPILE_CACHE
+    says — the cache lands where it was placed, nowhere else."""
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "placed")
+    env["MXTPU_COMPILE_CACHE"] = str(tmp_path / "ours")
+    res = subprocess.run(
+        [sys.executable, "-c", _CACHE_SNIPPET.format(root=ROOT)],
+        env=env, capture_output=True, text=True, timeout=240)
+    assert res.returncode == 0, res.stderr[-2000:]
+    out = json.loads(res.stdout.strip().splitlines()[-1])
+    assert out["dir"] == out["jax_dir"] == str(tmp_path / "placed")
+    assert out["misses"] > 0
+    assert os.listdir(str(tmp_path / "placed"))
+    assert not os.path.exists(str(tmp_path / "ours"))
 
 
 # ---------------------------------------------------------------------------

@@ -256,3 +256,40 @@ def test_env_knob_defaults_and_floors(monkeypatch):
     c = PagedKVCache(1, 1, 2, max_seq=32)
     assert c.num_blocks == 64 and c.block_size == 8
     assert c.max_blocks_per_seq == 4
+
+
+# ---------------------------------------------------------------------------
+# the Pallas decode kernel against the gather oracle (interpret mode)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("B,H,KVH,D,bs,mb,dtype,tol", [
+    (5, 4, 4, 16, 8, 6, jnp.float32, 1e-5),      # MHA, toy
+    (5, 8, 2, 32, 8, 5, jnp.float32, 1e-5),      # GQA, group 4
+    (5, 12, 12, 64, 16, 4, jnp.bfloat16, 2e-2),  # GPT-2-small heads
+], ids=["mha_f32", "gqa_f32", "mha_bf16_h12_d64"])
+def test_pallas_paged_decode_matches_gather_oracle(B, H, KVH, D, bs, mb,
+                                                   dtype, tol):
+    """The kernel the TPU path takes, run by the Pallas interpreter
+    (steered here, not by a program option), against
+    ``_jnp_paged_decode`` on ragged contexts: an empty slot, one token,
+    a block boundary, a full table and a partial last block."""
+    from mxnet_tpu.ops import flash_attention as fa
+
+    rng = np.random.RandomState(0)
+    nb = B * mb + 1
+    q = jnp.asarray(rng.randn(B, H, D), dtype)
+    k_pool = jnp.asarray(rng.randn(nb, bs, KVH, D), dtype)
+    v_pool = jnp.asarray(rng.randn(nb, bs, KVH, D), dtype)
+    # every sequence owns distinct, shuffled pool blocks (never null 0)
+    tables = jnp.asarray(1 + rng.permutation(B * mb).reshape(B, mb),
+                         jnp.int32)
+    lens = jnp.asarray([0, 1, bs, mb * bs, mb * bs - 3], jnp.int32)
+    scale = D ** -0.5
+    ref = fa._jnp_paged_decode(q, k_pool, v_pool, tables, lens, scale)
+    out = fa._pallas_paged_decode(q, k_pool, v_pool, tables, lens, scale,
+                                  interpret=True)
+    assert out.shape == (B, H, D) and out.dtype == q.dtype
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               rtol=tol, atol=tol)
+    assert not np.asarray(out, np.float32)[0].any()  # empty slot: zeros

@@ -177,18 +177,6 @@ def test_engine_wait_instrumented():
     assert obs.ENGINE_WAIT_SECONDS.value(path="native") >= 0
 
 
-def test_engine_wait_relay_path_instrumented(monkeypatch):
-    """The relay dependent-read sync reports under path="relay"."""
-    from mxnet_tpu import engine
-
-    obs.set_enabled(True)
-    monkeypatch.setattr(engine, "_RELAY", True)
-    a = mx.nd.ones((4, 4)) + 1
-    engine.wait(a.data)
-    assert obs.ENGINE_WAIT_TOTAL.value(path="relay") >= 1
-    assert obs.ENGINE_WAIT_TOTAL.value(path="native") == 0
-
-
 # ---------------------------------------------------------------------------
 # the acceptance loop: hybridized Trainer training on CPU
 # ---------------------------------------------------------------------------

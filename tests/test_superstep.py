@@ -614,10 +614,8 @@ def test_superstep_run_epoch_with_tail_parity():
 # ---------------------------------------------------------------------------
 
 def test_bucketed_psum_in_scan_parity():
-    from mxnet_tpu.parallel.compat import get_shard_map
     from jax.sharding import PartitionSpec as P
 
-    shard_map = get_shard_map()
     mesh = parallel.make_mesh({"dp": 8})
     rs = np.random.RandomState(0)
     grads = [jnp.asarray(rs.randn(*s).astype(dt)) for s, dt in
@@ -632,8 +630,8 @@ def test_bucketed_psum_in_scan_parity():
         _, outs = jax.lax.scan(body, 0, jnp.arange(2))
         return [o[1] for o in outs]  # second scan iteration's results
 
-    outs = shard_map(inner, mesh=mesh, in_specs=(P(),),
-                     out_specs=P())(grads)
+    outs = jax.shard_map(inner, mesh=mesh, in_specs=(P(),),
+                         out_specs=P())(grads)
     for g, o in zip(grads, outs):
         np.testing.assert_allclose(np.asarray(o, np.float32),
                                    8 * np.asarray(g, np.float32),
@@ -643,18 +641,16 @@ def test_bucketed_psum_in_scan_parity():
 def test_bucketed_psum_single_tensor_and_split():
     """Odd sizes, one-tensor buckets, and the bucket-bytes split all
     reduce correctly (dtype-homogeneous buckets only)."""
-    from mxnet_tpu.parallel.compat import get_shard_map
     from jax.sharding import PartitionSpec as P
 
-    shard_map = get_shard_map()
     mesh = parallel.make_mesh({"dp": 8})
     grads = [jnp.ones((1000,), jnp.float32),  # 4000 B: splits at 1024
              jnp.ones((3,), jnp.float32),
              jnp.ones((7,), jnp.float16)]
 
-    f = shard_map(lambda gs: parallel.bucketed_psum(gs, "dp",
-                                                    bucket_bytes=1024),
-                  mesh=mesh, in_specs=(P(),), out_specs=P())
+    f = jax.shard_map(lambda gs: parallel.bucketed_psum(gs, "dp",
+                                                        bucket_bytes=1024),
+                      mesh=mesh, in_specs=(P(),), out_specs=P())
     outs = f(grads)
     for g, o in zip(grads, outs):
         np.testing.assert_allclose(np.asarray(o, np.float32),

@@ -49,8 +49,6 @@ def main():
 
     if args.kv_store == "psum":
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-        from mxnet_tpu.parallel.compat import get_shard_map
-        shard_map = get_shard_map()
 
         mesh = Mesh(np.array(jax.devices()), ("dp",))
         x = jax.device_put(
@@ -59,11 +57,12 @@ def main():
 
         @jax.jit
         def allreduce(v):
-            return shard_map(lambda a: jax.lax.psum(a, "dp"), mesh=mesh,
-                             in_specs=P("dp", None), out_specs=P("dp", None))(v)
+            return jax.shard_map(lambda a: jax.lax.psum(a, "dp"), mesh=mesh,
+                                 in_specs=P("dp", None),
+                                 out_specs=P("dp", None))(v)
 
         r = allreduce(x)
-        _ = np.asarray(r).ravel()[0]  # sync through any relay
+        _ = np.asarray(r).ravel()[0]  # sync: dependent host read
         t0 = time.perf_counter()
         for _ in range(args.num_iters):
             r = allreduce(r)

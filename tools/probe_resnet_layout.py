@@ -3,7 +3,8 @@
 
 Times a hand-rolled ResNet-50 v1 train step (fwd+bwd+SGD-momentum, BN train
 stats) in raw JAX under different data layouts/dtypes, independent of the
-framework, to locate the MFU gap flagged in VERDICT.md ("What's weak" #1).
+framework, to locate the MFU gap seen in a chip record from before this
+round, deleted in PR 22; the figure is a claim.
 
 Usage: python tools/probe_resnet_layout.py [nchw|nhwc|both] [batch]
 """
