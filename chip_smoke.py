@@ -37,12 +37,22 @@ sys.path.insert(0, ROOT)
 import numpy as np  # noqa: E402
 
 # A bfloat16 logit keeps 8 significant bits. The decode path and the
-# dense reference round differently, so where the reference's top two
-# logits sit within this many bfloat16 steps of each other, either token
-# is the reference's answer. Seen at full width: 16 of 512 tokens, up
-# to 2.0 steps apart (CPU rehearsal) and 1.7 (v5e, PR 22); the bound
-# leaves one step of room. Zero for a float32 model.
+# dense reference round differently, so where the reference ranks other
+# tokens within this many bfloat16 steps of its top logit, any of them
+# is the reference's answer. Seen at full width: 16 of 512 tokens, 8 of
+# them exact ties, the widest 2.0 steps and three tokens down (CPU
+# rehearsal), 1.7 steps (v5e, PR 22); the bound leaves one step of
+# room. Zero for a float32 model.
 BF16_TIE_STEPS = 3
+# What a tie could hide, the logit tap shows: max|engine - reference| /
+# max|reference| over each emitted token's logits. Twelve bf16 layers
+# read 1.39e-2 at full width (CPU rehearsal, PR 22), and a decode that
+# reads one KV row too few reads 1.39e-1 (four layers); the bound
+# sits between.
+LOGIT_TOL = {"bfloat16": 3e-2, "float32": 1e-4}
+# past this share of the device's memory a phase warns (stderr and its
+# JSON line): the next allocation may not fit
+MEMORY_WARN_SHARE = 0.9
 
 # -- the full widths the command line runs ----------------------------------
 RESNET50 = dict(batch=128, image=224, classes=1000, steps=5)
@@ -112,12 +122,21 @@ def _seed(seed):
     return np.random.RandomState(seed)
 
 
-def _memory(device):
+def _memory(device, phase):
     """The allocator's view after a phase. The peak is the process's
-    high-water mark so far, not this phase's alone."""
+    high-water mark so far, not this phase's alone; past
+    ``MEMORY_WARN_SHARE`` of the limit the phase says so."""
     stats = device.memory_stats() or {}  # the CPU backend reports none
-    return {k: stats.get(k) for k in
-            ("peak_bytes_in_use", "bytes_in_use", "bytes_limit")}
+    out = {k: stats.get(k) for k in
+           ("peak_bytes_in_use", "bytes_in_use", "bytes_limit")}
+    if out["peak_bytes_in_use"] and out["bytes_limit"]:
+        share = out["peak_bytes_in_use"] / out["bytes_limit"]
+        out["peak_share_of_limit"] = round(share, 4)
+        if share > MEMORY_WARN_SHARE:
+            out["warning"] = (f"peak is {share:.1%} of the device's "
+                              f"memory (warns past {MEMORY_WARN_SHARE:.0%})")
+            print(f"chip_smoke: {phase}: {out['warning']}", file=sys.stderr)
+    return out
 
 
 def _finite(losses, what):
@@ -202,7 +221,7 @@ def train_resnet50(compiles, *, batch, image, classes, steps, make_net=None,
         "compiles_total": compiles.count - c0,
         "fused_fallbacks": fallbacks.fired,
         "param_platforms": _param_platforms(net),
-        "memory": _memory(jax.devices()[0]),
+        "memory": _memory(jax.devices()[0], "train_resnet50"),
     }
     _finite(losses, "train_resnet50")
     _require(not fallbacks.fired,
@@ -232,12 +251,10 @@ def _mlm_loss():
 
 
 def train_bert_base(compiles, *, batch, seq, vocab, steps, make_net=None,
-                    dtype="bfloat16", seed=0, platform="tpu",
-                    require_kernel=True):
+                    dtype="bfloat16", seed=0, platform="tpu"):
     """``parallel.SPMDTrainStep(net, mlm_loss, "adam", mesh=None)`` as
-    bench.py builds it. ``require_kernel``: the step's compiled HLO must
-    hold a ``tpu_custom_call`` (else attention quietly took the jnp
-    path); only a CPU rehearsal may waive it."""
+    bench.py builds it. On a TPU the step's compiled HLO must hold a
+    ``tpu_custom_call`` (else attention quietly took the jnp path)."""
     import jax
 
     import mxnet_tpu as mx
@@ -262,7 +279,7 @@ def train_bert_base(compiles, *, batch, seq, vocab, steps, make_net=None,
         c0 = compiles.count
         losses, ms, compiled = _timed_steps(one_step, steps, compiles)
     compiles_total = compiles.count - c0
-    hlo = step.compile_step().as_text()  # lowers the step once more
+    hlo = step._compile_step().as_text()  # lowers the step once more
     state_platforms = sorted({d.platform for leaf in step._state[0]
                               for d in leaf.devices()})
     out = {
@@ -276,17 +293,17 @@ def train_bert_base(compiles, *, batch, seq, vocab, steps, make_net=None,
         "tpu_custom_call_in_step_hlo": "tpu_custom_call" in hlo,
         "spmd_fallbacks": fallbacks.fired,
         "param_platforms": state_platforms,
-        "memory": _memory(jax.devices()[0]),
+        "memory": _memory(jax.devices()[0], "train_bert_base"),
     }
     _finite(losses, "train_bert_base")
     _require(not any(compiled[1:]),
              f"steps after the first compiled: {compiled}")
-    _require(state_platforms == [platform],
-             f"step state lives on {state_platforms}, expected {platform!r}")
-    if require_kernel:
+    if platform == "tpu":
         _require(out["tpu_custom_call_in_step_hlo"],
                  "no tpu_custom_call in the step's HLO: attention took "
                  "_jnp_flash_fwd")
+    _require(state_platforms == [platform],
+             f"step state lives on {state_platforms}, expected {platform!r}")
     return out
 
 
@@ -380,19 +397,103 @@ def kernels(*, flash, window, decode, dtype="bfloat16", seed=0, tol=None):
                      f"(bound {bound:g})")
     return {"phase": "kernels", "dtype": dtype, "max_rel_err": cases,
             "tol_fwd": fwd_tol, "tol_grad": grad_tol,
-            "memory": _memory(jax.devices()[0])}
+            "memory": _memory(jax.devices()[0], "kernels")}
 
 
 # ---------------------------------------------------------------------------
 # phase: the paged-decode server
 # ---------------------------------------------------------------------------
 
+def _tap_columns(vocab):
+    """Every k-th logit, some 256 of them: what the tap hands the host."""
+    return np.arange(0, vocab, max(1, vocab // 256))
+
+
+def _tapped_decoder(taps):
+    """``TransformerDecoderLM`` whose prefill and decode step also hand
+    the host the logits the engine samples from: per sequence the top
+    logit, its index and the ``_tap_columns`` (``jax.debug.callback``,
+    some 1 KB per token). The engine is not touched and has no logits
+    to give; this is the only way to hold them to the reference."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.serving import TransformerDecoderLM
+
+    def tap(kind, at, live, logits):
+        cols = _tap_columns(logits.shape[-1])
+        jax.debug.callback(
+            lambda *a: taps.append((kind, *map(np.asarray, a))),
+            at, live, logits.argmax(-1), logits.max(-1).astype(jnp.float32),
+            logits[:, cols].astype(jnp.float32))
+
+    class Tapped(TransformerDecoderLM):
+        def prefill_fn(self):
+            prefill = super().prefill_fn()
+
+            def tapped(params, tokens, k_pool, v_pool, table, length):
+                out = prefill(params, tokens, k_pool, v_pool, table, length)
+                tap("prefill", length, length > 0, out[0])
+                return out
+
+            return tapped
+
+        def decode_step_fn(self):
+            step = super().decode_step_fn()
+
+            def tapped(params, token, pos, k_pool, v_pool, tables, active):
+                out = step(params, token, pos, k_pool, v_pool, tables,
+                           active)
+                tap("decode", pos, active, out[0])
+                return out
+
+            return tapped
+
+    return Tapped
+
+
+def _tapped_logits(taps, prompts, outputs):
+    """Sort the tap's records by request: per request and emitted token
+    (argmax, top logit, tapped columns). The prefill's record carries
+    the prompt length; a decode record carries its slot and the
+    position it wrote, and a slot's first position is its request's
+    prompt length. So prompt lengths are distinct and no slot serves
+    two requests, which the caller's shapes guarantee."""
+    by_len = {len(p): i for i, p in enumerate(prompts)}
+    _require(len(by_len) == len(prompts), "serve_decode: prompt lengths "
+             "must differ for the tap to tell requests apart")
+    rows = [dict() for _ in prompts]  # token index -> record
+    slots = {}
+    for kind, at, live, arg, top, cols in taps:
+        for b in np.flatnonzero(live):
+            rec = (int(arg[b]), float(top[b]), cols[b])
+            if kind == "prefill":
+                rows[by_len[int(at[b])]][0] = rec
+            else:
+                slots.setdefault(int(b), {})[int(at[b])] = rec
+    for b, recs in slots.items():
+        first = min(recs)
+        _require(first in by_len and sorted(recs) == list(
+            range(first, first + len(recs))),
+            f"serve_decode: slot {b} served more than one request")
+        for pos, rec in recs.items():
+            rows[by_len[first]][pos - first + 1] = rec
+    for i, (row, out) in enumerate(zip(rows, outputs)):
+        _require(sorted(row) == list(range(len(out))),
+                 f"serve_decode: the tap saw tokens {sorted(row)} of "
+                 f"request {i}, the engine emitted {len(out)}")
+    return [[row[k] for k in range(len(out))]
+            for row, out in zip(rows, outputs)]
+
+
 def _dense_reference(net, prompts, outputs):
     """The engine's dense-recompute reference (tests/test_generation.py):
     ONE causal forward of ``net.forward_fn()`` over prompt+generated
     must greedy-predict every generated token from its own prefix.
-    Returns per request (reference argmax, reference max logit, the
-    reference's logit of the token the engine produced)."""
+    Returns per request the reference's token, how many tokens it
+    ranks above the one the engine produced, its top logit, its logit
+    of the engine's token, its ``_tap_columns`` and max|logit|, each
+    per generated token."""
     import jax
     import jax.numpy as jnp
 
@@ -405,16 +506,22 @@ def _dense_reference(net, prompts, outputs):
 
     @jax.jit
     def ref(params, tokens):
-        logits = fwd(params, tokens).astype(jnp.float32)[:, :-1]
+        # the barrier keeps every reading below on the same rounded
+        # logits (the TPU compiler otherwise recomputes the gathered
+        # one from the head's column, at another precision)
+        logits = jax.lax.optimization_barrier(fwd(params, tokens))
+        logits = logits.astype(jnp.float32)[:, :-1]
         chosen = jnp.take_along_axis(logits, tokens[:, 1:, None], axis=-1)
-        return logits.argmax(-1), logits.max(-1), chosen[..., 0]
+        return (logits.argmax(-1), (logits > chosen).sum(-1),
+                logits.max(-1), chosen[..., 0],
+                logits[..., _tap_columns(logits.shape[-1])],
+                jnp.abs(logits).max(-1))
 
-    want, top, chosen = (np.asarray(a) for a in
-                         ref(net.params(), jnp.asarray(seqs)))
+    arrays = [np.asarray(a) for a in ref(net.params(), jnp.asarray(seqs))]
     rows = []
     for i, (p, o) in enumerate(zip(prompts, outputs)):
         at = slice(len(p) - 1, len(p) - 1 + len(o))
-        rows.append((want[i, at], top[i, at], chosen[i, at]))
+        rows.append(tuple(a[i, at] for a in arrays))
     return rows
 
 
@@ -422,19 +529,27 @@ def serve_decode(compiles, *, model, prompt_lens, buckets, new_tokens, slots,
                  chunk, seed=0, platform="tpu"):
     """``TransformerDecoderLM`` through ``GenerationEngine`` and
     ``PagedKVCache`` as examples/generate.py drives them: one greedy
-    request per prompt length, all submitted at once. Nothing may
-    compile after the engine's warm-up, and the tokens must equal the
-    dense-recompute reference. In bfloat16 the reference's own top two
-    logits can sit within a rounding step or two of each other; there,
-    and only there, either token is the reference's answer
-    (``BF16_TIE_STEPS``), and nine tokens in ten must still be equal
-    outright. A float32 model must be token-exact."""
+    request per prompt length (all different, no more than ``slots``),
+    all submitted at once. Nothing may compile after the engine's
+    warm-up, and the tokens must equal the dense-recompute reference.
+
+    In bfloat16 the reference's own top logits can sit within a
+    rounding step or two of each other; there, and only there, the
+    engine may emit another of them (``BF16_TIE_STEPS``), and nine
+    tokens in ten must still be equal outright. Since a small KV fault
+    could hide in such a tie, the logits the engine sampled from
+    (``_tapped_decoder``) are also held to the reference's numerically,
+    every emitted token, at ``LOGIT_TOL``. A float32 model must be
+    token-exact. The tap's host callback is inside the gap readings."""
     import jax
 
-    from mxnet_tpu.serving import GenerationEngine, TransformerDecoderLM
+    from mxnet_tpu.serving import GenerationEngine
 
+    _require(len(prompt_lens) <= slots,
+             "serve_decode: more requests than slots")
     rng = _seed(seed)
-    net = TransformerDecoderLM(seed=seed, **model)
+    taps = []
+    net = _tapped_decoder(taps)(seed=seed, **model)
     block = 16
     per_seq = -(-model["max_seq"] // block)
     c0, t0 = compiles.count, time.perf_counter()
@@ -459,18 +574,34 @@ def serve_decode(compiles, *, model, prompt_lens, buckets, new_tokens, slots,
                                  for d in eng.cache.k_pool.devices()})
     finally:
         eng.close()
-    steps = BF16_TIE_STEPS if model.get("dtype") == "bfloat16" else 0
-    exact = ties = 0
-    for (want, top, chosen), out in zip(
-            _dense_reference(net, prompts, outputs), outputs):
+    jax.effects_barrier()  # every tap has reached the host
+    _require(all(len(o) == new_tokens for o in outputs),
+             "serve_decode: a request came back short")
+    dtype = model.get("dtype", "float32")
+    steps = BF16_TIE_STEPS if dtype == "bfloat16" else 0
+    exact, logit_err = 0, 0.0
+    tied = []  # per tie: (bf16 steps below the reference's top, rank)
+    beyond = []  # (engine's tokens, reference's) where no tie explains it
+    for (want, rank, top, chosen, cols, scale), tapped, out in zip(
+            _dense_reference(net, prompts, outputs),
+            _tapped_logits(taps, prompts, outputs), outputs):
+        _require([t[0] for t in tapped] == out.tolist(),
+                 "serve_decode: the tapped logits' argmax is not the "
+                 f"token the engine emitted: {tapped} vs {out.tolist()}")
+        eng_top = np.array([t[1] for t in tapped])
+        eng_cols = np.stack([t[2] for t in tapped])
+        err = np.maximum(np.abs(eng_top - chosen),
+                         np.abs(eng_cols - cols).max(-1)) / scale
+        logit_err = max(logit_err, float(err.max()))
         same = out == want
         bf16_step = 2.0 ** (np.floor(np.log2(np.abs(top))) - 7)
-        near = ~same & (top - chosen <= steps * bf16_step)
-        _require(bool((same | near).all()),
-                 f"serve_decode: tokens differ from the dense reference "
-                 f"beyond a rounding tie: {out.tolist()} vs {want.tolist()}")
+        apart = (top - chosen) / bf16_step
+        near = ~same & (apart <= steps)
+        if not (same | near).all():
+            beyond.append((out.tolist(), want.tolist()))
         exact += int(same.sum())
-        ties += int(near.sum())
+        tied += [(round(float(a), 3), int(r))
+                 for a, r in zip(apart[near], rank[near])]
     out = {
         "phase": "serve_decode", "path": "GenerationEngine + PagedKVCache",
         "model": model, "requests": len(prompts),
@@ -478,7 +609,10 @@ def serve_decode(compiles, *, model, prompt_lens, buckets, new_tokens, slots,
         "slots": slots, "chunk": chunk, "buckets": list(buckets),
         "tokens_total": int(sum(len(o) for o in outputs)),
         "tokens_equal_reference": exact,
-        "tokens_at_reference_rounding_tie": ties,
+        "tokens_at_reference_rounding_tie": len(tied),
+        "ties_steps_below_top_and_rank": tied,
+        "tie_bound_bf16_steps": steps,
+        "logits_max_rel_err": logit_err, "logits_tol": LOGIT_TOL[dtype],
         "smoke_reading_deploy_s": round(deploy_s, 3),
         "deploy_compiles": deploy_compiles,
         "compiles_after_warmup": served_compiles,
@@ -486,10 +620,14 @@ def serve_decode(compiles, *, model, prompt_lens, buckets, new_tokens, slots,
         "smoke_reading_inter_token_gap_ms": [round(v, 3) for v in gap_ms],
         "dispatches": stats["dispatches"],
         "cache_platforms": pool_platforms,
-        "memory": _memory(jax.devices()[0]),
+        "memory": _memory(jax.devices()[0], "serve_decode"),
     }
-    _require(all(len(o) == new_tokens for o in outputs),
-             "serve_decode: a request came back short")
+    _require(np.isfinite(logit_err) and logit_err <= LOGIT_TOL[dtype],
+             f"serve_decode: the engine's logits are off the dense "
+             f"reference's by {logit_err:.4g} (bound {LOGIT_TOL[dtype]:g})")
+    _require(not beyond,
+             "serve_decode: tokens differ from the dense reference beyond "
+             f"a rounding tie (engine, reference): {beyond[:1]}")
     _require(exact >= 0.9 * out["tokens_total"],
              f"serve_decode: only {exact} of {out['tokens_total']} tokens "
              "equal the dense reference")
@@ -542,7 +680,7 @@ def train_resnet50_dp4(*, batch, image, classes, steps, make_net=None,
         d.id for d in parallel.shard_batch(x, mesh).sharding.device_set)
     param_devices = sorted({s.device.id for leaf in step._state[0]
                             for s in leaf.addressable_shards})
-    hlo = step.compile_step().as_text()
+    hlo = step._compile_step().as_text()
     # None where the backend reports no memory stats (the CPU)
     bytes_in_use = {str(d.id): (d.memory_stats() or {}).get("bytes_in_use")
                     for d in devices}

@@ -1327,7 +1327,7 @@ class SPMDTrainStep:
         self._last_loss = losses[-1]
         return losses
 
-    def compile_step(self):
+    def _compile_step(self):
         """The step executable at the last call's shapes, as a
         ``jax.stages.Compiled`` (``as_text()``, ``cost_analysis()``,
         ``memory_analysis()``), or None before the first call or in
@@ -1355,9 +1355,9 @@ class SPMDTrainStep:
     def cost_analysis(self):
         """XLA's cost analysis for the compiled step (``{"flops": ...}``),
         or None when the backend doesn't expose it (some PJRT plugins).
-        Costs what :meth:`compile_step` costs."""
+        Costs what :meth:`_compile_step` costs."""
         try:
-            compiled = self.compile_step()
+            compiled = self._compile_step()
             return None if compiled is None else compiled.cost_analysis()
         except Exception:
             return None

@@ -71,10 +71,17 @@ loss_r, sum_r = run("ready")
 assert loss_b == loss_r, (loss_b, loss_r)
 assert sum_b == sum_r, (sum_b, sum_r)
 loss_z2, sum_z2 = run("ready", stage=2)
-# ZeRO-2 reduces with a reduce-scatter where stage 0 all-reduces: across
-# processes the two collectives may add in another order, so the float32
-# results agree to an ulp or two, not bit for bit (one process does)
-np.testing.assert_allclose(loss_z2, loss_r, rtol=1e-6)
-np.testing.assert_allclose(sum_z2, sum_r, rtol=1e-6)
+if nprocs == 1:
+    assert loss_z2 == loss_r, (loss_z2, loss_r)
+    assert sum_z2 == sum_r, (sum_z2, sum_r)
+else:
+    # XLA:CPU's cross-process reduce-scatter adds (d0+d1)+(d2+d3) where
+    # its all-reduce adds in another order (read in PR 22: 1415 of 4096
+    # random float32 sums differ); after ten steps loss and checksum each
+    # sit one float32 ulp apart, run after run. Hold them to two.
+    np.testing.assert_array_max_ulp(np.float32(loss_z2), np.float32(loss_r),
+                                    maxulp=2)
+    np.testing.assert_array_max_ulp(np.float32(sum_z2), np.float32(sum_r),
+                                    maxulp=2)
 print(f"OVERLAP_WORKER_OK rank={rank}/{nprocs} loss={loss_r:.10f} "
       f"checksum={sum_r:.8f}", flush=True)

@@ -60,7 +60,7 @@ def test_train_resnet_phase_fails_when_params_are_elsewhere(compiles):
 def test_train_bert_phase(compiles):
     out = chip_smoke.train_bert_base(
         compiles, make_net=_tiny_bert, batch=4, seq=16, vocab=100, steps=3,
-        dtype="float32", platform="cpu", require_kernel=False)
+        dtype="float32", platform="cpu")
     assert out["compiles_by_step"][1:] == [0, 0]
     assert out["tpu_custom_call_in_step_hlo"] is False  # the CPU's jnp path
     json.dumps(out)
@@ -70,7 +70,7 @@ def test_train_bert_phase_demands_the_kernel(compiles):
     with pytest.raises(chip_smoke.SmokeFailure, match="tpu_custom_call"):
         chip_smoke.train_bert_base(
             compiles, make_net=_tiny_bert, batch=4, seq=16, vocab=100,
-            steps=2, dtype="float32", platform="cpu")
+            steps=2, dtype="float32", platform="tpu")
 
 
 def test_kernels_phase():
@@ -90,8 +90,42 @@ def test_serve_decode_phase_is_token_exact(compiles):
         new_tokens=10, slots=4, chunk=4, platform="cpu")
     assert out["tokens_equal_reference"] == out["tokens_total"] == 40
     assert out["tokens_at_reference_rounding_tie"] == 0
+    assert out["logits_max_rel_err"] < 1e-5
     assert out["compiles_after_warmup"] == 0
     json.dumps(out)
+
+
+def test_serve_decode_phase_refuses_a_stale_context(compiles, monkeypatch):
+    """A decode that reads one row too few nudges the logits: the tap
+    must refuse it whether or not a token flips."""
+    from mxnet_tpu.ops import flash_attention as fa
+
+    real = fa.paged_decode_attention
+
+    def one_row_short(q, k_pool, v_pool, tables, lens, **kw):
+        return real(q, k_pool, v_pool, tables, lens - (lens > 1), **kw)
+
+    monkeypatch.setattr(fa, "paged_decode_attention", one_row_short)
+    model = dict(vocab_size=64, num_layers=2, d_model=32, num_heads=4,
+                 d_ff=64, max_seq=64, dtype="float32")
+    with pytest.raises(chip_smoke.SmokeFailure,
+                       match="logits are off"):
+        chip_smoke.serve_decode(
+            compiles, model=model, prompt_lens=(3, 5, 9, 14),
+            buckets=(4, 16), new_tokens=10, slots=4, chunk=4, platform="cpu")
+
+
+@pytest.mark.parametrize("peak,warns", [(8.0e9, False), (15.95e9, True)],
+                         ids=["half", "pr22_resnet_peak"])
+def test_memory_says_when_the_peak_nears_the_limit(peak, warns, capsys):
+    class Device:
+        def memory_stats(self):
+            return {"peak_bytes_in_use": int(peak), "bytes_in_use": 1,
+                    "bytes_limit": 16909336064}
+
+    out = chip_smoke._memory(Device(), "some_phase")
+    assert ("warning" in out) is warns
+    assert ("some_phase" in capsys.readouterr().err) is warns
 
 
 def _convnet_without_batchnorm():
