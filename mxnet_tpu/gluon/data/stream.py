@@ -513,6 +513,7 @@ class StreamReader:
                 f"{self._world}")
         self._base = 0    # global batch index all ranks rebased from
         self._steps = 0   # batches THIS partition delivered since base
+        self._stats_due = False  # batches recorded since a stream.stats
         # -- pipeline state --------------------------------------------
         self._cv = threading.Condition()
         self._reorder = {}      # seq -> decoded sample
@@ -772,8 +773,18 @@ class StreamReader:
     def close(self):
         """Idempotent shutdown: join the reader + pool threads and
         close per-thread shard handles."""
+        self._closing_stats()
         self._drain()
         self.shardset.close()
+
+    def _closing_stats(self):
+        """One last ``stream.stats`` after the batches delivered since
+        the last one, when the reader is exhausted or closed: the
+        periodic instant of a short run (batch 1's) can precede every
+        finished read."""
+        if _obs.ENABLED and self._stats_due:
+            self._stats_due = False
+            _obs.record_stream_stats(len(self._reorder))
 
     def reset(self):
         """DataIter-protocol reset: restart this partition from the
@@ -824,11 +835,13 @@ class StreamReader:
                 break
         wait = time.perf_counter() - t0
         if len(samples) < self.batch_size:
+            self._closing_stats()
             raise StopIteration
         self._steps += 1
         batch = self._collate(samples)
         if _obs.ENABLED:
             _obs.record_stream_batch(wait, len(self._reorder))
+            self._stats_due = True
             if _obs.attribution.ENABLED:
                 _obs.attribution.note_input_wait(wait)
         return batch

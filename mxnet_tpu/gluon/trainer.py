@@ -25,7 +25,6 @@ per K steps. See docs/performance.md "superstep".
 
 from __future__ import annotations
 
-import contextlib
 import time
 
 import jax
@@ -53,13 +52,15 @@ from .parameter import Parameter, ParameterDict
 # eligibility/staleness).
 
 def _dispatch_call(site, span, fn, args):
-    """Slow-path executable invocation: marks ``site`` in flight for
-    the crash flight recorder and opens a named profiler span. Call
-    sites take this route only when the recorder is installed or a
-    profiler window is armed — the normal path stays a bare call."""
-    rec = _obs.flight.dispatch(site) if _obs.flight.INSTALLED \
-        else contextlib.nullcontext()
-    with rec, _obs.introspect.annotate(span):
+    """Slow-path executable invocation under the program's span
+    ``span``, marked in flight as ``site`` for the crash flight recorder
+    when that is installed. Call sites come here only when the recorder
+    is installed or someone is looking (``_obs.watching()``): the
+    normal path stays a bare call."""
+    with _obs.span(span, cat="train"):
+        if _obs.flight.INSTALLED:
+            with _obs.flight.dispatch(site):
+                return fn(*args)
         return fn(*args)
 
 
@@ -261,18 +262,30 @@ class Trainer:
         return out
 
     def _step_instrumented(self, batch_size, ignore_stale_grad):
-        if not _obs.ENABLED:
+        sp = _obs.span("trainer.step", cat="train")
+        if sp is _obs.NO_SPAN:
             self._step_impl(batch_size, ignore_stale_grad)
             return
         t0 = time.perf_counter()
-        gnorm = self._step_impl(batch_size, ignore_stale_grad)
+        with sp:
+            gnorm = self._step_impl(batch_size, ignore_stale_grad)
+            # the tracer's step advances inside the span: its own
+            # event, and every one after it, carries the new id
+            _obs.tracer().mark_step()
         t1 = time.perf_counter()  # span excludes any probe device sync
+        if not _obs.ENABLED:  # a profiler session alone: the span is all
+            return
         if gnorm is None:
             # eager update path: grad norm AFTER allreduce — forces one
             # device sync per step (docs/observability.md overhead notes);
             # the fused path computes it in-graph and hands back a LAZY
             # device scalar instead, so there is no extra sync at all
             gnorm = self._grad_norm()
+        if isinstance(gnorm, float):
+            # only plain floats go into the ring buffer: a lazy device
+            # scalar per event would pin one live device buffer per step
+            # for the lifetime of the ring (the gauge keeps the latest)
+            sp.set(grad_norm=gnorm)
         _obs.record_trainer_step(t0, t1, gnorm)
         if _obs.watchdog.ENABLED:
             # detector sweep at trainer cadence: a monotonic-clock
@@ -729,18 +742,17 @@ class Trainer:
                 plan["states"], lr, wd, rescale, clip,
                 plan["lr_mults"], plan["wd_mults"], scale_in, div_in,
                 unsk_in, ovf_in)
-        if _obs.flight.INSTALLED or _obs.introspect.PROFILING \
-                or _obs.introspect.ENABLED:
-            if _obs.introspect.ENABLED and not plan.get("introspected"):
-                # cost/memory analysis once per plan, from the aval
-                # skeleton (the call below donates the live buffers)
-                plan["introspected"] = True
-                _obs.introspect.register_jit(
-                    "trainer_fused", plan["fn"],
-                    _obs.introspect.avals_of(args),
-                    donated=_fusedstep.DONATE)
+        if _obs.introspect.ENABLED and not plan.get("introspected"):
+            # cost/memory analysis once per plan, from the aval
+            # skeleton (the call below donates the live buffers)
+            plan["introspected"] = True
+            _obs.introspect.register_jit(
+                "trainer_fused", plan["fn"],
+                _obs.introspect.avals_of(args),
+                donated=_fusedstep.DONATE)
+        if _obs.flight.INSTALLED or _obs.watching():
             new_ws, new_sts, gnorm, new_scale, new_unsk, new_ovf = \
-                _dispatch_call("trainer_fused", "mxtpu.fused_update",
+                _dispatch_call("trainer_fused", "trainer.fused_update",
                                plan["fn"], args)
         else:
             new_ws, new_sts, gnorm, new_scale, new_unsk, new_ovf = \
@@ -1412,20 +1424,22 @@ class Superstep:
 
     def _dispatch(self, plan, args, k):
         """One compiled superstep invocation, with the optional slow-
-        path instrumentation (cost registration, profiler window,
-        flight-recorder in-flight marking) kept off the default path."""
+        path instrumentation (cost registration, the ``MXTPU_PROFILE``
+        window's state machine, the program's span, flight-recorder
+        in-flight marking) kept off the default path."""
         intro = _obs.introspect
-        if not (intro.ENABLED or intro.PROFILING or _obs.flight.INSTALLED):
+        armed = _obs.introspect.PROFILING  # MXTPU_PROFILE's step window
+        if not (intro.ENABLED or armed or _obs.flight.INSTALLED
+                or _obs.watching()):
             return plan["fn"](*args)
         if intro.ENABLED and not plan.get("introspected"):
             plan["introspected"] = True
             intro.register_jit("superstep", plan["fn"],
                                intro.avals_of(args),
                                donated=_fusedstep.DONATE)
-        prof = intro.profile_step(k, name="superstep") if intro.PROFILING \
-            else contextlib.nullcontext()
-        with prof:
-            return _dispatch_call("superstep", "mxtpu.superstep",
+        with intro.profile_step(k, name="superstep") if armed \
+                else _obs.NO_SPAN:
+            return _dispatch_call("superstep", "trainer.superstep_dispatch",
                                   plan["fn"], args)
 
     # -- fallback / tail -------------------------------------------------

@@ -18,13 +18,6 @@ from ..ndarray.ndarray import NDArray
 from .base import KVStoreBase, register_kvstore
 
 
-import contextlib as _contextlib
-
-#: reusable no-op context for the profiler-span guards below (a
-#: nullcontext instance is reentrant and allocation-free at the sites)
-_NULL_CTX = _contextlib.nullcontext()
-
-
 def _nd_nbytes(v) -> int:
     """Payload bytes of one NDArray-like (0 when unknowable)."""
     try:
@@ -296,8 +289,7 @@ class KVStoreLocal(KVStoreBase):
                 intro.register_jit("kv_bucket", plan["fused"],
                                    (intro.avals_of(raw_groups),
                                     intro.avals_of(res)))
-            with intro.annotate("mxtpu.grad_bucket") if intro.PROFILING \
-                    else _NULL_CTX:
+            with _obs.span("kv.grad_bucket", cat="comms"):
                 merged, new_res = plan["fused"](raw_groups, res)
             n_dispatch = 1
         else:
@@ -305,15 +297,13 @@ class KVStoreLocal(KVStoreBase):
                 intro.register_jit("kv_bucket_pack", plan["pack"],
                                    (intro.avals_of(raw_groups),
                                     intro.avals_of(res)))
-            prof = intro.PROFILING
-            with intro.annotate("mxtpu.grad_pack") if prof else _NULL_CTX:
+            with _obs.span("kv.grad_pack", cat="comms"):
                 bucket_arrs, new_res = plan["pack"](raw_groups, res)
             reduce_live = not self._reduce_raw_is_identity()
-            with intro.annotate("mxtpu.grad_allreduce") if prof \
-                    else _NULL_CTX:
+            with _obs.span("kv.grad_allreduce", cat="comms"):
                 bucket_arrs = tuple(self._reduce_raw(b)
                                     for b in bucket_arrs)
-            with intro.annotate("mxtpu.grad_unpack") if prof else _NULL_CTX:
+            with _obs.span("kv.grad_unpack", cat="comms"):
                 merged = plan["unpack"](bucket_arrs)
             n_dispatch = 2 + (len(bucket_arrs) if reduce_live else 0)
         if thr is not None:

@@ -53,7 +53,13 @@ from .metrics import (  # noqa: F401
     SeriesGauge,
     DEFAULT_BUCKETS,
 )
-from .tracing import Span, Tracer, load_jsonl  # noqa: F401
+from .tracing import (  # noqa: F401
+    NO_SPAN,
+    Span,
+    Tracer,
+    load_jsonl,
+    profiler_active,
+)
 
 #: THE switch. Hot paths read this module attribute and skip all
 #: recording when False. Seeded from MXTPU_TELEMETRY (default off).
@@ -96,8 +102,22 @@ def reset():
     _TRACER.clear()
 
 
-def span(name, cat="default", **args) -> Span:
-    return _TRACER.span(name, cat=cat, **args)
+def span(name, cat="default", **args):
+    """THE span of the program. Live while someone is looking — the
+    ``ENABLED`` switch is set, or a ``jax.profiler`` session is open,
+    whoever opened it — and the shared no-op otherwise. A live span
+    lands in the profiler's trace as ``mx:<name>`` on the thread that
+    ran it and in the ring on the same clock (``tracing.Span``)."""
+    if watching():
+        return _TRACER.span(name, cat, **args)
+    return NO_SPAN
+
+
+def watching() -> bool:
+    """Whether :func:`span` hands out a live span right now; also the
+    guard for events recorded with explicit times (a request's phases
+    cross threads and go to the ring alone)."""
+    return ENABLED or profiler_active()
 
 
 # ---------------------------------------------------------------------------
@@ -745,23 +765,14 @@ def record_engine_wait(path: str, dt: float):
 
 
 def record_trainer_step(t0: float, t1: float, grad_norm=None):
-    """One Trainer.step: advances the tracer step, records the span."""
-    dt = t1 - t0
+    """One Trainer.step's counters (its ``trainer.step`` span, which
+    also advances the tracer's step, is ``Trainer.step``'s own)."""
     TRAINER_STEP_TOTAL.inc()
-    TRAINER_STEP_SECONDS.observe(dt)
+    TRAINER_STEP_SECONDS.observe(t1 - t0)
     if grad_norm is not None:
         # lazy: the fused step hands a device scalar; it syncs only when
         # the gauge is read (value()/exposition), never per step
         TRAINER_GRAD_NORM.set_lazy(grad_norm)
-    step = _TRACER.mark_step()
-    args = {"step": step}
-    if isinstance(grad_norm, float):
-        # only plain floats go into the ring buffer: storing a lazy
-        # device scalar per event would pin one live device buffer per
-        # step for the lifetime of the 65536-event ring (the gauge above
-        # keeps the latest lazy value; trace events just omit it)
-        args["grad_norm"] = grad_norm
-    _TRACER.record("trainer.step", cat="trainer", ts=t0, dur=dt, args=args)
     if attribution.ENABLED:
         attribution.record_step(t0, t1, site="trainer")
 
@@ -781,7 +792,7 @@ def record_superstep(k: int, t0: float, t1: float, grad_norm=None):
     step = None
     for _ in range(k):
         step = _TRACER.mark_step()
-    _TRACER.record("trainer.superstep", cat="trainer", ts=t0, dur=dt,
+    _TRACER.record("trainer.superstep", cat="train", ts=t0, dur=dt,
                    args={"k": int(k), "step": step})
     if attribution.ENABLED:
         attribution.record_step(t0, t1, k=k, site="superstep")
@@ -867,10 +878,7 @@ def record_stream_decode(dt: float):
 def record_stream_batch(wait: float, reorder_depth: int):
     """One batch delivered by StreamReader: consumer-wait accounting
     + the per-batch trace span telemetry_report joins against steps.
-    Every 16th batch also emits a ``stream.stats`` instant carrying
-    the cumulative per-shard read totals and decode-pool busy/wait so
-    an exported trace is self-contained for the Input-pipeline
-    section (registry counters don't travel with the JSONL)."""
+    Every 16th batch also emits the ``stream.stats`` instant (below)."""
     STREAM_BATCHES_TOTAL.inc()
     STREAM_CONSUMER_WAIT_SECONDS.inc(wait)
     STREAM_QUEUE_DEPTH.set(reorder_depth, queue="reorder")
@@ -878,24 +886,33 @@ def record_stream_batch(wait: float, reorder_depth: int):
                    ts=_time.perf_counter() - wait, dur=wait,
                    args={"consumer_wait": wait,
                          "reorder_depth": reorder_depth})
-    n = STREAM_BATCHES_TOTAL.total()
-    if n % 16 == 1:
-        per_shard = {}
-        for labels in STREAM_READ_BYTES.labelsets():
-            shard = labels.get("shard", "-")
-            per_shard[shard] = {
-                "bytes": STREAM_READ_BYTES.value(**labels),
-                "seconds": STREAM_READ_SECONDS.value(**labels),
-                "records": STREAM_RECORDS_TOTAL.value(**labels)}
-        _TRACER.record(
-            "stream.stats", cat="io", ph="i",
-            args={"per_shard": per_shard,
-                  "decode_busy": STREAM_DECODE_SECONDS.total(),
-                  "decode_wait": STREAM_DECODE_WAIT_SECONDS.total(),
-                  "consumer_wait": STREAM_CONSUMER_WAIT_SECONDS.total(),
-                  "depth_raw": STREAM_QUEUE_DEPTH.value(queue="raw"),
-                  "depth_reorder": reorder_depth,
-                  "batches": n})
+    if STREAM_BATCHES_TOTAL.total() % 16 == 1:
+        record_stream_stats(reorder_depth)
+
+
+def record_stream_stats(reorder_depth: int):
+    """The ``stream.stats`` instant: cumulative per-shard read totals
+    and decode-pool busy/wait, so an exported trace is self-contained
+    for the Input-pipeline section (registry counters don't travel with
+    the JSONL). Every 16th batch emits one, and a reader that is
+    exhausted or closed a last one: the first may come before any read
+    has finished."""
+    per_shard = {}
+    for labels in STREAM_READ_BYTES.labelsets():
+        shard = labels.get("shard", "-")
+        per_shard[shard] = {
+            "bytes": STREAM_READ_BYTES.value(**labels),
+            "seconds": STREAM_READ_SECONDS.value(**labels),
+            "records": STREAM_RECORDS_TOTAL.value(**labels)}
+    _TRACER.record(
+        "stream.stats", cat="io", ph="i",
+        args={"per_shard": per_shard,
+              "decode_busy": STREAM_DECODE_SECONDS.total(),
+              "decode_wait": STREAM_DECODE_WAIT_SECONDS.total(),
+              "consumer_wait": STREAM_CONSUMER_WAIT_SECONDS.total(),
+              "depth_raw": STREAM_QUEUE_DEPTH.value(queue="raw"),
+              "depth_reorder": reorder_depth,
+              "batches": STREAM_BATCHES_TOTAL.total()})
 
 
 def record_ckpt_tick(dt: float):

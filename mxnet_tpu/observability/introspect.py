@@ -466,8 +466,9 @@ _PROFILE = {
     "active": False, "done": False, "step": 0, "captures": 0,
 }
 
-#: True when a MXTPU_PROFILE window is armed (or profiling was started
-#: programmatically); the ONE boolean the training hot paths read.
+#: True while a MXTPU_PROFILE window is armed: the switch of
+#: ``profile_step``'s window state machine and of nothing else (the
+#: program's spans ask the profiler itself whether a session is open).
 PROFILING = False
 
 
@@ -554,17 +555,15 @@ def profile_step(k=1, name="train"):
 def profile_window(logdir):
     """Programmatic capture: everything inside the block lands in one
     ``jax.profiler`` trace under ``logdir`` (open in TensorBoard or
-    Perfetto). Composes with ``annotate()`` named spans."""
+    Perfetto), the program's own spans (``observability.span``) beside
+    the device's operations as ``mx:<name>``."""
     import jax
 
     jax.profiler.start_trace(logdir)
     _PROFILE["captures"] += 1
-    was_active = _PROFILE["active"]
-    _PROFILE["active"] = True  # annotate() spans inside the block record
     try:
         yield logdir
     finally:
-        _PROFILE["active"] = was_active
         try:
             jax.profiler.stop_trace()
         except Exception as e:
@@ -573,16 +572,11 @@ def profile_window(logdir):
 
 
 def annotate(name):
-    """Named profiler span (``jax.profiler.TraceAnnotation``) for hot
-    regions — the fused update, bucket pack/allreduce/unpack — visible
-    in the captured device trace. Returns a no-op context manager when
-    no window is active, so call sites can use it unconditionally
-    inside a ``PROFILING`` check."""
-    if not (_PROFILE["active"] or PROFILING):
-        return contextlib.nullcontext()
-    import jax
+    """Alias of ``observability.span`` from before the program had one
+    span; live whenever a profiler session is, whoever opened it."""
+    from . import span
 
-    return jax.profiler.TraceAnnotation(name)
+    return span(name, cat="profile")
 
 
 _maybe_arm_from_env()

@@ -11,8 +11,20 @@ grow memory on long runs) and export two ways:
 - ``dump_jsonl()`` — one event object per line, the format
   ``tools/telemetry_report.py`` aggregates.
 
-Timestamps are microseconds on the ``perf_counter`` clock, zeroed at
-tracer construction (chrome://tracing only needs monotonicity).
+Timestamps are epoch microseconds (``time.time_ns()``): the clock the
+``jax.profiler`` stamps its host events with, up to one constant per
+profiler session, so a ring event can be laid beside a device operation
+of the same session's ``.xplane.pb``. Callers that hand ``record()`` a
+``perf_counter`` time are mapped through one ``(perf_counter,
+time_ns)`` pair taken when the tracer is built.
+
+A :class:`Span` is the program's one span: on entry it opens a
+``jax.profiler.TraceAnnotation("mx:" + name)`` (a no-op costing half a
+microsecond outside a profiler session) and on exit appends one ring
+event carrying its ``id`` and, in ``args["parent"]``, the id of the
+innermost span open on the same thread. ``observability.span()`` hands
+one out only while someone is looking (``ENABLED`` or a profiler
+session, whoever opened it) and the shared :data:`NO_SPAN` otherwise.
 """
 
 from __future__ import annotations
@@ -24,17 +36,46 @@ import os
 import threading
 import time
 
+from jax.profiler import TraceAnnotation as _Annotation
+
 from ..base import getenv
+
+#: prefix of the program's spans in a profiler trace (the benchmark's
+#: own are ``cb:``)
+PROFILER_PREFIX = "mx:"
+
+#: True inside a ``jax.profiler`` session, whoever opened it (~40 ns)
+profiler_active = _Annotation.is_enabled
 
 
 def _default_capacity() -> int:
     return getenv("MXTPU_TRACE_BUFFER", 65536, dtype=int)
 
 
-class Span:
-    """Context manager recording one complete ("X") event on exit."""
+class _NoSpan:
+    """What ``observability.span()`` returns while nobody is looking."""
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0")
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set(self, **args):
+        pass
+
+
+NO_SPAN = _NoSpan()
+
+
+class Span:
+    """Context manager recording one complete ("X") event on exit, and
+    the same interval as ``mx:<name>`` in a profiler trace."""
+
+    __slots__ = ("_tracer", "name", "cat", "args", "id", "_t0", "_ann",
+                 "_stack")
 
     def __init__(self, tracer, name, cat, args):
         self._tracer = tracer
@@ -42,14 +83,31 @@ class Span:
         self.cat = cat
         self.args = args
 
+    def set(self, **args):
+        """Args known only once the span is under way, or just after
+        it (the ring's event holds this dict); they reach the ring, not
+        the profiler, which takes its args at entry."""
+        self.args.update(args)
+
     def __enter__(self):
-        self._t0 = time.perf_counter()
+        tr = self._tracer
+        stack = self._stack = tr._open_spans()
+        if stack:
+            self.args["parent"] = stack[-1].id
+        self.id = tr.new_span_id()
+        self._ann = _Annotation(PROFILER_PREFIX + self.name, **self.args)
+        self._ann.__enter__()
+        stack.append(self)
+        self._t0 = time.time_ns()
         return self
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter()
-        self._tracer.record(self.name, cat=self.cat,
-                            ts=self._t0, dur=t1 - self._t0, args=self.args)
+        t1 = time.time_ns()
+        self._ann.__exit__(*exc)
+        self._stack.pop()
+        self._tracer._append(self.name, self.cat, "X", self.id,
+                             self._t0 / 1e3, (t1 - self._t0) / 1e3,
+                             self.args)
         return False
 
 
@@ -59,8 +117,10 @@ class Tracer:
     def __init__(self, capacity=None):
         self._events = collections.deque(
             maxlen=capacity or _default_capacity())
-        self._epoch = time.perf_counter()
+        # one pair maps a caller's perf_counter seconds onto epoch us
+        self._pc0, self._ns0 = time.perf_counter(), time.time_ns()
         self.step = 0  # advanced by Trainer.step via mark_step()
+        self._local = threading.local()
         # span ids: process-unique, monotonic, survive clear() — parent
         # links recorded before a clear must not collide after it
         self._span_ids = itertools.count(1)
@@ -74,8 +134,33 @@ class Tracer:
 
     def new_span_id(self) -> int:
         """A process-unique span id (itertools.count — GIL-atomic).
-        Correlated child events reference it via ``args["parent"]``."""
+        Correlated child events reference it via ``args["parent"]``
+        (a :class:`Span` fills that in from its thread's open spans)."""
         return next(self._span_ids)
+
+    def _open_spans(self) -> list:
+        """This thread's stack of open spans."""
+        try:
+            return self._local.stack
+        except AttributeError:
+            stack = self._local.stack = []
+            return stack
+
+    def _append(self, name, cat, ph, span_id, ts_us, dur_us, args):
+        args["step"] = self.step
+        ev = {
+            "name": name,
+            "cat": cat,
+            "ph": ph,
+            "id": span_id,
+            "ts": ts_us,
+            "dur": dur_us,
+            "pid": os.getpid(),
+            "tid": threading.get_ident() & 0xFFFF,
+            "args": args,
+        }
+        self._events.append(ev)
+        return ev
 
     def record(self, name, cat="default", ts=None, dur=0.0, args=None,
                ph="X", span_id=None):
@@ -85,19 +170,11 @@ class Tracer:
         handing it to children as their parent)."""
         if ts is None:
             ts = time.perf_counter()
-        ev = {
-            "name": name,
-            "cat": cat,
-            "ph": ph,
-            "id": int(span_id) if span_id is not None else self.new_span_id(),
-            "ts": (ts - self._epoch) * 1e6,
-            "dur": dur * 1e6,
-            "pid": os.getpid(),
-            "tid": threading.get_ident() & 0xFFFF,
-            "args": dict(args or (), step=self.step),
-        }
-        self._events.append(ev)
-        return ev
+        return self._append(
+            name, cat, ph,
+            int(span_id) if span_id is not None else self.new_span_id(),
+            (ts - self._pc0) * 1e6 + self._ns0 / 1e3, dur * 1e6,
+            dict(args or ()))
 
     def instant(self, name, cat="default", **args):
         return self.record(name, cat=cat, dur=0.0, args=args, ph="i")

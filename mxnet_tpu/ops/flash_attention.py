@@ -228,6 +228,7 @@ def _pallas_flash_fwd(q, k, v, scale, causal, bq=512, bk=512, window=0):
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
+        name="mxtpu_flash_fwd",
     )(qr, kr, vr)
     return out.reshape(B, H, T, D), lse.reshape(B, H, T)
 
@@ -371,6 +372,7 @@ def _pallas_flash_bwd(q, k, v, out, lse, g, scale, causal, bq=512, bk=512,
         scratch_shapes=[pltpu.VMEM((t_eff, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
+        name="mxtpu_flash_bwd",
     )(qr, kr, vr, gr, lse_r, delta)
 
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
@@ -562,6 +564,7 @@ def _pallas_flash_bwd_split(q, k, v, out, lse, g, scale, causal, bq=512,
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BK, t_eff, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        name="mxtpu_flash_bwd_dq",
     )(qr, kr, vr, gr, lse_r, delta)
 
     q_spec_kv = pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, 0))
@@ -580,6 +583,7 @@ def _pallas_flash_bwd_split(q, k, v, out, lse, g, scale, causal, bq=512,
                    jax.ShapeDtypeStruct((BK, S, D), v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
+        name="mxtpu_flash_bwd_dkv",
     )(qr, kr, vr, gr, lse_r, delta)
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
 
@@ -760,6 +764,7 @@ def _pallas_paged_decode(q, k_pool, v_pool, tables, lens, scale,
         out_shape=jax.ShapeDtypeStruct((B, KVH, group, D), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="mxtpu_paged_decode",
     )(tables, lens, qr, k_pool, v_pool)
     return out.reshape(B, H, D)
 

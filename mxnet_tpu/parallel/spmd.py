@@ -1023,6 +1023,10 @@ class SPMDTrainStep:
             with autograd.predict_mode():
                 self.block(xin)
             self.init_state()
+        with _obs.span("spmd.step", cat="train"):
+            return self._step(x, y, lr, sync)
+
+    def _step(self, x, y, lr, sync):
         raw_x = x.data if isinstance(x, NDArray) else jnp.asarray(x)
         raw_y = y.data if isinstance(y, NDArray) else jnp.asarray(y)
         if self.mesh is not None:
@@ -1059,11 +1063,12 @@ class SPMDTrainStep:
                 _obs.introspect.avals_of(args), donated=self._donate)
         att = _obs.ENABLED and _obs.attribution.ENABLED
         t0 = time.perf_counter() if att else 0.0
-        if _obs.flight.INSTALLED:
-            with _obs.flight.dispatch("spmd_step"):
+        with _obs.span("spmd.dispatch", cat="train"):
+            if _obs.flight.INSTALLED:
+                with _obs.flight.dispatch("spmd_step"):
+                    out = self._compiled(*args)
+            else:
                 out = self._compiled(*args)
-        else:
-            out = self._compiled(*args)
         if _obs.ENABLED:
             _obs.record_xla_dispatch("spmd_step")
             if att:
@@ -1089,16 +1094,19 @@ class SPMDTrainStep:
         params, opt_states = self._state
         att = _obs.ENABLED and _obs.attribution.ENABLED
         t0 = time.perf_counter() if att else 0.0
-        gstack, austack, lstack = st["bwd"](params, raw_x, raw_y, key)
+        with _obs.span("spmd.dispatch", cat="train", leg="bwd"):
+            gstack, austack, lstack = st["bwd"](params, raw_x, raw_y, key)
         tc = time.perf_counter() if att else 0.0
-        reds, auxs, loss = st["comm"](gstack, austack, lstack)
+        with _obs.span("spmd.dispatch", cat="train", leg="comm"):
+            reds, auxs, loss = st["comm"](gstack, austack, lstack)
         if att:
             # the comm leg is a separate host-driven dispatch here —
             # its host-side span IS observable, so attribution gets a
             # measured figure instead of the overlap-probe hint
             _obs.attribution.note_comm(time.perf_counter() - tc)
-        new_params, new_states = st["upd"](params, opt_states, reds,
-                                           auxs, lr_arr)
+        with _obs.span("spmd.dispatch", cat="train", leg="upd"):
+            new_params, new_states = st["upd"](params, opt_states, reds,
+                                               auxs, lr_arr)
         if _obs.ENABLED:
             _obs.record_xla_dispatch("spmd_step", 3)
             if att:

@@ -147,8 +147,8 @@ def sample_tokens(logits, key, temperature, top_k, top_p, greedy):
 
 class _GenRequest:
     __slots__ = ("prompt", "max_new", "temperature", "top_k", "top_p",
-                 "greedy", "seed", "eos", "deadline", "t_submit",
-                 "tokens", "t_first", "t_last", "event", "result",
+                 "greedy", "seed", "eos", "deadline", "rid", "t_submit",
+                 "t_admit", "tokens", "t_first", "t_last", "event", "result",
                  "error", "version", "claimed", "cancelled",
                  "_state_lock")
 
@@ -163,7 +163,10 @@ class _GenRequest:
         self.seed = int(seed)
         self.eos = int(eos)
         self.deadline = deadline  # absolute perf_counter time, or None
+        # the id its ``req*`` and ``gen.prefill`` spans share
+        self.rid = _obs.tracer().new_span_id()
         self.t_submit = time.perf_counter()
+        self.t_admit = None  # claim() won and its blocks are allocated
         self.tokens = []
         self.t_first = None
         self.t_last = None
@@ -565,19 +568,53 @@ class GenerationEngine:
                 self._idle.set()
                 if closing:
                     return
-            self._work.wait(0.02)
+            with _obs.span("gen.idle", cat="generation"):
+                self._work.wait(0.02)
             self._work.clear()
 
     def _fail(self, req, err, code):
         self._failed += 1
         if _obs.ENABLED:
             _obs.record_serve_request(self._name, code)
+        self._trace_request(req, code)
         req.finish(error=err, version=self._version)
+
+    def _trace_request(self, req, outcome):
+        """One finished or failed request's phases into the ring, from
+        the stamps on it. They cross threads (submitted on the client's,
+        finished here), so they go to the ring with explicit times and
+        not to the profiler. ``req`` is submit -> its last token's
+        stamp (now, for one that failed), ``req.queue`` submit ->
+        admitted, ``req.prefill`` -> first token, ``req.decode`` -> last
+        token; all four under one ``rid``."""
+        if not _obs.watching():
+            return
+        ring = _obs.tracer()
+        end = req.t_last if outcome == "ok" else time.perf_counter()
+        rid = req.rid
+        ring.record("req", cat="request", ts=req.t_submit,
+                    dur=end - req.t_submit, span_id=rid,
+                    args={"rid": rid, "outcome": outcome,
+                          "prompt_len": len(req.prompt),
+                          "tokens": len(req.tokens)})
+        phases = [("req.queue", req.t_submit, req.t_admit or end)]
+        if req.t_admit is not None and req.t_first is not None:
+            phases += [("req.prefill", req.t_admit, req.t_first),
+                       ("req.decode", req.t_first, req.t_last)]
+        for name, t0, t1 in phases:
+            ring.record(name, cat="request", ts=t0, dur=t1 - t0,
+                        args={"rid": rid, "parent": rid})
 
     def _admit(self):
         """Join queued requests to idle slots (iteration-level
         scheduling): sweep deadlines, then prefill into free slots
         while the cache can back the prompt."""
+        with _obs.span("gen.admit", cat="generation") as sp:
+            sp.set(admitted=self._admit_queued())
+
+    def _admit_queued(self) -> int:
+        """``_admit``'s work; returns how many requests it prefilled."""
+        admitted = 0
         now = time.perf_counter()
         with self._lock:
             q = list(self._queue)
@@ -597,11 +634,11 @@ class GenerationEngine:
             free = [s for s in range(self._slots) if not self._active[s]
                     and self._slot_req[s] is None]
             if not free:
-                return
+                return admitted
             with self._lock:
                 req = self._queue.popleft() if self._queue else None
             if req is None:
-                return
+                return admitted
             if not req.claim():  # lost to cancel()
                 continue
             try:
@@ -614,9 +651,11 @@ class GenerationEngine:
                         req.claimed = False
                     with self._lock:
                         self._queue.appendleft(req)
-                    return
+                    return admitted
                 self._fail(req, e, "shed")
                 continue
+            req.t_admit = time.perf_counter()
+            admitted += 1
             try:
                 self._prefill(req, table, free[0])
             except BaseException as e:  # noqa: BLE001 - typed to waiter
@@ -625,16 +664,21 @@ class GenerationEngine:
                            ServingError(f"prefill failed: {e}"), "error")
 
     def _prefill(self, req, table, slot):
-        import jax.numpy as jnp
-
         plen = len(req.prompt)
         tb = self._bucket_for(plen)
+        with _obs.span("gen.prefill", cat="generation", rid=req.rid,
+                       bucket=tb, prompt_len=plen, slot=slot):
+            self._prefill_traced(req, table, slot, plen, tb)
+
+    def _prefill_traced(self, req, table, slot, plen, tb):
+        import jax.numpy as jnp
+
         padded = _np.zeros((1, tb), _np.int32)
         padded[0, :plen] = req.prompt
         k, v = self.cache.pools()
-        t0 = time.perf_counter()
-        tok, k, v = self._prefill_exes[tb](
-            self._params, jnp.asarray(padded), k, v,
+        t0 = time.perf_counter()  # dt: staging, the call and the sync
+        operands = (
+            jnp.asarray(padded), k, v,
             table.device_row(self._mb)[None, :],
             _np.array([plen], _np.int32),  # mxtpu-lint: host-sync-ok
             _np.array([req.seed], _np.int32),  # mxtpu-lint: host-sync-ok
@@ -642,10 +686,13 @@ class GenerationEngine:
             _np.array([req.top_k], _np.int32),  # mxtpu-lint: host-sync-ok
             _np.array([req.top_p], _np.float32),  # mxtpu-lint: host-sync-ok
             _np.array([req.greedy], bool))  # host operand staging  # mxtpu-lint: host-sync-ok
-        self.cache.update_pools(k, v)
-        # the ONE deliberate per-request sync: the first token decides
-        # retire-or-seat before the next chunk can include this slot
-        first = int(_np.asarray(tok)[0])  # mxtpu-lint: host-sync-ok
+        with _obs.span("gen.prefill.device", cat="generation", bucket=tb):
+            tok, k, v = self._prefill_exes[tb](self._params, *operands)
+            self.cache.update_pools(k, v)
+            # the ONE deliberate per-request sync: the first token
+            # decides retire-or-seat before the next chunk can include
+            # this slot
+            first = int(_np.asarray(tok)[0])  # mxtpu-lint: host-sync-ok
         dt = time.perf_counter() - t0
         table.length = plen
         self._prefills += 1
@@ -682,10 +729,30 @@ class GenerationEngine:
         """One decode dispatch: every active slot advances up to
         ``chunk`` tokens; retirements free their slots and cache blocks
         at the boundary (where the NEXT _admit can seat a newcomer)."""
-        import jax.numpy as jnp
+        with _obs.span("gen.chunk", cat="generation") as sp:
+            with _obs.span("gen.chunk.prep", cat="generation"):
+                tables = self._grow_tables()
+                if tables is None:
+                    return
+                t0 = time.perf_counter()  # dt: staging, the call and the syncs
+                operands = self._chunk_operands(tables)
+            if sp is not _obs.NO_SPAN:
+                # read after the chunk's growth, where the pool is fullest
+                sp.set(blocks_used=self.cache.blocks_used())
+            with _obs.span("gen.chunk.device", cat="generation",
+                           steps=self._chunk):
+                toks, flags = self._run_chunk(operands)
+            dt = time.perf_counter() - t0
+            with _obs.span("gen.chunk.deliver", cat="generation") as out:
+                emitted, retired = self._deliver(toks, flags, dt)
+                out.set(emitted=emitted, retired=retired)
 
-        # back the chunk's cache growth per slot; a pool too full to
-        # grow a sequence retires that request early (typed OOM)
+    def _grow_tables(self):
+        """Backs the chunk's cache growth slot by slot; the slots'
+        block tables as one ``(slots, max_blocks)`` array, or ``None``
+        when no slot is left to step."""
+        # a pool too full to grow a sequence retires that request early
+        # (typed OOM)
         for s in range(self._slots):
             if not self._active[s]:
                 continue
@@ -701,21 +768,30 @@ class GenerationEngine:
                 self._clear_slot(s)
                 self._fail(req, e, "shed")
         if not self._active.any():
-            return
+            return None
         tables = _np.zeros((self._slots, self._mb), _np.int32)
         for s in range(self._slots):
             if self._slot_tables[s] is not None:
                 tables[s] = self._slot_tables[s].device_row(self._mb)
+        return tables
+
+    def _chunk_operands(self, tables):
+        """Stages the chunk's operands."""
+        import jax.numpy as jnp
+
         k, v = self.cache.pools()
-        t0 = time.perf_counter()
-        (k, v, lens, token, active, remaining, rng, toks, flags) = \
-            self._chunk_exe(
-                self._params, k, v, jnp.asarray(tables),
+        return (k, v, jnp.asarray(tables),
                 jnp.asarray(self._lens), jnp.asarray(self._token),
                 jnp.asarray(self._active), jnp.asarray(self._remaining),
                 self._rng, jnp.asarray(self._temp),
                 jnp.asarray(self._topk), jnp.asarray(self._topp),
                 jnp.asarray(self._greedy), jnp.asarray(self._eos))
+
+    def _run_chunk(self, operands):
+        """The chunk's executable, to the last byte the scheduler needs
+        of it: ``(tokens, emitted flags)``, each ``(chunk, slots)``."""
+        (k, v, lens, token, active, remaining, rng, toks, flags) = \
+            self._chunk_exe(self._params, *operands)
         self.cache.update_pools(k, v)
         self._rng = rng
         # ONE host sync per chunk: everything the scheduler needs
@@ -727,11 +803,16 @@ class GenerationEngine:
         self._token = _np.array(token)  # mxtpu-lint: host-sync-ok
         self._active = _np.array(active)  # mxtpu-lint: host-sync-ok
         self._remaining = _np.array(remaining)  # mxtpu-lint: host-sync-ok
-        dt = time.perf_counter() - t0
+        return toks, flags
+
+    def _deliver(self, toks, flags, dt):
+        """Hands each slot its tokens of a chunk that took ``dt``
+        seconds and retires what finished; ``(tokens emitted, requests
+        retired)``."""
         self._decode_wall += dt
         self._chunks += 1
         now = time.perf_counter()
-        emitted_total = 0
+        emitted_total = retired = 0
         for s in range(self._slots):
             req = self._slot_req[s]
             if req is None:
@@ -757,6 +838,7 @@ class GenerationEngine:
                 table = self._slot_tables[s]
                 self._clear_slot(s)
                 self._retire(req, table)
+                retired += 1
         self._tokens += emitted_total
         if _obs.ENABLED:
             _obs.record_xla_dispatch("decode_chunk")
@@ -767,6 +849,7 @@ class GenerationEngine:
             _obs.DECODE_ACTIVE_SLOTS.set(
                 int(self._active.sum()),  # host numpy mirror  # mxtpu-lint: host-sync-ok
                 model=self._name)
+        return emitted_total, retired
 
     def _clear_slot(self, s):
         self._slot_req[s] = None
@@ -783,6 +866,7 @@ class GenerationEngine:
             _obs.record_serve_request(self._name, "ok")
             _obs.SERVE_LATENCY_SECONDS.observe(
                 time.perf_counter() - req.t_submit, model=self._name)
+        self._trace_request(req, "ok")
         req.finish(result=_np.asarray(req.tokens, _np.int32),
                    version=self._version)
 

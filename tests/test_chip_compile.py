@@ -57,9 +57,18 @@ def on_chip(topo):
     return functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
 
 
-def _compiles_to_kernel(fn, *shapes):
+def _compiles_to_kernel(fn, *shapes, names):
+    """Compiles, and finds each kernel in the HLO as an instruction
+    named after its ``pl.pallas_call(name=)``: the trace's operation
+    lines are these lines, and the benchmark's per-kernel metrics match
+    the names."""
     hlo = jax.jit(fn).lower(*shapes).compile().as_text()
-    assert "tpu_custom_call" in hlo
+    calls = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == len(names)
+    for name in names:
+        assert any(line.lstrip().startswith(f"%{name}.")
+                   or line.lstrip().startswith(f"%{name} ")
+                   for line in calls), (name, calls)
 
 
 # (B, H, T, D), causal, window — BERT-base's attention, the r04 flash
@@ -79,7 +88,7 @@ def test_flash_fwd_compiles_for_v5e(on_chip, case):
         lambda q, k, v: fa._pallas_flash_fwd(
             q, k, v, shape[-1] ** -0.5, causal, bq=_BLOCK, bk=_BLOCK,
             window=window),
-        qkv, qkv, qkv)
+        qkv, qkv, qkv, names=["mxtpu_flash_fwd"])
 
 
 @pytest.mark.parametrize("case", ["bert_base", "causal_4k"])
@@ -91,7 +100,8 @@ def test_flash_bwd_split_compiles_for_v5e(on_chip, case):
         lambda q, k, v, o, l, g: fa._pallas_flash_bwd_split(
             q, k, v, o, l, g, shape[-1] ** -0.5, causal, bq=_BLOCK,
             bk=_BLOCK, window=window),
-        qkv, qkv, qkv, qkv, lse, qkv)
+        qkv, qkv, qkv, qkv, lse, qkv,
+        names=["mxtpu_flash_bwd_dq", "mxtpu_flash_bwd_dkv"])
 
 
 # B, H, KVH, D, block_size, num_blocks, max_blocks per sequence
@@ -107,4 +117,4 @@ def test_paged_decode_compiles_for_v5e(on_chip, B, H, KVH, D, bs, nb, mb):
     _compiles_to_kernel(
         lambda q, k, v, t, l: fa._pallas_paged_decode(q, k, v, t, l,
                                                       D ** -0.5),
-        q, pool, pool, tables, lens)
+        q, pool, pool, tables, lens, names=["mxtpu_paged_decode"])

@@ -375,3 +375,136 @@ def test_local_replica_serves_decoder_spec():
         assert list(got) == list(want)
     finally:
         rep.close()
+
+
+# ---------------------------------------------------------------------------
+# the scheduler's and the requests' spans under a profiler session
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def traced(net, tmp_path_factory):
+    """One profiler session over an engine of its own serving a dozen
+    requests with telemetry off: the ring's events of that session, the
+    requests' answers and the cache's counters."""
+    import jax
+
+    obs.set_enabled(False)
+    obs.reset()
+    e = GenerationEngine(net, BUCKETS, slots=SLOTS, chunk=CHUNK,
+                         queue_cap=64, cache_blocks=96,
+                         cache_block_size=4, name="gen-traced")
+    cap = {}
+    try:
+        e.predict(np.array([1, 2, 3], np.int32), max_new_tokens=4,
+                  timeout=60.0)  # spans outside a session: none recorded
+        cap["ring_before"] = len(obs.tracer())
+        try:
+            jax.profiler.start_trace(str(tmp_path_factory.mktemp("prof")))
+        except Exception as err:  # pragma: no cover - env-specific plugin
+            pytest.skip(f"jax profiler unavailable here: {err}")
+        try:
+            rng = np.random.RandomState(0)
+            futs = []
+            for i in range(12):
+                prompt = rng.randint(1, VOCAB, size=rng.randint(2, 15))
+                futs.append((prompt, 6 + 3 * (i % 5), e.submit(
+                    prompt.astype(np.int32), max_new_tokens=6 + 3 * (i % 5))))
+                time.sleep(0.002)
+            cap["answers"] = [(p, n, f.result(120.0)) for p, n, f in futs]
+            time.sleep(0.05)  # a few idle turns
+        finally:
+            jax.profiler.stop_trace()
+        cap["stats"] = e.cache.stats()
+        # the module's other engine idles on a thread of its own
+        tid = e._thread.ident & 0xFFFF
+        cap["ring"] = [ev for ev in obs.tracer().events()
+                       if ev["cat"] != "generation" or ev["tid"] == tid]
+    finally:
+        e.close()
+        obs.reset()
+    return cap
+
+
+def _cat(cap, cat):
+    return [ev for ev in cap["ring"] if ev["cat"] == cat]
+
+
+def test_scheduler_spans_tile_its_thread(traced):
+    assert traced["ring_before"] == 0  # off and unwatched: nothing
+    gen = sorted(_cat(traced, "generation"), key=lambda ev: ev["ts"])
+    ids = {ev["id"] for ev in gen}
+    top = [ev for ev in gen if ev["args"].get("parent") not in ids]
+    assert {ev["name"] for ev in top} <= {
+        "gen.admit", "gen.chunk", "gen.idle",
+        # a span open when the session began is not recorded, and its
+        # children stand as top-level spans
+        "gen.prefill", "gen.prefill.device", "gen.chunk.prep",
+        "gen.chunk.device", "gen.chunk.deliver"}
+    assert {"gen.admit", "gen.chunk", "gen.idle"} \
+        <= {ev["name"] for ev in top}
+    for a, b in zip(top, top[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"] + 1.0, (a, b)  # no overlap
+    # device waits + host self time + idle are the thread's whole time
+    children = {}
+    for ev in gen:
+        children.setdefault(ev["args"].get("parent"), []).append(ev)
+    own = {ev["id"]: ev["dur"] - sum(c["dur"]
+                                     for c in children.get(ev["id"], ()))
+           for ev in gen}
+    assert all(v >= -1.0 for v in own.values())
+    thread = max(ev["ts"] + ev["dur"] for ev in gen) - gen[0]["ts"]
+    assert sum(own.values()) <= thread + 1.0
+    # within 2 %: what lies between two spans is the loop's bookkeeping.
+    # Under six test workers the thread is also descheduled there for
+    # milliseconds at a time, so the typical gap is held to the 2 % and
+    # the whole of them to a looser bound
+    gaps = sorted(b["ts"] - (a["ts"] + a["dur"]) for a, b in zip(top, top[1:]))
+    assert gaps[len(gaps) // 2] * len(gaps) < 0.02 * thread
+    assert sum(own.values()) > 0.85 * thread
+    # every prefill sits in an admit, every phase of a chunk in a chunk
+    by_id = {ev["id"]: ev for ev in gen}
+    for ev in gen:
+        parent = by_id.get(ev["args"].get("parent"))
+        if parent is not None:
+            assert parent["name"] == {
+                "gen.prefill": "gen.admit",
+                "gen.prefill.device": "gen.prefill"}.get(
+                    ev["name"], "gen.chunk")
+
+
+def test_request_phases_share_a_rid_and_sum_to_the_request(traced):
+    reqs = _cat(traced, "request")
+    whole = [ev for ev in reqs if ev["name"] == "req"]
+    assert len(whole) == len(traced["answers"]) == 12
+    prefills = {ev["args"]["rid"]: ev for ev in _cat(traced, "generation")
+                if ev["name"] == "gen.prefill"}
+    asked = sorted((len(p), n) for p, n, toks in traced["answers"])
+    assert sorted((ev["args"]["prompt_len"], ev["args"]["tokens"])
+                  for ev in whole) == asked
+    for ev in whole:
+        rid = ev["args"]["rid"]
+        assert ev["id"] == rid and ev["args"]["outcome"] == "ok"
+        parts = {p["name"]: p for p in reqs
+                 if p["args"]["rid"] == rid and p is not ev}
+        assert set(parts) == {"req.queue", "req.prefill", "req.decode"}
+        assert all(p["args"]["parent"] == rid for p in parts.values())
+        assert sum(p["dur"] for p in parts.values()) \
+            == pytest.approx(ev["dur"], abs=1000.0)  # 1 ms, in us
+        assert parts["req.queue"]["ts"] == pytest.approx(ev["ts"], abs=1.0)
+        # the request's own prefill ran inside its req.prefill phase
+        pf = prefills[rid]
+        assert pf["args"]["prompt_len"] == ev["args"]["prompt_len"]
+        assert parts["req.prefill"]["ts"] <= pf["ts"] + 1000.0
+
+
+def test_every_chunk_carries_the_pools_blocks_in_use(traced):
+    chunks = [ev for ev in _cat(traced, "generation")
+              if ev["name"] == "gen.chunk"]
+    seen = [ev["args"]["blocks_used"] for ev in chunks]
+    st = traced["stats"]
+    assert seen and all(1 <= n < st["num_blocks"] for n in seen)
+    # read after the chunk's growth: the longest answer's blocks (of 4
+    # tokens) were all in use at its last chunk
+    longest = max(len(p) + n for p, n, toks in traced["answers"])
+    assert max(seen) >= (longest - 1) // st["block_size"]
+    assert st["blocks_used"] == 0  # all retired

@@ -6,7 +6,7 @@ renders the ``profiler.dumps``-style table (Name / Total Count /
 Time (ms) / Min / Max / Avg), aggregated per event name::
 
     python tools/telemetry_report.py trace.jsonl
-    python tools/telemetry_report.py trace.jsonl --cat trainer --sort avg
+    python tools/telemetry_report.py trace.jsonl --cat train --sort avg
 
 Pure stdlib on purpose — the report runs anywhere (CI artifact hosts,
 laptops without jax) and in milliseconds.
@@ -621,7 +621,7 @@ def main(argv=None):
         description="Aggregate a mxnet_tpu telemetry JSONL trace")
     ap.add_argument("trace", help="path to the JSONL file ('-' for stdin)")
     ap.add_argument("--cat", default=None,
-                    help="only events of this category (e.g. trainer, "
+                    help="only events of this category (e.g. train, "
                          "compile, comms)")
     ap.add_argument("--sort", default="total", choices=sorted(_SORTS),
                     help="sort column (default: total)")

@@ -9,7 +9,8 @@ whatever threads happened to record them. This tool reconstructs the
 timeline the way an operator reads it:
 
 - one named TRACK per subsystem (train loop / attribution / prefetcher /
-  collectives / checkpoint writer / serving batcher / compile / watchdog),
+  collectives / checkpoint writer / serving batcher / generation
+  scheduler / requests / compile / watchdog),
   mapped from each event's category and stably ordered;
 - ``step.phases`` attribution spans EXPANDED into stacked per-phase
   child slices (input_wait -> h2d -> ckpt_overhead -> comm_exposed ->
@@ -37,12 +38,14 @@ import sys
 #: (track title, predicate over event) — first match wins; order is the
 #: top-to-bottom track order in the viewer
 TRACKS = (
-    ("train loop", lambda ev: ev.get("cat") == "trainer"),
+    ("train loop", lambda ev: ev.get("cat") in ("trainer", "train")),
     ("attribution", lambda ev: ev.get("cat") == "attribution"),
     ("prefetcher", lambda ev: ev.get("cat") == "io"),
     ("collectives", lambda ev: ev.get("cat") == "comms"),
     ("checkpoint writer", lambda ev: ev.get("cat") == "resilience"),
     ("serving batcher", lambda ev: ev.get("cat") == "serving"),
+    ("generation", lambda ev: ev.get("cat") == "generation"),
+    ("request", lambda ev: ev.get("cat") == "request"),
     ("compile", lambda ev: ev.get("cat") == "compile"),
     ("watchdog", lambda ev: ev.get("cat") == "watchdog"),
 )
