@@ -365,8 +365,9 @@ def _decode_case(B, H, KVH, D, bs, mb, dtype, seed):
     lens[:4] = (0, 1, bs, mb * bs)  # empty slot, one token, block edge, full
     lens = jnp.asarray(lens, jnp.int32)
     got = jax.jit(fa.paged_decode_attention)(q, k_pool, v_pool, tables, lens)
-    ref = jax.jit(lambda *a: fa._jnp_paged_decode(*a, D ** -0.5))(
-        q, k_pool, v_pool, tables, lens)
+    ref = jax.jit(lambda q, k, v, t, l: fa._jnp_paged_decode(
+        q, k.reshape(1, nb, bs, -1), v.reshape(1, nb, bs, -1), t, l,
+        D ** -0.5))(q, k_pool, v_pool, tables, lens)
     _require(not np.asarray(got, np.float32)[0].any(),
              "paged decode: an empty slot did not return zeros")
     return {"out": _rel_err(got, ref)}

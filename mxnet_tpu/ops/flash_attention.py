@@ -685,17 +685,17 @@ flash_attention_core.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
 def _paged_decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_scr, l_scr, acc_scr, *, scale, bs, mb, kvh):
+                         m_scr, l_scr, acc_scr, *, scale, bs, mb, kvh, d):
     """One grid step per (sequence, kv block): grid ``(B, max_blocks)``.
     The block tables and context lengths ride the scalar-prefetch lane,
-    so each step's K/V DMA source address is ``tables[seq, j]`` — the
-    pool block, ALL kv heads of it: a ``(1, bs, KVH, D)`` window spans
-    the pool's last two dimensions, which is what Mosaic's tiling rule
-    asks of a block (a one-head ``(1, bs, 1, D)`` window is refused).
-    Mosaic double-buffers the NEXT block's fetch against THIS block's
-    compute. Online softmax in fp32 VMEM scratch per kv head, exactly
-    the prefill kernel's recurrence with q_len = group (the GQA query
-    heads of one kv head)."""
+    so each step's K/V DMA source address is ``(layer, tables[seq, j])``
+    — the pool block of the call's layer, ALL kv heads of it: a ``(1, 1,
+    bs, KVH*D)`` window spans the pool's last two dimensions, which is
+    what Mosaic's tiling rule asks of a block; a head is a static slice
+    of ``D`` lanes of it. Mosaic double-buffers the NEXT block's fetch
+    against THIS block's compute. Online softmax in fp32 VMEM scratch
+    per kv head, exactly the prefill kernel's recurrence with q_len =
+    group (the GQA query heads of one kv head)."""
     seq = pl.program_id(0)
     j = pl.program_id(1)
     ctx = lens_ref[seq]
@@ -711,8 +711,8 @@ def _paged_decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
     def _compute():
         for h in range(kvh):
             q = q_ref[0, h]                              # (group, d)
-            k = k_ref[0, :, h, :]                        # (bs, d)
-            v = v_ref[0, :, h, :]                        # (bs, d)
+            k = k_ref[0, 0, :, h * d:(h + 1) * d]        # (bs, d)
+            v = v_ref[0, 0, :, h * d:(h + 1) * d]        # (bs, d)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
@@ -735,18 +735,24 @@ def _paged_decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def _pallas_paged_decode(q, k_pool, v_pool, tables, lens, scale,
-                         interpret=False):
+                         layer=0, interpret=False):
+    """``k_pool``/``v_pool`` are the WHOLE pool ``(layers, num_blocks,
+    block_size, KVH*D)``; ``layer`` (a static int) goes into the K/V
+    index map, so the kernel's DMAs address the layer's blocks inside
+    the pool and no slice of it is ever made."""
     B, H, D = q.shape
-    _, bs, KVH, _ = k_pool.shape
+    _, _, bs, width = k_pool.shape
+    KVH = width // D
     mb = tables.shape[1]
     group = H // KVH
     qr = q.reshape(B, KVH, group, D)
     q_spec = pl.BlockSpec((1, KVH, group, D),
                           lambda i, j, tables, lens: (i, 0, 0, 0))
     # the indirection: this grid step's K/V block is whichever POOL
-    # block the sequence's table names for logical block j
-    kv_spec = pl.BlockSpec((1, bs, KVH, D),
-                           lambda i, j, tables, lens: (tables[i, j], 0, 0, 0))
+    # block of this layer the sequence's table names for logical block j
+    kv_spec = pl.BlockSpec(
+        (1, 1, bs, width),
+        lambda i, j, tables, lens: (layer, tables[i, j], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, mb),
@@ -760,7 +766,7 @@ def _pallas_paged_decode(q, k_pool, v_pool, tables, lens, scale,
     )
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, scale=scale, bs=bs, mb=mb,
-                          kvh=KVH),
+                          kvh=KVH, d=D),
         out_shape=jax.ShapeDtypeStruct((B, KVH, group, D), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
@@ -769,15 +775,18 @@ def _pallas_paged_decode(q, k_pool, v_pool, tables, lens, scale,
     return out.reshape(B, H, D)
 
 
-def _jnp_paged_decode(q, k_pool, v_pool, tables, lens, scale):
+def _jnp_paged_decode(q, k_pool, v_pool, tables, lens, scale, layer=0):
     """CPU path + oracle: materialize each slot's context via the same
-    table gather the kernel's index map performs, then masked softmax."""
+    ``(layer, table)`` gather the kernel's index map performs (one
+    indexing step into the whole pool, no layer's slice in between),
+    then masked softmax."""
     B, H, D = q.shape
-    _, bs, KVH, _ = k_pool.shape
+    _, _, bs, width = k_pool.shape
+    KVH = width // D
     mb = tables.shape[1]
     S = mb * bs
-    k = k_pool[tables].reshape(B, S, KVH, D)
-    v = v_pool[tables].reshape(B, S, KVH, D)
+    k = k_pool[layer, tables].reshape(B, S, KVH, D)
+    v = v_pool[layer, tables].reshape(B, S, KVH, D)
     group = H // KVH
     if group > 1:
         k = jnp.repeat(k, group, axis=2)
@@ -798,14 +807,19 @@ def _jnp_paged_decode(q, k_pool, v_pool, tables, lens, scale):
 
 @register("paged_decode_attention")
 def paged_decode_attention(query, k_pool, v_pool, block_tables,
-                           context_lens, scale=None):
+                           context_lens, scale=None, layer=None):
     """Decode-specialized attention: ``query`` is one new token per
-    sequence, ``(B, H, D)``; K/V live in ONE layer's slice of the paged
-    pool, ``(num_blocks, block_size, KVH, D)``; ``block_tables``
-    ``(B, max_blocks)`` int32 names each sequence's pool blocks in
-    logical order and ``context_lens`` ``(B,)`` int32 is how many
-    positions are valid (rows past it — padding and the null block —
-    are masked).
+    sequence, ``(B, H, D)``. With ``layer`` (a static int) K/V are the
+    WHOLE paged pool as :class:`~mxnet_tpu.serving.PagedKVCache` keeps
+    it, ``(layers, num_blocks, block_size, KVH*D)``, read in place: the
+    index goes into the kernel's index map, so no layer's slice of the
+    pool is ever materialised (a ``k_pool[li]`` operand is a copy of
+    that layer per call). Without ``layer`` they are one layer with the
+    heads apart, ``(num_blocks, block_size, KVH, D)`` — the same path
+    over a pool of one layer. ``block_tables`` ``(B, max_blocks)`` int32
+    names each sequence's pool blocks in logical order and
+    ``context_lens`` ``(B,)`` int32 is how many positions are valid
+    (rows past it — padding and the null block — are masked).
 
     TPU path: one grid step per (sequence, kv block) with the
     tables/lengths scalar-prefetched so the index map itself performs
@@ -822,16 +836,26 @@ def paged_decode_attention(query, k_pool, v_pool, block_tables,
     free list in :mod:`mxnet_tpu.serving.kvcache`)."""
     if scale is None:
         scale = 1.0 / (query.shape[-1] ** 0.5)
-    if query.shape[1] % k_pool.shape[2] != 0:
+    D = query.shape[-1]
+    if layer is None:
+        nb, bs = k_pool.shape[:2]
+        k_pool = k_pool.reshape(1, nb, bs, -1)
+        v_pool = v_pool.reshape(1, nb, bs, -1)
+        layer = 0
+    if k_pool.ndim != 4 or not 0 <= layer < k_pool.shape[0]:
+        raise ValueError(
+            "the pool is (layers, num_blocks, block_size, KVH*D) with "
+            f"layer=, or one layer (num_blocks, block_size, KVH, D); got "
+            f"{k_pool.shape} and layer={layer}")
+    kvh, rest = divmod(k_pool.shape[-1], D)
+    if rest or kvh == 0 or query.shape[1] % kvh != 0:
         raise ValueError("query heads must be a multiple of kv heads; got "
-                         f"{query.shape[1]} vs {k_pool.shape[2]}")
+                         f"{query.shape[1]} vs {k_pool.shape[-1]}/{D}")
     tables = block_tables.astype(jnp.int32)
     lens = context_lens.astype(jnp.int32)
-    if _use_pallas(query.shape[-1]):
-        return _pallas_paged_decode(query, k_pool, v_pool, tables, lens,
-                                    float(scale))
-    return _jnp_paged_decode(query, k_pool, v_pool, tables, lens,
-                             float(scale))
+    decode = _pallas_paged_decode if _use_pallas(D) else _jnp_paged_decode
+    return decode(query, k_pool, v_pool, tables, lens, float(scale),
+                  layer=int(layer))
 
 
 @register("flash_attention", aliases=("_contrib_flash_attention",))

@@ -13,8 +13,9 @@ The SAME math is exposed three ways, which is what the correctness
 tests pin against each other:
 
 - :meth:`forward_fn` — dense full-context causal forward (the oracle);
-- :meth:`prefill_fn` — dense over the prompt, but scattering each
-  layer's K/V into the paged pool through the request's block table;
+- :meth:`prefill_fn` — dense over the prompt, then one scatter of
+  every layer's K/V into the paged pool through the request's block
+  table;
 - :meth:`decode_step_fn` — one token per sequence, K/V appended to the
   pool and attention read back through
   :func:`~mxnet_tpu.ops.flash_attention.paged_decode_attention`.
@@ -195,27 +196,30 @@ class TransformerDecoderLM:
 
     def prefill_fn(self):
         """Prompt ingestion: dense causal forward over ONE padded
-        prompt, scattering every layer's K/V into the paged pool
-        through the request's block table. ``(params, tokens[1, Tb],
-        k_pool, v_pool, table[1, mb], length[1]) -> (logits[1, V],
-        k_pool, v_pool)`` — logits are at the LAST REAL position
-        (``length - 1``); pad positions write to the null block."""
-        from .kvcache import paged_prefill_write
+        prompt, then every layer's K/V scattered into the pool in place
+        — one scatter for K and one for V at ``(layer, block, offset)``
+        through the request's block table, no layer's slice taken out
+        and written back. ``(params, tokens[1, Tb], k_pool, v_pool,
+        table[1, mb], length[1]) -> (logits[1, V], k_pool, v_pool)`` —
+        logits are at the LAST REAL position (``length - 1``); pad
+        positions write to the null block."""
+        from .kvcache import paged_prefill_write_all
 
         def prefill(params, tokens, k_pool, v_pool, table, length):
             import jax.numpy as jnp
 
-            writes = []
+            ks, vs = [], []
 
             def write_kv(li, k, v):
-                writes.append((li, k[0], v[0]))  # (Tb, KVH, hd)
+                # (Tb, KVH * hd): a token's row as the pool keeps it
+                ks.append(k[0].reshape(k.shape[1], -1))
+                vs.append(v[0].reshape(v.shape[1], -1))
 
             h = self._trunk_dense(params, tokens, write_kv=write_kv)
-            for li, k, v in writes:
-                k_pool = k_pool.at[li].set(
-                    paged_prefill_write(k_pool[li], table[0], length[0], k))
-                v_pool = v_pool.at[li].set(
-                    paged_prefill_write(v_pool[li], table[0], length[0], v))
+            k_pool = paged_prefill_write_all(k_pool, table[0], length[0],
+                                             jnp.stack(ks))
+            v_pool = paged_prefill_write_all(v_pool, table[0], length[0],
+                                             jnp.stack(vs))
             last = jnp.clip(length - 1, 0, tokens.shape[1] - 1)
             h_last = jnp.take_along_axis(
                 h, last[:, None, None].astype(jnp.int32), axis=1)[:, 0]
@@ -229,7 +233,10 @@ class TransformerDecoderLM:
         return next-token logits. ``(params, token[B], pos[B], k_pool,
         v_pool, tables[B, mb], active[B]) -> (logits[B, V], k_pool,
         v_pool)``. Inactive slots write to the null block and read an
-        empty context — the step is branch-free in slot liveness."""
+        empty context — the step is branch-free in slot liveness. The
+        pool is updated and read in place: each layer scatters its rows
+        at ``[li, blk, off]`` and hands the kernel the WHOLE pool with
+        the layer index, never ``k_pool[li]``."""
         from ..ops.flash_attention import paged_decode_attention
         from .kvcache import slot_coords
 
@@ -246,10 +253,11 @@ class TransformerDecoderLM:
             for li, lyr in enumerate(params["layers"]):
                 h = _ln(x, lyr["ln1_g"], lyr["ln1_b"])
                 q, k, v = self._qkv(lyr, h)       # (B, H/KVH, hd)
-                k_pool = k_pool.at[li, blk, off].set(k)
-                v_pool = v_pool.at[li, blk, off].set(v)
-                o = paged_decode_attention(q, k_pool[li], v_pool[li],
-                                           tables, ctx, scale=scale)
+                rows = (k.shape[0], -1)  # (B, KVH * hd), the pool's row
+                k_pool = k_pool.at[li, blk, off].set(k.reshape(rows))
+                v_pool = v_pool.at[li, blk, off].set(v.reshape(rows))
+                o = paged_decode_attention(q, k_pool, v_pool, tables, ctx,
+                                           scale=scale, layer=li)
                 x = x + o.reshape(x.shape[0], -1) @ lyr["wo"]
                 x = x + self._mlp(lyr, _ln(x, lyr["ln2_g"], lyr["ln2_b"]))
             h = _ln(x, params["lnf_g"], params["lnf_b"])

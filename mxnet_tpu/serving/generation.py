@@ -51,6 +51,7 @@ recipe: docs/serving.md "Generation".
 from __future__ import annotations
 
 import collections
+import logging
 import threading
 import time
 
@@ -249,6 +250,11 @@ class GenerateFuture:
 # the engine
 # ---------------------------------------------------------------------------
 
+# an executable whose temporaries pass this share of one KV pool's bytes
+# is taken to copy the pool (in place they are a few per cent of it)
+_POOL_TEMP_SHARE_WARN = 0.25
+
+
 class GenerationEngine:
     """Continuous-batching generation server over a paged KV cache.
 
@@ -324,6 +330,8 @@ class GenerationEngine:
         self._timeouts = 0
         self._failed = 0
         self._compiles = 0
+        self._pool_temp_share = 0.0
+        self._pool_temp_worst = None
         self._decode_wall = 0.0
         self._sealed = False
         # slot state (scheduler-thread-private after start)
@@ -421,7 +429,7 @@ class GenerationEngine:
         jfn = jax.jit(chunk_fn, donate_argnums=(1, 2))
         t0 = time.perf_counter()
         self._chunk_exe = jfn.lower(*chunk_args).compile()
-        self._record_compile("decode_chunk", t0)
+        self._record_compile("decode_chunk", t0, self._chunk_exe)
         if _obs.introspect.ENABLED \
                 and not _obs.introspect.registered("decode_chunk"):
             _obs.introspect.register_jit(
@@ -450,7 +458,7 @@ class GenerationEngine:
             t0 = time.perf_counter()
             exe = jpf.lower(*args).compile()
             self._prefill_exes[tb] = exe
-            self._record_compile(f"decode_prefill[{tb}]", t0)
+            self._record_compile(f"decode_prefill[{tb}]", t0, exe)
             site = f"decode_prefill[{self._name}:{tb}]"
             if _obs.introspect.ENABLED \
                     and not _obs.introspect.registered(site):
@@ -461,17 +469,35 @@ class GenerationEngine:
             tok, kp, vp = exe(*args)
             jax.block_until_ready(tok)
             self.cache.update_pools(kp, vp)
+        if self._pool_temp_share > _POOL_TEMP_SHARE_WARN:
+            logging.getLogger(__name__).warning(
+                "generation engine %s:%s: executable %s holds temporaries "
+                "of %.2f times a KV pool's bytes (%d blocks, %d bytes): at "
+                "a deployment's pool that is a copy of the pool come back, "
+                "and the executable's time will follow the pool's size",
+                self._name, self._version, self._pool_temp_worst,
+                self._pool_temp_share, self.cache.num_blocks,
+                self.cache.k_pool.nbytes)
         self._sealed = True
 
-    def _record_compile(self, what, t0):
+    def _record_compile(self, what, t0, exe):
+        """Counts the compile and checks that ``exe`` works on the pool
+        in place: its temporaries over one pool's bytes. A copy of the
+        pool is a temporary of the pool's size (the share passes 1);
+        in place it is a few per cent (logits, the dense prefill's
+        scores). ``stats()["pool_temp_share"]`` keeps the largest."""
         self._compiles += 1
+        share = (exe.memory_analysis().temp_size_in_bytes
+                 / self.cache.k_pool.nbytes)
+        if share > self._pool_temp_share:
+            self._pool_temp_share, self._pool_temp_worst = share, what
         if _obs.ENABLED:
             _obs.SERVE_COMPILE_TOTAL.inc(1, model=self._name)
             _obs.tracer().record(
                 "serving.compile", cat="serving", ts=t0,
                 dur=time.perf_counter() - t0,
                 args={"model": self._name, "version": self._version,
-                      "bucket": str(what)})
+                      "bucket": str(what), "pool_temp_share": share})
 
     # -- submit path -------------------------------------------------------
     def _bucket_for(self, plen):
@@ -938,6 +964,7 @@ class GenerationEngine:
             "queue_depth": self.queue_depth(),
             "active_slots": self.active_slots(),
             "compiles": self._compiles,
+            "pool_temp_share": self._pool_temp_share,
             "retraces_after_warmup": 0 if self._sealed else None,
             "recompiles_after_warmup": 0 if self._sealed else None,
             "cache": self.cache.stats(),

@@ -6,9 +6,18 @@ dispatch, so per-request contiguous KV buffers would fragment device
 memory and force reallocation every time a sequence grows. Instead the
 cache owns ONE pool per projection, shaped
 
-    ``(layers, num_blocks, block_size, kv_heads, head_dim)``
+    ``(layers, num_blocks, block_size, kv_heads * head_dim)``
 
-and every request holds a :class:`BlockTable` — the list of pool block
+— a token's row holds all its kv heads side by side. The two minor
+axes are what the TPU tiles: ``(block_size, kv_heads * head_dim)`` =
+``(16, 768)`` fills bf16 tiles of ``(16, 128)`` exactly, so the
+device's own layout of the pool is the row-major one that the scatters
+and the decode kernel address in place. With the heads on an axis of
+their own the minor axes were ``(12, 64)``: padded to ``(16, 128)``
+(2.67 times the bytes) in the layout the kernel and the scatters need,
+so the device kept the pool in another axis order and every
+executable transposed the whole pool on the way in and on the way out
+(PERF.md, PR 26). Every request holds a :class:`BlockTable` — the list of pool block
 ids that back its tokens, in order. Growing a sequence is appending a
 block id to a host-side list; no device copy, no reallocation, zero
 external fragmentation (internal waste is bounded by one partial block
@@ -25,7 +34,12 @@ append into a shared partial block, and copies exactly that one block.
 The pool arrays are FUNCTIONAL values threaded through the compiled
 prefill/decode executables (donated in, returned out); the cache
 object carries the current arrays between dispatches plus the host
-allocator state. Everything device-side (gather/scatter through the
+allocator state. The hand-off is IN PLACE: an executable only ever
+scatters rows into the whole pool (``.at[layer, blk, off]``) and reads
+it through the decode kernel's ``(layer, table)`` index map, so no
+operation produces a value of the pool's shape or of one layer's slice
+of it (``GenerationEngine.stats()["pool_temp_share"]`` says when a
+copy has come back). Everything device-side (gather/scatter through the
 table) lives in the pure helpers at the bottom so the decode model and
 the tests target the same code.
 
@@ -123,7 +137,7 @@ class PagedKVCache:
             -(-int(max_seq) // self.block_size) if max_seq
             else self.num_blocks - 1)
         shape = (self.layers, self.num_blocks, self.block_size,
-                 self.kv_heads, self.head_dim)
+                 self.kv_heads * self.head_dim)
         self.k_pool = jnp.zeros(shape, dtype=self._dtype)
         self.v_pool = jnp.zeros(shape, dtype=self._dtype)
         self._lock = threading.Lock()
@@ -291,33 +305,53 @@ def slot_coords(tables, pos, block_size, active=None):
 
 def paged_write(pool_layer, blk, off, values):
     """Scatter one token's K (or V) per batch slot into a single
-    layer's pool slice ``(num_blocks, block_size, kv_heads, head_dim)``.
-    ``values`` is ``(B, kv_heads, head_dim)``."""
+    layer's pool ``(num_blocks, block_size, ...)``; ``values`` is
+    ``(B, ...)`` with the pool's trailing axes. (The decode step writes
+    the whole pool the same way, one axis up: ``pool.at[layer, blk,
+    off]``.)"""
     return pool_layer.at[blk, off].set(values)
+
+
+def _prefill_coords(table_row, length, num_tokens, block_size):
+    """``(block_id, offset)`` of each of a padded prompt's positions:
+    positions ``>= length`` (bucket padding) go to the null block."""
+    import jax.numpy as jnp
+
+    pos = jnp.arange(num_tokens, dtype=jnp.int32)
+    idx = jnp.clip(pos // block_size, 0, table_row.shape[0] - 1)
+    blk = jnp.where(pos < length, table_row[idx], 0)
+    return blk, pos % block_size
 
 
 def paged_prefill_write(pool_layer, table_row, length, values):
-    """Scatter a whole prompt's K (or V) into one layer's pool slice.
-    ``table_row`` ``(max_blocks,)`` int32, ``values`` ``(T, kv_heads,
-    head_dim)``; positions ``>= length`` (bucket padding) go to the
-    null block."""
-    import jax.numpy as jnp
-
-    t = values.shape[0]
-    pos = jnp.arange(t, dtype=jnp.int32)
-    block_size = pool_layer.shape[1]
-    idx = jnp.clip(pos // block_size, 0, table_row.shape[0] - 1)
-    blk = jnp.where(pos < length, table_row[idx], 0)
-    off = pos % block_size
+    """Scatter a whole prompt's K (or V) into one layer's pool
+    ``(num_blocks, block_size, ...)``. ``table_row`` ``(max_blocks,)``
+    int32, ``values`` ``(T, ...)`` with the pool's trailing axes;
+    positions ``>= length`` (bucket padding) go to the null block."""
+    blk, off = _prefill_coords(table_row, length, values.shape[0],
+                               pool_layer.shape[1])
     return pool_layer.at[blk, off].set(values)
 
 
+def paged_prefill_write_all(pool, table_row, length, values):
+    """Every layer's rows of a prompt into the WHOLE pool ``(layers,
+    num_blocks, block_size, ...)`` in one scatter at ``(layer, block,
+    offset)``: what prefill runs, and the same pool bit for bit as
+    :func:`paged_prefill_write` layer by layer, without a layer's slice
+    taken out and written back. ``values`` is ``(layers, T, ...)``."""
+    import jax.numpy as jnp
+
+    blk, off = _prefill_coords(table_row, length, values.shape[1],
+                               pool.shape[2])
+    li = jnp.arange(pool.shape[0], dtype=jnp.int32)[:, None]
+    return pool.at[li, blk[None], off[None]].set(values)
+
+
 def paged_gather(pool_layer, tables):
-    """Gather each slot's K (or V) context from one layer's pool slice
-    through its block table: ``(B, max_blocks * block_size, kv_heads,
-    head_dim)``. Padding rows gather the null block — callers mask by
-    context length."""
+    """Gather each slot's K (or V) context from one layer's pool
+    ``(num_blocks, block_size, ...)`` through its block table: ``(B,
+    max_blocks * block_size, ...)``. Padding rows gather the null block
+    — callers mask by context length."""
     b, mb = tables.shape
-    g = pool_layer[tables]  # (B, max_blocks, block_size, KVH, D)
-    return g.reshape(b, mb * pool_layer.shape[1],
-                     pool_layer.shape[2], pool_layer.shape[3])
+    g = pool_layer[tables]  # (B, max_blocks, block_size, ...)
+    return g.reshape(b, mb * pool_layer.shape[1], *pool_layer.shape[2:])

@@ -17,6 +17,7 @@ off around them (such an entry cannot be read back without a chip).
 """
 
 import functools
+import math
 import os
 
 import jax
@@ -104,17 +105,57 @@ def test_flash_bwd_split_compiles_for_v5e(on_chip, case):
         names=["mxtpu_flash_bwd_dq", "mxtpu_flash_bwd_dkv"])
 
 
-# B, H, KVH, D, block_size, num_blocks, max_blocks per sequence
-@pytest.mark.parametrize("B,H,KVH,D,bs,nb,mb", [
-    (32, 12, 12, 64, 16, 4096, 64),     # GPT-2-small heads, 1024 ctx
-    (32, 32, 8, 128, 16, 4096, 128),    # GQA 32Q/8KV at head_dim 128
-], ids=["mha_h12_d64", "gqa_h32_kv8_d128"])
-def test_paged_decode_compiles_for_v5e(on_chip, B, H, KVH, D, bs, nb, mb):
+# B, H, KVH, D, block_size, layers, num_blocks, max_blocks per sequence:
+# the kernel reads the whole pool (a token's heads side by side in its
+# row) at a static layer index
+@pytest.mark.parametrize("B,H,KVH,D,bs,L,nb,mb", [
+    (32, 12, 12, 64, 16, 2, 4096, 64),    # GPT-2-small heads, 1024 ctx
+    (32, 32, 8, 128, 16, 2, 4096, 128),   # GQA 32Q/8KV at head_dim 128
+    (128, 12, 12, 64, 16, 12, 3073, 64),  # the serve cell's pool, whole
+], ids=["mha_h12_d64", "gqa_h32_kv8_d128", "serve_chat_pool"])
+def test_paged_decode_compiles_for_v5e(on_chip, B, H, KVH, D, bs, L, nb, mb):
     q = on_chip((B, H, D), jnp.bfloat16)
-    pool = on_chip((nb, bs, KVH, D), jnp.bfloat16)
+    pool = on_chip((L, nb, bs, KVH * D), jnp.bfloat16)
     tables = on_chip((B, mb), jnp.int32)
     lens = on_chip((B,), jnp.int32)
     _compiles_to_kernel(
-        lambda q, k, v, t, l: fa._pallas_paged_decode(q, k, v, t, l,
-                                                      D ** -0.5),
+        lambda q, k, v, t, l: fa._pallas_paged_decode(
+            q, k, v, t, l, D ** -0.5, layer=L - 1),
         q, pool, pool, tables, lens, names=["mxtpu_paged_decode"])
+
+
+@pytest.mark.parametrize("which", ["decode_step_fn", "prefill_fn"])
+def test_decoder_works_on_the_pool_in_place_on_v5e(on_chip, monkeypatch,
+                                                   which):
+    """What the generation engine compiles, at the serve cell's pool
+    (3,073 blocks of 16 tokens, 12 heads of 64; two layers): with the
+    pools donated the executable's temporaries stay a small share of
+    one pool, and the kernel is still its only custom call. With the
+    heads on an axis of their own the device kept the pool in another
+    axis order and every executable transposed it on the way in and on
+    the way out: temporaries of five pools."""
+    from mxnet_tpu.serving import TransformerDecoderLM
+
+    monkeypatch.setattr(fa, "_use_pallas", lambda d: True)  # no TPU here
+    layers, slots, mb, bucket = 2, 128, 64, 64
+    net = TransformerDecoderLM(vocab_size=512, num_layers=layers,
+                               d_model=768, num_heads=12, max_seq=1024,
+                               dtype="bfloat16")
+    params = jax.tree_util.tree_map(
+        lambda a: on_chip(a.shape, a.dtype), net.params())
+    pool = on_chip((layers, 3073, 16, 768), jnp.bfloat16)
+    if which == "decode_step_fn":
+        args = (params, on_chip((slots,), jnp.int32),
+                on_chip((slots,), jnp.int32), pool, pool,
+                on_chip((slots, mb), jnp.int32), on_chip((slots,), bool))
+        donate, kernels = (3, 4), layers
+    else:
+        args = (params, on_chip((1, bucket), jnp.int32), pool, pool,
+                on_chip((1, mb), jnp.int32), on_chip((1,), jnp.int32))
+        donate, kernels = (2, 3), 0
+    exe = jax.jit(getattr(net, which)(),
+                  donate_argnums=donate).lower(*args).compile()
+    pool_bytes = 2 * math.prod(pool.shape)
+    assert exe.memory_analysis().temp_size_in_bytes < 0.25 * pool_bytes
+    assert exe.as_text().count(
+        'custom_call_target="tpu_custom_call"') == kernels

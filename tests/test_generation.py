@@ -93,6 +93,59 @@ def test_greedy_decode_matches_dense_recompute(eng, net, prompt, n):
     _assert_matches_dense(net, prompt, toks)
 
 
+@pytest.mark.parametrize("cache_blocks", [40, 513])
+def test_greedy_tokens_do_not_depend_on_the_pool_size(net, cache_blocks):
+    """The served tokens are the dense recompute's under a small pool
+    and under one thirteen times larger: the pool is addressed in
+    place, through the tables, whatever its size."""
+    e = GenerationEngine(net, BUCKETS, slots=SLOTS, chunk=CHUNK,
+                         cache_blocks=cache_blocks, cache_block_size=4,
+                         name=f"gen-pool{cache_blocks}")
+    try:
+        for prompt, n in [([3, 1, 4], 10), (list(range(2, 15)), 17)]:
+            toks = e.predict(np.array(prompt, np.int32), max_new_tokens=n,
+                             greedy=True, timeout=60.0)
+            assert len(toks) == n
+            _assert_matches_dense(net, prompt, toks)
+    finally:
+        e.close()
+
+
+def test_pool_temp_share_is_reported_after_deploy(eng, caplog):
+    """Deploy measures every executable's temporaries against one
+    pool's bytes: ``stats()`` holds the largest, each compile record
+    its own, and a deploy over 0.25 warns once, naming the executable.
+    (A toy pool is smaller than the logits, so the toy engines warn;
+    at a deployment's pool the share is a few per cent.)"""
+    import logging
+
+    share = eng.stats()["pool_temp_share"]
+    assert isinstance(share, float) and np.isfinite(share) and share > 0
+    obs.set_enabled(True)
+    net2 = TransformerDecoderLM(vocab_size=VOCAB, num_layers=1,
+                                d_model=32, num_heads=4, max_seq=MAX_SEQ)
+    with caplog.at_level(logging.WARNING,
+                         logger="mxnet_tpu.serving.generation"):
+        e = GenerationEngine(net2, [4, 8], slots=2, chunk=2,
+                             cache_blocks=8, cache_block_size=4,
+                             name="gen-temp", autostart=False)
+    try:
+        recs = [ev["args"] for ev in obs.tracer().events()
+                if ev["name"] == "serving.compile"
+                and ev["args"]["model"] == "gen-temp"]
+        assert [r["bucket"] for r in recs] == [
+            "decode_chunk", "decode_prefill[4]", "decode_prefill[8]"]
+        assert max(r["pool_temp_share"] for r in recs) \
+            == e.stats()["pool_temp_share"] > 0.25
+        warned = [r.getMessage() for r in caplog.records
+                  if "gen-temp" in r.getMessage()]
+        assert len(warned) == 1
+        worst = max(recs, key=lambda r: r["pool_temp_share"])["bucket"]
+        assert f"executable {worst} " in warned[0]
+    finally:
+        e.close()
+
+
 def test_batch_axis_squeeze_and_validation(eng):
     a = eng.predict(np.array([[5, 6, 7]], np.int32),
                     max_new_tokens=3, timeout=60.0)

@@ -4,6 +4,7 @@ the pure in-graph paging helpers the decode model compiles against
 (null-block routing for inactive slots / pad positions, scatter +
 gather round-trips through the table indirection)."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -285,6 +286,10 @@ def test_pallas_paged_decode_matches_gather_oracle(B, H, KVH, D, bs, mb,
                          jnp.int32)
     lens = jnp.asarray([0, 1, bs, mb * bs, mb * bs - 3], jnp.int32)
     scale = D ** -0.5
+    # the helpers take the whole pool, a token's heads side by side in
+    # its row: one layer is a leading 1
+    k_pool = k_pool.reshape(1, nb, bs, KVH * D)
+    v_pool = v_pool.reshape(1, nb, bs, KVH * D)
     ref = fa._jnp_paged_decode(q, k_pool, v_pool, tables, lens, scale)
     out = fa._pallas_paged_decode(q, k_pool, v_pool, tables, lens, scale,
                                   interpret=True)
@@ -293,3 +298,147 @@ def test_pallas_paged_decode_matches_gather_oracle(B, H, KVH, D, bs, mb,
                                np.asarray(ref, np.float32),
                                rtol=tol, atol=tol)
     assert not np.asarray(out, np.float32)[0].any()  # empty slot: zeros
+
+
+# ---------------------------------------------------------------------------
+# the pool's hand-off: one scatter into the whole pool, the kernel reads
+# the whole pool by layer index (no layer's slice on either side)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("bucket,length", [(16, 5), (8, 8), (4, 7)],
+                         ids=["bucket_longer", "bucket_exact",
+                              "bucket_shorter"])
+def test_whole_pool_prefill_write_equals_layer_by_layer(bucket, length):
+    """``paged_prefill_write_all`` (what prefill runs) gives bit for
+    bit the pool that ``paged_prefill_write`` gives layer by layer; pad
+    positions (and a bucket shorter than the stated length has none)
+    land in the null block only."""
+    from mxnet_tpu.serving.kvcache import paged_prefill_write_all
+
+    layers, nb, bs, width = 3, 9, 4, 6
+    rng = np.random.RandomState(bucket)
+    pool = jnp.asarray(rng.randn(layers, nb, bs, width), jnp.float32)
+    table_row = np.array([5, 2, 7, 3], np.int32)
+    vals = jnp.asarray(rng.randn(layers, bucket, width), jnp.float32)
+    got = np.asarray(paged_prefill_write_all(pool, table_row, length, vals))
+    want = np.stack([
+        np.asarray(paged_prefill_write(pool[li], table_row, length,
+                                       vals[li]))
+        for li in range(layers)])
+    assert np.array_equal(got, want)
+    real = min(bucket, length)
+    for t in range(real):  # every real position through the table
+        assert np.array_equal(got[:, table_row[t // bs], t % bs],
+                              np.asarray(vals[:, t]))
+    # blocks the table does not name are untouched, but for the sink
+    # when there was padding
+    others = [b for b in range(1, nb) if b not in table_row[:-(-real // bs)]]
+    assert np.array_equal(got[:, others], np.asarray(pool)[:, others])
+    assert np.array_equal(got[:, 0], np.asarray(pool)[:, 0]) \
+        == (bucket <= length)
+
+
+@pytest.mark.parametrize("path", ["gather", "kernel"])
+@pytest.mark.parametrize("H,KVH", [(4, 4), (8, 2)],
+                         ids=["group1", "group4"])
+def test_whole_pool_decode_equals_one_layer_call(H, KVH, path):
+    """``paged_decode_attention`` on the whole pool with ``layer=li``
+    is the one-layer call on that layer's pool, for every layer, with
+    and without GQA, through the gather path and through the kernel
+    (Pallas interpreter); an empty slot returns zeros."""
+    from mxnet_tpu.ops import flash_attention as fa
+
+    layers, B, D, bs, mb = 3, 4, 16, 8, 3
+    rng = np.random.RandomState(KVH)
+    nb = B * mb + 1
+    q = jnp.asarray(rng.randn(B, H, D), jnp.float32)
+    k_pool = jnp.asarray(rng.randn(layers, nb, bs, KVH * D), jnp.float32)
+    v_pool = jnp.asarray(rng.randn(layers, nb, bs, KVH * D), jnp.float32)
+    tables = jnp.asarray(1 + rng.permutation(B * mb).reshape(B, mb),
+                         jnp.int32)
+    lens = jnp.asarray([0, 1, bs, mb * bs - 3], jnp.int32)
+    for li in range(layers):
+        one_layer = fa.paged_decode_attention(
+            q, k_pool[li].reshape(nb, bs, KVH, D),
+            v_pool[li].reshape(nb, bs, KVH, D), tables, lens)
+        if path == "gather":
+            got = fa.paged_decode_attention(q, k_pool, v_pool, tables,
+                                            lens, layer=li)
+            assert np.array_equal(np.asarray(got), np.asarray(one_layer))
+        else:
+            got = fa._pallas_paged_decode(q, k_pool, v_pool, tables, lens,
+                                          D ** -0.5, layer=li,
+                                          interpret=True)
+            np.testing.assert_allclose(np.asarray(got),
+                                       np.asarray(one_layer),
+                                       rtol=1e-5, atol=1e-5)
+        assert not np.asarray(got)[0].any()
+    # layers differ, so a wrong index could not pass
+    assert not np.array_equal(
+        np.asarray(fa.paged_decode_attention(q, k_pool, v_pool, tables,
+                                             lens, layer=0)),
+        np.asarray(fa.paged_decode_attention(q, k_pool, v_pool, tables,
+                                             lens, layer=1)))
+
+
+def test_paged_decode_attention_refuses_a_layer_outside_the_pool():
+    from mxnet_tpu.ops import flash_attention as fa
+
+    q = jnp.zeros((2, 4, 8), jnp.float32)
+    pool = jnp.zeros((2, 5, 4, 16), jnp.float32)
+    tables, lens = jnp.zeros((2, 2), jnp.int32), jnp.zeros(2, jnp.int32)
+    with pytest.raises(ValueError, match="layer"):
+        fa.paged_decode_attention(q, pool, pool, tables, lens, layer=2)
+    with pytest.raises(ValueError, match="kv heads"):
+        fa.paged_decode_attention(q, pool[..., :12], pool[..., :12],
+                                  tables, lens, layer=0)
+
+
+def _pool_shaped_outputs(jaxpr, pool_shape):
+    """(primitive, shape) of every equation output, through nested
+    jaxprs, that holds as many elements as the pool or as one layer of
+    it, whatever its axes."""
+    import math
+
+    sizes = {math.prod(pool_shape), math.prod(pool_shape[1:])}
+    found = []
+
+    def walk(jp):
+        for eqn in jp.eqns:
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+            for v in eqn.outvars:
+                shape = getattr(v.aval, "shape", ())
+                if math.prod(shape) in sizes and len(shape) > 1:
+                    found.append((eqn.primitive.name, tuple(shape)))
+
+    walk(jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("which", ["decode_step_fn", "prefill_fn"])
+def test_served_functions_never_slice_or_copy_the_pool(which):
+    """Structure of what the engine compiles: no equation of the decode
+    step or of prefill produces one layer's slice of the pool, and only
+    the scatters (and, in the decode step, nothing else) produce a
+    value of the pool's size. The gather path reads ``pool[layer,
+    tables]`` in one step, so this holds on a CPU as it does for the
+    kernel."""
+    from mxnet_tpu.serving import TransformerDecoderLM
+
+    net = TransformerDecoderLM(vocab_size=32, num_layers=3, d_model=16,
+                               num_heads=4, kv_heads=2, max_seq=32)
+    # sizes no activation shares: 3 layers x 23 blocks x 4 x (2*4)
+    pool = jnp.zeros((3, 23, 4, 8), jnp.float32)
+    if which == "decode_step_fn":
+        jaxpr = jax.make_jaxpr(net.decode_step_fn())(
+            net.params(), jnp.zeros(2, jnp.int32), jnp.zeros(2, jnp.int32),
+            pool, pool, jnp.zeros((2, 8), jnp.int32), jnp.ones(2, bool))
+        scatters = 2 * net.num_layers
+    else:
+        jaxpr = jax.make_jaxpr(net.prefill_fn())(
+            net.params(), jnp.zeros((1, 8), jnp.int32), pool, pool,
+            jnp.zeros((1, 8), jnp.int32), jnp.ones(1, jnp.int32))
+        scatters = 2
+    found = _pool_shaped_outputs(jaxpr.jaxpr, pool.shape)
+    assert found == [("scatter", pool.shape)] * scatters, found
