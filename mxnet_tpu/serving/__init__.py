@@ -62,6 +62,8 @@ from .errors import (  # noqa: F401
 from .kvcache import (  # noqa: F401
     BlockTable,
     PagedKVCache,
+    SequenceCache,
+    StateStore,
     kvcache_block_size,
     kvcache_blocks,
 )

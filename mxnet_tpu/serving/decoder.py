@@ -1,27 +1,56 @@
-"""A small functional transformer decoder LM — the generation stack's
-reference model (and the fleet demo/test workload).
+"""The functional decoder LM of the generation stack: one class that
+its configuration drives.
 
 This is deliberately NOT a Gluon block: the decode fast path needs
 pure ``(params, state) -> (logits, state)`` functions it can close
-into AOT-compiled prefill/decode executables, with the KV pools
+into AOT-compiled prefill/decode executables, with the cache's arrays
 threaded through as donated operands. The class carries the
 hyperparameters and the (deterministically seeded) weights; everything
 the device runs comes out of :meth:`prefill_fn` / :meth:`decode_step_fn`
 / :meth:`forward_fn` as pure closures over nothing but shapes.
 
+What the configuration chooses, each on its own:
+
+- ``norm``: ``"layernorm"`` (gain and bias) or ``"rmsnorm"`` (gain);
+- ``positions``: ``"learned"`` (a table added to the embedding) or
+  ``"rope"`` (rotary, half-split form, applied to queries and keys);
+- ``mlp``: ``"gelu"`` (two matrices with biases) or ``"swiglu"``
+  (``silu(x W_gate) * (x W_up)`` through ``W_down``, no biases);
+- ``qk_norm``: an RMSNorm over ``head_dim`` of every query and key head
+  before the positions are applied;
+- ``layer_kinds``: a kind a layer, ``"attention"`` (softmax attention
+  over the paged KV pool) or ``"retention"`` (power retention of degree
+  2 over a fixed state a sequence, :mod:`mxnet_tpu.ops.retention`: a
+  gate ``sigmoid(h W_g + b_g)`` a KV head decays the state).
+
+A net whose layers are all retention layers keeps their weights stacked
+on a leading axis and runs them under one ``lax.scan`` (``scan_layers``,
+which follows from ``layer_kinds``): one layer's program whatever the
+depth, which is what keeps the compile of a wide model's prefill
+buckets short. The paged-decode kernel takes its layer as a constant,
+so a net with an attention layer runs its layers one by one.
+
+The defaults are the GPT-2 shape the stack started with (learned
+positions, pre-LayerNorm, GELU MLP, grouped-query attention allowed,
+``kv_heads | num_heads``, a head of its own). :data:`PRESETS` names
+one more, ``brumby_tiny``: the switches of manifestai/Brumby-14B-Base
+(Qwen3-14B's shapes with every layer a retention layer) at a size for
+the CPU tests. The published widths are the benchmark's
+``chipbench/configs/brumby_14b.json``.
+
 The SAME math is exposed three ways, which is what the correctness
 tests pin against each other:
 
-- :meth:`forward_fn` — dense full-context causal forward (the oracle);
-- :meth:`prefill_fn` — dense over the prompt, then one scatter of
-  every layer's K/V into the paged pool through the request's block
-  table;
-- :meth:`decode_step_fn` — one token per sequence, K/V appended to the
-  pool and attention read back through
-  :func:`~mxnet_tpu.ops.flash_attention.paged_decode_attention`.
+- :meth:`forward_fn`: dense full-context causal forward (the oracle;
+  a retention layer in its attention form);
+- :meth:`prefill_fn`: dense over the prompt; every attention layer's
+  K/V scattered into the paged pool through the request's block table,
+  every retention layer's state carried chunk to chunk from the one
+  its slot holds and left there at the prompt's real length;
+- :meth:`decode_step_fn`: one token per sequence, attention through
+  :func:`~mxnet_tpu.ops.flash_attention.paged_decode_attention`,
+  retention through :func:`~mxnet_tpu.ops.retention.power_retention_step`.
 
-Architecture: learned positional embeddings, pre-LN, grouped-query
-attention (``kv_heads | num_heads``), GELU MLP, weight-tied-free head.
 Process replicas rebuild it from the ``{"decoder": {...}}`` spec with
 the same seed, so every replica serves identical weights.
 """
@@ -31,6 +60,19 @@ from __future__ import annotations
 import numpy as np
 
 _EPS = 1e-5
+KINDS = ("attention", "retention")
+
+# tokens a chunk of a retention layer's prefill: inside a chunk the
+# attention form, between chunks the state (the program's, not a model's)
+RETENTION_CHUNK = 256
+
+PRESETS = {
+    "brumby_tiny": dict(
+        vocab_size=128, num_layers=2, d_model=64, num_heads=4, kv_heads=2,
+        head_dim=16, d_ff=128, max_seq=256, norm="rmsnorm", norm_eps=1e-6,
+        positions="rope", rope_theta=1e6, mlp="swiglu", qk_norm=True,
+        layer_kinds="retention"),
+}
 
 
 def _ln(x, g, b):
@@ -41,17 +83,49 @@ def _ln(x, g, b):
     return (x - mu) / jnp.sqrt(var + _EPS) * g + b
 
 
+def _rms(x, g, eps):
+    """RMSNorm over the last axis, computed in float32."""
+    import jax
+    import jax.numpy as jnp
+
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True)
+                            + eps)
+    return y.astype(x.dtype) * g
+
+
+def _rope(x, pos, theta):
+    """Rotary positions in the half-split form. ``x`` is ``(..., heads,
+    head_dim)`` and ``pos`` has its leading axes."""
+    import jax.numpy as jnp
+
+    half = x.shape[-1] // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = pos.astype(jnp.float32)[..., None, None] * freq
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x32 = x.astype(jnp.float32)
+    a, b = x32[..., :half], x32[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
 class TransformerDecoderLM:
-    """Tiny decoder-only LM with paged-cache-aware prefill/decode.
+    """Decoder-only LM with cache-aware prefill/decode.
 
     >>> net = TransformerDecoderLM(vocab_size=64, num_layers=2,
     ...                            d_model=32, num_heads=4, kv_heads=2)
-    >>> dims = net.decode_dims()   # cache geometry for PagedKVCache
+    >>> dims = net.decode_dims()   # sizes the engine asks for
+    >>> net.cache_spec()           # what its layers keep, a layer kind
+    {'attention': {'layers': 2, 'kv_heads': 2, 'head_dim': 8}}
+    >>> tiny = TransformerDecoderLM.from_preset("brumby_tiny")
     """
 
     def __init__(self, vocab_size=64, num_layers=2, d_model=32,
                  num_heads=4, kv_heads=None, d_ff=None, max_seq=128,
-                 seed=0, dtype="float32"):
+                 seed=0, dtype="float32", *, head_dim=None,
+                 norm="layernorm", norm_eps=None, positions="learned",
+                 rope_theta=10000.0, mlp="gelu", qk_norm=False,
+                 layer_kinds="attention"):
         self.vocab_size = int(vocab_size)
         self.num_layers = int(num_layers)
         self.d_model = int(d_model)
@@ -64,10 +138,44 @@ class TransformerDecoderLM:
         if self.num_heads % self.kv_heads != 0:
             raise ValueError("num_heads must be a multiple of kv_heads; "
                              f"got {self.num_heads} vs {self.kv_heads}")
-        if self.d_model % self.num_heads != 0:
+        if head_dim is None and self.d_model % self.num_heads != 0:
             raise ValueError("d_model must divide into num_heads")
-        self.head_dim = self.d_model // self.num_heads
+        self.head_dim = int(head_dim or self.d_model // self.num_heads)
+        for name, value, known in (
+                ("norm", norm, ("layernorm", "rmsnorm")),
+                ("positions", positions, ("learned", "rope")),
+                ("mlp", mlp, ("gelu", "swiglu"))):
+            if value not in known:
+                raise ValueError(f"{name} is one of {known}; got {value!r}")
+        self.norm, self.positions, self.mlp = norm, positions, mlp
+        self.norm_eps = float(norm_eps if norm_eps is not None else _EPS)
+        self.rope_theta = float(rope_theta)
+        self.qk_norm = bool(qk_norm)
+        if isinstance(layer_kinds, str):
+            layer_kinds = [layer_kinds] * self.num_layers
+        self.layer_kinds = [str(k) for k in layer_kinds]
+        if len(self.layer_kinds) != self.num_layers \
+                or any(k not in KINDS for k in self.layer_kinds):
+            raise ValueError(
+                f"layer_kinds names one of {KINDS} for each of the "
+                f"{self.num_layers} layers; got {layer_kinds!r}")
+        # a layer's place among the layers of its kind: its index in
+        # that kind's cache
+        seen = dict.fromkeys(KINDS, 0)
+        self._kind_index = []
+        for k in self.layer_kinds:
+            self._kind_index.append(seen[k])
+            seen[k] += 1
+        self._kind_count = {k: n for k, n in seen.items() if n}
+        # layers of one kind under one scan; the paged-decode kernel
+        # takes its layer as a constant, so retention layers only
+        self.scan_layers = set(self.layer_kinds) == {"retention"}
         self._params = self._init_params()
+
+    @classmethod
+    def from_preset(cls, name, **overrides):
+        """A net of :data:`PRESETS`, with some of its sizes replaced."""
+        return cls(**{**PRESETS[name], **overrides})
 
     # -- weights -----------------------------------------------------------
     def _init_params(self):
@@ -87,30 +195,52 @@ class TransformerDecoderLM:
 
         d, h, kvh, hd, ff = (self.d_model, self.num_heads, self.kv_heads,
                              self.head_dim, self.d_ff)
+        biased = self.norm == "layernorm"
+
+        def norm_leaves(prefix):
+            out = {prefix + "_g": ones(d)}
+            if biased:
+                out[prefix + "_b"] = zeros(d)
+            return out
+
         layers = []
-        for _ in range(self.num_layers):
-            layers.append({
-                "ln1_g": ones(d), "ln1_b": zeros(d),
-                "wq": w(d, h * hd), "wk": w(d, kvh * hd),
-                "wv": w(d, kvh * hd), "wo": w(h * hd, d),
-                "ln2_g": ones(d), "ln2_b": zeros(d),
-                "w1": w(d, ff), "b1": zeros(ff),
-                "w2": w(ff, d), "b2": zeros(d),
-            })
-        return {
-            "embed": w(self.vocab_size, d),
-            "pos": w(self.max_seq, d),
-            "layers": layers,
-            "lnf_g": ones(d), "lnf_b": zeros(d),
-            "head": w(d, self.vocab_size),
-        }
+        for kind in self.layer_kinds:
+            lyr = norm_leaves("ln1")
+            lyr.update(wq=w(d, h * hd), wk=w(d, kvh * hd),
+                       wv=w(d, kvh * hd), wo=w(h * hd, d))
+            if self.qk_norm:
+                lyr.update(q_norm=ones(hd), k_norm=ones(hd))
+            if kind == "retention":
+                # gates that remember: 1 - sigmoid(b_g) log-uniform
+                # between 1/100 and 1/10,000, so that a state carried
+                # over hundreds of tokens still reaches the logits
+                forget = 10.0 ** -rng.uniform(2.0, 4.0, kvh)
+                lyr.update(wg=w(d, kvh), bg=jnp.asarray(
+                    np.log((1.0 - forget) / forget), dtype=self.dtype))
+            lyr.update(norm_leaves("ln2"))
+            if self.mlp == "gelu":
+                lyr.update(w1=w(d, ff), b1=zeros(ff), w2=w(ff, d),
+                           b2=zeros(d))
+            else:
+                lyr.update(w_gate=w(d, ff), w_up=w(d, ff), w_down=w(ff, d))
+            layers.append(lyr)
+        if self.scan_layers:
+            layers = {k: jnp.stack([lyr[k] for lyr in layers])
+                      for k in layers[0]}
+        out = {"embed": w(self.vocab_size, d), "layers": layers}
+        if self.positions == "learned":
+            out["pos"] = w(self.max_seq, d)
+        out.update(norm_leaves("lnf"))
+        out["head"] = w(d, self.vocab_size)
+        return out
 
     def params(self):
         """The weight pytree (a plain dict — device-resident arrays)."""
         return self._params
 
     def decode_dims(self) -> dict:
-        """Cache geometry the engine hands to :class:`PagedKVCache`."""
+        """The sizes the engine reads (``max_seq``, ``vocab_size``) and
+        the attention cache's geometry."""
         return {
             "layers": self.num_layers,
             "kv_heads": self.kv_heads,
@@ -120,6 +250,15 @@ class TransformerDecoderLM:
             "d_model": self.d_model,
         }
 
+    def cache_spec(self) -> dict:
+        """What a live sequence keeps, a layer kind the net has:
+        ``attention`` layers keep K/V rows that grow with the sequence
+        (the paged pool), ``retention`` layers a fixed state a KV head.
+        :class:`~.kvcache.SequenceCache` is built from this."""
+        return {kind: {"layers": n, "kv_heads": self.kv_heads,
+                       "head_dim": self.head_dim}
+                for kind, n in self._kind_count.items()}
+
     def spec(self) -> dict:
         """The ``{"decoder": ...}`` replica spec that rebuilds this net
         (same seed -> identical weights in every process replica)."""
@@ -128,22 +267,51 @@ class TransformerDecoderLM:
             "d_model": self.d_model, "num_heads": self.num_heads,
             "kv_heads": self.kv_heads, "d_ff": self.d_ff,
             "max_seq": self.max_seq, "seed": self.seed,
-            "dtype": self.dtype,
+            "dtype": self.dtype, "head_dim": self.head_dim,
+            "norm": self.norm, "norm_eps": self.norm_eps,
+            "positions": self.positions, "rope_theta": self.rope_theta,
+            "mlp": self.mlp, "qk_norm": self.qk_norm,
+            "layer_kinds": list(self.layer_kinds),
         }}
 
     # -- shared layer math -------------------------------------------------
-    def _qkv(self, lyr, h):
+    def _norm(self, x, p, name):
+        if self.norm == "rmsnorm":
+            return _rms(x, p[name + "_g"], self.norm_eps)
+        return _ln(x, p[name + "_g"], p[name + "_b"])
+
+    def _qkv(self, lyr, h, pos=None):
         """Project one layer's hidden states ``(..., d)`` to q/k/v with
-        head axes split out."""
+        head axes split out; queries and keys normed a head and rotated
+        to ``pos`` (the leading axes' positions) where the
+        configuration says so."""
         lead = h.shape[:-1]
         q = (h @ lyr["wq"]).reshape(*lead, self.num_heads, self.head_dim)
         k = (h @ lyr["wk"]).reshape(*lead, self.kv_heads, self.head_dim)
         v = (h @ lyr["wv"]).reshape(*lead, self.kv_heads, self.head_dim)
+        if self.qk_norm:
+            q = _rms(q, lyr["q_norm"], self.norm_eps)
+            k = _rms(k, lyr["k_norm"], self.norm_eps)
+        if self.positions == "rope":
+            q = _rope(q, pos, self.rope_theta)
+            k = _rope(k, pos, self.rope_theta)
         return q, k, v
+
+    @staticmethod
+    def _log_gate(lyr, h):
+        """``log sigmoid(h W_g + b_g)``, one a KV head, float32."""
+        import jax
+        import jax.numpy as jnp
+
+        return jax.nn.log_sigmoid((h @ lyr["wg"] + lyr["bg"])
+                                  .astype(jnp.float32))
 
     def _mlp(self, lyr, x):
         import jax
 
+        if self.mlp == "swiglu":
+            return (jax.nn.silu(x @ lyr["w_gate"]) * (x @ lyr["w_up"])) \
+                @ lyr["w_down"]
         return jax.nn.gelu(x @ lyr["w1"] + lyr["b1"]) @ lyr["w2"] + lyr["b2"]
 
     def _dense_attend(self, q, k, v, causal_mask):
@@ -164,24 +332,108 @@ class TransformerDecoderLM:
         o = jnp.einsum("bhts,bshd->bthd", p, v.astype(jnp.float32))
         return o.astype(q.dtype)
 
-    def _trunk_dense(self, params, tokens, write_kv=None):
-        """Dense causal trunk over ``tokens`` (B, T). ``write_kv`` is an
-        optional callback ``(layer_idx, k, v)`` the prefill path uses to
-        scatter each layer's K/V into the paged pool."""
+    def _dense_retain(self, q, k, v, log_g, causal_mask):
+        """Power retention in its attention form over full context (the
+        oracle): weights ``exp(G_t - G_s) (q_t . k_s)^2`` over their
+        own sum. q: (B, T, H, hd); k/v: (B, T, KVH, hd); log_g: (B, T,
+        KVH)."""
+        import jax.numpy as jnp
+
+        group = self.num_heads // self.kv_heads
+        run = jnp.cumsum(log_g, axis=1)                  # (B, T, KVH)
+        if group > 1:
+            k = jnp.repeat(k, group, axis=2)
+            v = jnp.repeat(v, group, axis=2)
+            run = jnp.repeat(run, group, axis=2)
+        s = jnp.einsum("bthd,bshd->bhts", q.astype(jnp.float32),
+                       k.astype(jnp.float32))
+        run = run.transpose(0, 2, 1)                     # (B, H, T)
+        gap = jnp.where(causal_mask, run[..., :, None] - run[..., None, :],
+                        0.0)
+        w = jnp.where(causal_mask, jnp.exp(gap) * s * s, 0.0)
+        o = jnp.einsum("bhts,bshd->bthd", w, v.astype(jnp.float32))
+        den = jnp.sum(w, axis=-1).transpose(0, 2, 1)     # (B, T, H)
+        return (o / den[..., None]).astype(q.dtype)
+
+    def _embed(self, params, tokens, pos):
+        x = params["embed"][tokens]
+        if self.positions == "learned":
+            x = x + params["pos"][pos]
+        return x
+
+    def _through_layers(self, params, x, carry, mixers):
+        """Every layer over ``x``. ``mixers[kind](lyr, h, carry, j)``
+        mixes the normed hidden states ``h`` across positions for layer
+        ``j`` of its kind and returns ``(o, carry)``, ``o`` with the
+        heads side by side; ``carry`` is whatever the face threads
+        through (the cache's arrays, or nothing). Stacked layers run
+        under one scan, with ``j`` traced."""
+        import jax
+        import jax.numpy as jnp
+
+        def layer(lyr, x, carry, kind, j):
+            o, carry = mixers[kind](lyr, self._norm(x, lyr, "ln1"), carry, j)
+            x = x + o.reshape(*x.shape[:-1], -1).astype(x.dtype) @ lyr["wo"]
+            return x + self._mlp(lyr, self._norm(x, lyr, "ln2")), carry
+
+        layers = params["layers"]
+        if isinstance(layers, dict):
+            kind = self.layer_kinds[0]
+
+            def body(c, xs):
+                lyr, j = xs
+                return layer(lyr, c[0], c[1], kind, j), None
+
+            (x, carry), _ = jax.lax.scan(
+                body, (x, carry),
+                (layers, jnp.arange(self.num_layers, dtype=jnp.int32)))
+        else:
+            for li, lyr in enumerate(layers):
+                x, carry = layer(lyr, x, carry, self.layer_kinds[li],
+                                 self._kind_index[li])
+        return self._norm(x, params, "lnf"), carry
+
+    def _trunk_dense(self, params, tokens):
+        """Dense causal trunk over ``tokens`` (B, T), every layer in
+        its attention form."""
         import jax.numpy as jnp
 
         b, t = tokens.shape
-        x = params["embed"][tokens] + params["pos"][:t][None]
+        pos = jnp.arange(t, dtype=jnp.int32)
         mask = jnp.tril(jnp.ones((t, t), bool))[None, None]
-        for li, lyr in enumerate(params["layers"]):
-            h = _ln(x, lyr["ln1_g"], lyr["ln1_b"])
-            q, k, v = self._qkv(lyr, h)
-            if write_kv is not None:
-                write_kv(li, k, v)
-            o = self._dense_attend(q, k, v, mask)
-            x = x + o.reshape(b, t, -1) @ lyr["wo"]
-            x = x + self._mlp(lyr, _ln(x, lyr["ln2_g"], lyr["ln2_b"]))
-        return _ln(x, params["lnf_g"], params["lnf_b"])
+
+        def attend(lyr, h, carry, j):
+            q, k, v = self._qkv(lyr, h, pos)
+            return self._dense_attend(q, k, v, mask), carry
+
+        def retain(lyr, h, carry, j):
+            q, k, v = self._qkv(lyr, h, pos)
+            return self._dense_retain(q, k, v, self._log_gate(lyr, h),
+                                      mask), carry
+
+        x = self._embed(params, tokens, pos[None])
+        return self._through_layers(
+            params, x, None, {"attention": attend, "retention": retain})[0]
+
+    def _split_cache(self, operands):
+        """The faces' cache operands, in their fixed order: the
+        attention layers' ``k_pool, v_pool``, then the retention
+        layers' ``state, norm``; after them an index a kind (the block
+        tables, the slots' states). Returns ``({kind: arrays}, {kind:
+        index})``."""
+        kinds = [k for k in KINDS if k in self._kind_count]
+        if len(operands) != 3 * len(kinds):
+            raise TypeError(
+                f"a net with {kinds} layers takes {2 * len(kinds)} cache "
+                f"arrays and {len(kinds)} indices; got {len(operands)}")
+        arrays = {k: tuple(operands[2 * i:2 * i + 2])
+                  for i, k in enumerate(kinds)}
+        index = {k: operands[2 * len(kinds) + i]
+                 for i, k in enumerate(kinds)}
+        return arrays, index
+
+    def _join_cache(self, arrays):
+        return tuple(a for k in KINDS if k in arrays for a in arrays[k])
 
     # -- the three pure faces ---------------------------------------------
     def forward_fn(self):
@@ -195,72 +447,124 @@ class TransformerDecoderLM:
         return forward
 
     def prefill_fn(self):
-        """Prompt ingestion: dense causal forward over ONE padded
-        prompt, then every layer's K/V scattered into the pool in place
-        — one scatter for K and one for V at ``(layer, block, offset)``
-        through the request's block table, no layer's slice taken out
-        and written back. ``(params, tokens[1, Tb], k_pool, v_pool,
-        table[1, mb], length[1]) -> (logits[1, V], k_pool, v_pool)`` —
-        logits are at the LAST REAL position (``length - 1``); pad
-        positions write to the null block."""
+        """Prompt ingestion over ONE padded prompt. ``(params,
+        tokens[1, Tb], *cache, *index, length[1]) -> (logits[1, V],
+        *cache)``: ``cache`` is ``k_pool, v_pool`` for a net of
+        attention layers (``index`` the request's block table ``[1,
+        mb]``), ``state, norm`` for one of retention layers (``index``
+        the request's state ``[1]``), both pairs and both indices for a
+        mixed one. Logits are at the LAST REAL position (``length -
+        1``).
+
+        Attention layers: dense causal attention, then every layer's
+        K/V scattered into the pool in place — one scatter for K and
+        one for V at ``(layer, block, offset)``, no layer's slice taken
+        out and written back; pad positions write to the null block.
+        Retention layers: the chunked form from the state the slot
+        holds (zeros for a fresh sequence) to the state after
+        ``length`` tokens, written back to the slot."""
+        from ..ops.retention import power_retention_chunked
         from .kvcache import paged_prefill_write_all
 
-        def prefill(params, tokens, k_pool, v_pool, table, length):
+        def prefill(params, tokens, *operands):
             import jax.numpy as jnp
 
+            *operands, length = operands
+            arrays, index = self._split_cache(operands)
+            t = tokens.shape[1]
+            pos = jnp.arange(t, dtype=jnp.int32)
+            mask = jnp.tril(jnp.ones((t, t), bool))[None, None]
             ks, vs = [], []
 
-            def write_kv(li, k, v):
+            def attend(lyr, h, carry, j):
+                q, k, v = self._qkv(lyr, h, pos)
                 # (Tb, KVH * hd): a token's row as the pool keeps it
-                ks.append(k[0].reshape(k.shape[1], -1))
-                vs.append(v[0].reshape(v.shape[1], -1))
+                ks.append(k[0].reshape(t, -1))
+                vs.append(v[0].reshape(t, -1))
+                return self._dense_attend(q, k, v, mask), carry
 
-            h = self._trunk_dense(params, tokens, write_kv=write_kv)
-            k_pool = paged_prefill_write_all(k_pool, table[0], length[0],
-                                             jnp.stack(ks))
-            v_pool = paged_prefill_write_all(v_pool, table[0], length[0],
-                                             jnp.stack(vs))
-            last = jnp.clip(length - 1, 0, tokens.shape[1] - 1)
+            def retain(lyr, h, carry, j):
+                q, k, v = self._qkv(lyr, h, pos)
+                S, z = carry
+                at = index["retention"][0]
+                o, (s1, z1) = power_retention_chunked(
+                    q[0], k[0], v[0], self._log_gate(lyr, h)[0],
+                    (S[j, at], z[j, at]), length[0], RETENTION_CHUNK)
+                return o[None], (S.at[j, at].set(s1), z.at[j, at].set(z1))
+
+            x = self._embed(params, tokens, pos[None])
+            h, state = self._through_layers(
+                params, x, arrays.get("retention"),
+                {"attention": attend, "retention": retain})
+            if state is not None:
+                arrays["retention"] = state
+            if ks:
+                table = index["attention"][0]
+                arrays["attention"] = tuple(
+                    paged_prefill_write_all(pool, table, length[0],
+                                            jnp.stack(rows))
+                    for pool, rows in zip(arrays["attention"], (ks, vs)))
+            last = jnp.clip(length - 1, 0, t - 1)
             h_last = jnp.take_along_axis(
                 h, last[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-            return h_last @ params["head"], k_pool, v_pool
+            return (h_last @ params["head"],) + self._join_cache(arrays)
 
         return prefill
 
     def decode_step_fn(self):
-        """One decode step for the whole slot batch: append each active
-        slot's token K/V to the pool, attend through the block table,
-        return next-token logits. ``(params, token[B], pos[B], k_pool,
-        v_pool, tables[B, mb], active[B]) -> (logits[B, V], k_pool,
-        v_pool)``. Inactive slots write to the null block and read an
-        empty context — the step is branch-free in slot liveness. The
-        pool is updated and read in place: each layer scatters its rows
-        at ``[li, blk, off]`` and hands the kernel the WHOLE pool with
-        the layer index, never ``k_pool[li]``."""
+        """One decode step for the whole slot batch. ``(params,
+        token[B], pos[B], *cache, *index, active[B]) -> (logits[B, V],
+        *cache)`` with ``cache`` and ``index`` as :meth:`prefill_fn`
+        takes them (``index`` a row a slot: ``tables[B, mb]``,
+        ``states[B]``). The step is branch-free in slot liveness.
+
+        Attention layers append each active slot's K/V to the pool and
+        attend through the block table; inactive slots write to the
+        null block and read an empty context. The pool is updated and
+        read in place: each layer scatters its rows at ``[li, blk,
+        off]`` and hands the kernel the WHOLE pool with the layer
+        index, never ``k_pool[li]``. Retention layers step each active
+        slot's state in place and read it in the same pass; the states
+        of inactive slots are neither read nor written."""
         from ..ops.flash_attention import paged_decode_attention
+        from ..ops.retention import power_retention_step
         from .kvcache import slot_coords
 
-        def step(params, token, pos, k_pool, v_pool, tables, active):
+        def step(params, token, pos, *operands):
             import jax.numpy as jnp
 
-            block_size = k_pool.shape[2]
+            *operands, active = operands
+            arrays, index = self._split_cache(operands)
             pos_c = jnp.clip(pos, 0, self.max_seq - 1)
-            x = params["embed"][token] + params["pos"][pos_c]
-            blk, off = slot_coords(tables, pos_c, block_size, active)
-            # context includes the token being written THIS step
-            ctx = jnp.where(active, pos_c + 1, 0).astype(jnp.int32)
-            scale = 1.0 / (self.head_dim ** 0.5)
-            for li, lyr in enumerate(params["layers"]):
-                h = _ln(x, lyr["ln1_g"], lyr["ln1_b"])
-                q, k, v = self._qkv(lyr, h)       # (B, H/KVH, hd)
+            x = self._embed(params, token, pos_c)
+            if "attention" in arrays:
+                tables = index["attention"]
+                blk, off = slot_coords(tables, pos_c,
+                                       arrays["attention"][0].shape[2],
+                                       active)
+                # context includes the token being written THIS step
+                ctx = jnp.where(active, pos_c + 1, 0).astype(jnp.int32)
+                scale = 1.0 / (self.head_dim ** 0.5)
+
+            def attend(lyr, h, carry, j):
+                k_pool, v_pool = carry["attention"]
+                q, k, v = self._qkv(lyr, h, pos_c)   # (B, H/KVH, hd)
                 rows = (k.shape[0], -1)  # (B, KVH * hd), the pool's row
-                k_pool = k_pool.at[li, blk, off].set(k.reshape(rows))
-                v_pool = v_pool.at[li, blk, off].set(v.reshape(rows))
+                k_pool = k_pool.at[j, blk, off].set(k.reshape(rows))
+                v_pool = v_pool.at[j, blk, off].set(v.reshape(rows))
                 o = paged_decode_attention(q, k_pool, v_pool, tables, ctx,
-                                           scale=scale, layer=li)
-                x = x + o.reshape(x.shape[0], -1) @ lyr["wo"]
-                x = x + self._mlp(lyr, _ln(x, lyr["ln2_g"], lyr["ln2_b"]))
-            h = _ln(x, params["lnf_g"], params["lnf_b"])
-            return h @ params["head"], k_pool, v_pool
+                                           scale=scale, layer=j)
+                return o, {**carry, "attention": (k_pool, v_pool)}
+
+            def retain(lyr, h, carry, j):
+                q, k, v = self._qkv(lyr, h, pos_c)
+                o, state = power_retention_step(
+                    q, k, v, self._log_gate(lyr, h), carry["retention"],
+                    index["retention"], active, layer=j)
+                return o, {**carry, "retention": state}
+
+            h, arrays = self._through_layers(
+                params, x, arrays, {"attention": attend, "retention": retain})
+            return (h @ params["head"],) + self._join_cache(arrays)
 
         return step

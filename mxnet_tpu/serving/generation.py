@@ -23,9 +23,13 @@ removes it at three levels:
   token budget retires them — a late submit never waits for the
   running batch to drain, and a finished sequence never pads it.
 
-K/V state lives in the :class:`~.kvcache.PagedKVCache` block pool;
-the pools are DONATED through every prefill/decode dispatch, so cache
-memory is constant and aliased in place. Slot liveness is an operand
+What a live sequence keeps lives in the :class:`~.kvcache.SequenceCache`
+that the net's ``cache_spec()`` configures: K/V rows in the
+:class:`~.kvcache.PagedKVCache` block pool for attention layers, a
+fixed state a slot in the :class:`~.kvcache.StateStore` for retention
+layers. Its arrays are DONATED as one pytree through every
+prefill/decode dispatch, so cache memory is constant and aliased in
+place. Slot liveness is an operand
 (never a shape): ragged traffic — joins, retirements, wildly different
 lengths — reuses the same sealed executables with ZERO retraces after
 warmup (``RetraceForbidden`` otherwise, the PR-13 contract).
@@ -71,7 +75,7 @@ from .errors import (
     ServerOverloaded,
     ServingError,
 )
-from .kvcache import PagedKVCache
+from .kvcache import SequenceCache
 
 _SLOTS_DEFAULT = 8
 _CHUNK_DEFAULT = 8
@@ -250,13 +254,63 @@ class GenerateFuture:
 # the engine
 # ---------------------------------------------------------------------------
 
+def generation_programs(net, chunk):
+    """The two programs the engine compiles for ``net``, as pure
+    functions: ``chunk_fn`` (``chunk`` decode steps of the whole slot
+    batch with sampling and the EOS/budget bookkeeping in-graph) and
+    ``prefill_fn`` (one padded prompt and its first token). ``cache``
+    is the cache's arrays as one pytree, donated and returned; ``index``
+    the per-slot indices that go beside them (block tables, states'
+    slots), in the order the net's faces take them."""
+    import jax
+    import jax.numpy as jnp
+
+    step = net.decode_step_fn()
+    prefill = net.prefill_fn()
+    chunk_t = int(chunk)
+
+    def chunk_fn(params, cache, index, lens, token, active,
+                 remaining, rng, temp, top_k, top_p, greedy, eos):
+        def body(carry, _):
+            cache, lens, token, active, remaining, rng = carry
+            logits, *cache = step(params, token, lens, *cache, *index,
+                                  active)
+            rng, sub = jax.random.split(rng)
+            nxt = sample_tokens(logits, sub, temp, top_k, top_p,
+                                greedy)
+            emitted = active
+            nxt = jnp.where(emitted, nxt, 0)
+            lens = lens + active.astype(lens.dtype)
+            remaining = remaining - active.astype(remaining.dtype)
+            hit_eos = (nxt == eos) & (eos >= 0)
+            active = active & ~hit_eos & (remaining > 0)
+            return ((tuple(cache), lens, nxt, active, remaining, rng),
+                    (nxt, emitted))
+
+        carry = (tuple(cache), lens, token, active, remaining, rng)
+        carry, (toks, flags) = jax.lax.scan(body, carry, None,
+                                            length=chunk_t)
+        cache, lens, token, active, remaining, rng = carry
+        return (cache, lens, token, active, remaining, rng, toks, flags)
+
+    def prefill_fn(params, tokens, cache, index, length,
+                   seed_v, temp, top_k, top_p, greedy):
+        logits, *cache = prefill(params, tokens, *cache, *index, length)
+        key = jax.random.fold_in(jax.random.PRNGKey(0), seed_v[0])
+        tok = sample_tokens(logits, key, temp, top_k, top_p, greedy)
+        return tok, tuple(cache)
+
+    return chunk_fn, prefill_fn
+
+
 # an executable whose temporaries pass this share of one KV pool's bytes
 # is taken to copy the pool (in place they are a few per cent of it)
 _POOL_TEMP_SHARE_WARN = 0.25
 
 
 class GenerationEngine:
-    """Continuous-batching generation server over a paged KV cache.
+    """Continuous-batching generation server over the net's cache (a
+    paged KV pool, a state a slot, or both).
 
     ``shapes`` are PROMPT-LENGTH buckets (ints, or 1-tuples): each gets
     its own sealed prefill executable; the decode loop is ONE sealed
@@ -301,15 +355,19 @@ class GenerationEngine:
         self._queue_cap = (int(queue_cap) if queue_cap is not None
                            else serve_queue_cap())
         self._buckets = self._normalize_buckets(shapes)
-        self.cache = PagedKVCache(
-            dims["layers"], dims["kv_heads"], dims["head_dim"],
-            max_seq=self.max_seq, num_blocks=cache_blocks,
-            block_size=cache_block_size, name=self._name,
+        # what the net's layers keep for a live sequence, a layer kind;
+        # a net that says nothing keeps K/V in every layer
+        spec = net.cache_spec() if hasattr(net, "cache_spec") else {
+            "attention": {k: dims[k] for k in ("layers", "kv_heads",
+                                                 "head_dim")}}
+        self.cache = SequenceCache(
+            spec, slots=self._slots, max_seq=self.max_seq,
+            num_blocks=cache_blocks, block_size=cache_block_size,
+            name=self._name,
             # the pool holds what the net's K/V projections emit: a
             # float32 pool under a bf16 net doubles the cache and hands
             # the decode kernel mixed operand dtypes
             dtype=dtype or getattr(net, "dtype", "float32"))
-        self._mb = self.cache.max_blocks_per_seq
         self._lock = threading.Lock()
         self._queue = collections.deque()
         self._closing = False
@@ -337,7 +395,7 @@ class GenerationEngine:
         # slot state (scheduler-thread-private after start)
         n = self._slots
         self._slot_req = [None] * n
-        self._slot_tables = [None] * n
+        self._slot_seqs = [None] * n
         self._lens = _np.zeros(n, _np.int32)
         self._token = _np.zeros(n, _np.int32)
         self._active = _np.zeros(n, bool)
@@ -377,56 +435,20 @@ class GenerationEngine:
         import jax
         import jax.numpy as jnp
 
-        step = self._net.decode_step_fn()
-        prefill = self._net.prefill_fn()
+        chunk_fn, prefill_fn = generation_programs(self._net, self._chunk)
         params = self._net.params()
-        chunk_t = self._chunk
-
-        def chunk_fn(params, k_pool, v_pool, tables, lens, token, active,
-                     remaining, rng, temp, top_k, top_p, greedy, eos):
-            def body(carry, _):
-                k_pool, v_pool, lens, token, active, remaining, rng = carry
-                logits, k_pool, v_pool = step(params, token, lens, k_pool,
-                                              v_pool, tables, active)
-                rng, sub = jax.random.split(rng)
-                nxt = sample_tokens(logits, sub, temp, top_k, top_p,
-                                    greedy)
-                emitted = active
-                nxt = jnp.where(emitted, nxt, 0)
-                lens = lens + active.astype(lens.dtype)
-                remaining = remaining - active.astype(remaining.dtype)
-                hit_eos = (nxt == eos) & (eos >= 0)
-                active = active & ~hit_eos & (remaining > 0)
-                return ((k_pool, v_pool, lens, nxt, active, remaining,
-                         rng), (nxt, emitted))
-
-            carry = (k_pool, v_pool, lens, token, active, remaining, rng)
-            carry, (toks, flags) = jax.lax.scan(body, carry, None,
-                                                length=chunk_t)
-            k_pool, v_pool, lens, token, active, remaining, rng = carry
-            return (k_pool, v_pool, lens, token, active, remaining, rng,
-                    toks, flags)
-
-        def prefill_fn(params, tokens, k_pool, v_pool, table, length,
-                       seed_v, temp, top_k, top_p, greedy):
-            logits, k_pool, v_pool = prefill(params, tokens, k_pool,
-                                             v_pool, table, length)
-            key = jax.random.fold_in(jax.random.PRNGKey(0), seed_v[0])
-            tok = sample_tokens(logits, key, temp, top_k, top_p, greedy)
-            return tok, k_pool, v_pool
-
-        n, mb = self._slots, self._mb
+        n = self._slots
         self._params = params
         self._rng = jax.random.PRNGKey(int(seed))
-        k_shape = self.cache.k_pool
-        chunk_args = (params, k_shape, self.cache.v_pool,
-                      jnp.zeros((n, mb), jnp.int32),
+        # every slot empty: block tables of null blocks, the null state
+        chunk_args = (params, self.cache.arrays(),
+                      self.cache.rows([None] * n),
                       jnp.zeros(n, jnp.int32), jnp.zeros(n, jnp.int32),
                       jnp.zeros(n, bool), jnp.zeros(n, jnp.int32),
                       self._rng, jnp.ones(n, jnp.float32),
                       jnp.zeros(n, jnp.int32), jnp.ones(n, jnp.float32),
                       jnp.ones(n, bool), jnp.full(n, -1, jnp.int32))
-        jfn = jax.jit(chunk_fn, donate_argnums=(1, 2))
+        jfn = jax.jit(chunk_fn, donate_argnums=(1,))
         t0 = time.perf_counter()
         self._chunk_exe = jfn.lower(*chunk_args).compile()
         self._record_compile("decode_chunk", t0, self._chunk_exe)
@@ -435,23 +457,23 @@ class GenerationEngine:
             _obs.introspect.register_jit(
                 "decode_chunk", jfn,
                 _obs.introspect.avals_of(chunk_args), donated=True)
-        # warm run: all slots inactive -> writes land in the null block,
-        # lens unchanged, rng advances; adopts the returned pools
+        # warm run: all slots inactive -> writes land in the null block
+        # and the null state, lens unchanged, rng advances; adopts the
+        # returned arrays
         out = self._chunk_exe(*chunk_args)
         jax.block_until_ready(out[0])
-        self.cache.update_pools(out[0], out[1])
-        self._rng = out[6]
+        self.cache.adopt(out[0])
+        self._rng = out[5]
 
         self._prefill_exes = {}
-        jpf = jax.jit(prefill_fn, donate_argnums=(2, 3))
+        jpf = jax.jit(prefill_fn, donate_argnums=(2,))
         for tb in self._buckets:
             if tb > self.max_seq:
                 raise MXNetError(
                     f"prompt bucket {tb} exceeds the net's max_seq "
                     f"{self.max_seq}")
             args = (params, jnp.zeros((1, tb), jnp.int32),
-                    self.cache.k_pool, self.cache.v_pool,
-                    jnp.zeros((1, mb), jnp.int32),
+                    self.cache.arrays(), self.cache.rows([None]),
                     jnp.zeros(1, jnp.int32), jnp.zeros(1, jnp.int32),
                     jnp.ones(1, jnp.float32), jnp.zeros(1, jnp.int32),
                     jnp.ones(1, jnp.float32), jnp.ones(1, bool))
@@ -465,30 +487,33 @@ class GenerationEngine:
                 _obs.introspect.register_jit(
                     site, jpf, _obs.introspect.avals_of(args),
                     donated=True)
-            # warm run: length 0 -> every write goes to the null block
-            tok, kp, vp = exe(*args)
+            # warm run: length 0 -> every write goes to the null block,
+            # and the null state is left as it was
+            tok, arrays = exe(*args)
             jax.block_until_ready(tok)
-            self.cache.update_pools(kp, vp)
+            self.cache.adopt(arrays)
         if self._pool_temp_share > _POOL_TEMP_SHARE_WARN:
             logging.getLogger(__name__).warning(
                 "generation engine %s:%s: executable %s holds temporaries "
-                "of %.2f times a KV pool's bytes (%d blocks, %d bytes): at "
-                "a deployment's pool that is a copy of the pool come back, "
-                "and the executable's time will follow the pool's size",
+                "of %.2f times a cache array's bytes (%d blocks, %d bytes): "
+                "at a deployment's cache that is a copy of it come back, "
+                "and the executable's time will follow the cache's size",
                 self._name, self._version, self._pool_temp_worst,
                 self._pool_temp_share, self.cache.num_blocks,
-                self.cache.k_pool.nbytes)
+                self.cache.array_bytes())
         self._sealed = True
 
     def _record_compile(self, what, t0, exe):
-        """Counts the compile and checks that ``exe`` works on the pool
-        in place: its temporaries over one pool's bytes. A copy of the
-        pool is a temporary of the pool's size (the share passes 1);
-        in place it is a few per cent (logits, the dense prefill's
-        scores). ``stats()["pool_temp_share"]`` keeps the largest."""
+        """Counts the compile and checks that ``exe`` works on the
+        cache in place: its temporaries over the bytes of the largest
+        array it threads through (a KV pool, the retention layers'
+        states). A copy of that array is a temporary of its size (the
+        share passes 1); in place it is a few per cent (logits, the
+        dense prefill's scores). ``stats()["pool_temp_share"]`` keeps
+        the largest."""
         self._compiles += 1
         share = (exe.memory_analysis().temp_size_in_bytes
-                 / self.cache.k_pool.nbytes)
+                 / self.cache.array_bytes())
         if share > self._pool_temp_share:
             self._pool_temp_share, self._pool_temp_worst = share, what
         if _obs.ENABLED:
@@ -668,7 +693,7 @@ class GenerationEngine:
             if not req.claim():  # lost to cancel()
                 continue
             try:
-                table = self.cache.allocate(len(req.prompt))
+                seq = self.cache.allocate(len(req.prompt))
             except KVCacheOOM as e:
                 if self._active.any():
                     # blocks free as running sequences retire: put the
@@ -683,29 +708,28 @@ class GenerationEngine:
             req.t_admit = time.perf_counter()
             admitted += 1
             try:
-                self._prefill(req, table, free[0])
+                self._prefill(req, seq, free[0])
             except BaseException as e:  # noqa: BLE001 - typed to waiter
-                self.cache.release(table)
+                self.cache.release(seq)
                 self._fail(req, e if isinstance(e, ServingError) else
                            ServingError(f"prefill failed: {e}"), "error")
 
-    def _prefill(self, req, table, slot):
+    def _prefill(self, req, seq, slot):
         plen = len(req.prompt)
         tb = self._bucket_for(plen)
         with _obs.span("gen.prefill", cat="generation", rid=req.rid,
                        bucket=tb, prompt_len=plen, slot=slot):
-            self._prefill_traced(req, table, slot, plen, tb)
+            self._prefill_traced(req, seq, slot, plen, tb)
 
-    def _prefill_traced(self, req, table, slot, plen, tb):
+    def _prefill_traced(self, req, seq, slot, plen, tb):
         import jax.numpy as jnp
 
         padded = _np.zeros((1, tb), _np.int32)
         padded[0, :plen] = req.prompt
-        k, v = self.cache.pools()
         t0 = time.perf_counter()  # dt: staging, the call and the sync
         operands = (
-            jnp.asarray(padded), k, v,
-            table.device_row(self._mb)[None, :],
+            jnp.asarray(padded), self.cache.arrays(),
+            self.cache.rows([seq]),
             _np.array([plen], _np.int32),  # mxtpu-lint: host-sync-ok
             _np.array([req.seed], _np.int32),  # mxtpu-lint: host-sync-ok
             _np.array([max(req.temperature, 1e-6)], _np.float32),  # mxtpu-lint: host-sync-ok
@@ -713,14 +737,14 @@ class GenerationEngine:
             _np.array([req.top_p], _np.float32),  # mxtpu-lint: host-sync-ok
             _np.array([req.greedy], bool))  # host operand staging  # mxtpu-lint: host-sync-ok
         with _obs.span("gen.prefill.device", cat="generation", bucket=tb):
-            tok, k, v = self._prefill_exes[tb](self._params, *operands)
-            self.cache.update_pools(k, v)
+            tok, arrays = self._prefill_exes[tb](self._params, *operands)
+            self.cache.adopt(arrays)
             # the ONE deliberate per-request sync: the first token
             # decides retire-or-seat before the next chunk can include
             # this slot
             first = int(_np.asarray(tok)[0])  # mxtpu-lint: host-sync-ok
         dt = time.perf_counter() - t0
-        table.length = plen
+        self.cache.written(seq, plen)
         self._prefills += 1
         now = time.perf_counter()
         req.tokens.append(first)
@@ -733,10 +757,10 @@ class GenerationEngine:
         done = (req.max_new <= 1
                 or (req.eos >= 0 and first == req.eos))
         if done:
-            self._retire(req, table)
+            self._retire(req, seq)
             return
         self._slot_req[slot] = req
-        self._slot_tables[slot] = table
+        self._slot_seqs[slot] = seq
         self._lens[slot] = plen  # next decode step writes position plen
         self._token[slot] = first
         self._active[slot] = True
@@ -757,13 +781,13 @@ class GenerationEngine:
         at the boundary (where the NEXT _admit can seat a newcomer)."""
         with _obs.span("gen.chunk", cat="generation") as sp:
             with _obs.span("gen.chunk.prep", cat="generation"):
-                tables = self._grow_tables()
-                if tables is None:
+                index = self._grow_sequences()
+                if index is None:
                     return
                 t0 = time.perf_counter()  # dt: staging, the call and the syncs
-                operands = self._chunk_operands(tables)
+                operands = self._chunk_operands(index)
             if sp is not _obs.NO_SPAN:
-                # read after the chunk's growth, where the pool is fullest
+                # read after the chunk's growth, where the cache is fullest
                 sp.set(blocks_used=self.cache.blocks_used())
             with _obs.span("gen.chunk.device", cat="generation",
                            steps=self._chunk):
@@ -773,10 +797,11 @@ class GenerationEngine:
                 emitted, retired = self._deliver(toks, flags, dt)
                 out.set(emitted=emitted, retired=retired)
 
-    def _grow_tables(self):
-        """Backs the chunk's cache growth slot by slot; the slots'
-        block tables as one ``(slots, max_blocks)`` array, or ``None``
-        when no slot is left to step."""
+    def _grow_sequences(self):
+        """Backs the chunk's cache growth slot by slot (blocks of the
+        pool; a state grows by nothing); the slots' index operands
+        (block tables, states' slots), or ``None`` when no slot is left
+        to step."""
         # a pool too full to grow a sequence retires that request early
         # (typed OOM)
         for s in range(self._slots):
@@ -786,27 +811,22 @@ class GenerationEngine:
                 self._chunk,
                 int(self._remaining[s]))  # host numpy mirror  # mxtpu-lint: host-sync-ok
             try:
-                self.cache.ensure(self._slot_tables[s],
+                self.cache.ensure(self._slot_seqs[s],
                                   min(need, self.max_seq))
             except KVCacheOOM as e:
                 req = self._slot_req[s]
-                self.cache.release(self._slot_tables[s])
+                self.cache.release(self._slot_seqs[s])
                 self._clear_slot(s)
                 self._fail(req, e, "shed")
         if not self._active.any():
             return None
-        tables = _np.zeros((self._slots, self._mb), _np.int32)
-        for s in range(self._slots):
-            if self._slot_tables[s] is not None:
-                tables[s] = self._slot_tables[s].device_row(self._mb)
-        return tables
+        return self.cache.rows(self._slot_seqs)
 
-    def _chunk_operands(self, tables):
+    def _chunk_operands(self, index):
         """Stages the chunk's operands."""
         import jax.numpy as jnp
 
-        k, v = self.cache.pools()
-        return (k, v, jnp.asarray(tables),
+        return (self.cache.arrays(), tuple(jnp.asarray(i) for i in index),
                 jnp.asarray(self._lens), jnp.asarray(self._token),
                 jnp.asarray(self._active), jnp.asarray(self._remaining),
                 self._rng, jnp.asarray(self._temp),
@@ -816,9 +836,9 @@ class GenerationEngine:
     def _run_chunk(self, operands):
         """The chunk's executable, to the last byte the scheduler needs
         of it: ``(tokens, emitted flags)``, each ``(chunk, slots)``."""
-        (k, v, lens, token, active, remaining, rng, toks, flags) = \
+        (arrays, lens, token, active, remaining, rng, toks, flags) = \
             self._chunk_exe(self._params, *operands)
-        self.cache.update_pools(k, v)
+        self.cache.adopt(arrays)
         self._rng = rng
         # ONE host sync per chunk: everything the scheduler needs
         # (np.array copies — jax device views are read-only and the
@@ -861,9 +881,9 @@ class GenerationEngine:
                                                     model=self._name)
                 emitted_total += n
             if not self._active[s]:
-                table = self._slot_tables[s]
+                seq = self._slot_seqs[s]
                 self._clear_slot(s)
-                self._retire(req, table)
+                self._retire(req, seq)
                 retired += 1
         self._tokens += emitted_total
         if _obs.ENABLED:
@@ -879,14 +899,14 @@ class GenerationEngine:
 
     def _clear_slot(self, s):
         self._slot_req[s] = None
-        self._slot_tables[s] = None
+        self._slot_seqs[s] = None
         self._active[s] = False
         self._lens[s] = 0
         self._token[s] = 0
         self._remaining[s] = 0
 
-    def _retire(self, req, table):
-        self.cache.release(table)
+    def _retire(self, req, seq):
+        self.cache.release(seq)
         self._requests_ok += 1
         if _obs.ENABLED:
             _obs.record_serve_request(self._name, "ok")
@@ -905,8 +925,8 @@ class GenerationEngine:
         for s in range(self._slots):
             req = self._slot_req[s]
             if req is not None:
-                if self._slot_tables[s] is not None:
-                    self.cache.release(self._slot_tables[s])
+                if self._slot_seqs[s] is not None:
+                    self.cache.release(self._slot_seqs[s])
                 self._clear_slot(s)
                 self._fail(req, err, "closed")
         self._idle.set()
@@ -1043,8 +1063,7 @@ class GenerationEngine:
         self._chunk_exe = None
         self._prefill_exes = {}
         self._params = None
-        self.cache.k_pool = None
-        self.cache.v_pool = None
+        self.cache.release_arrays()
 
     def __del__(self):
         try:

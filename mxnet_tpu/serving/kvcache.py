@@ -43,6 +43,12 @@ copy has come back). Everything device-side (gather/scatter through the
 table) lives in the pure helpers at the bottom so the decode model and
 the tests target the same code.
 
+A net whose layers keep a fixed recurrent state a sequence (power
+retention) gets a second kind of cache beside the pool, or in its
+place: :class:`StateStore`, one state a decode slot, no table, nothing
+that grows. :class:`SequenceCache` is the one manager the generation
+engine talks to; the net's ``cache_spec()`` says which parts it has.
+
 Knobs: ``MXTPU_KVCACHE_BLOCKS`` (pool size), ``MXTPU_KVCACHE_BLOCK_SIZE``
 (tokens per block). Gauges: ``mxtpu_kvcache_blocks_used`` /
 ``mxtpu_kvcache_occupancy_ratio``; counters ``mxtpu_kvcache_forks_total``
@@ -280,6 +286,271 @@ class PagedKVCache:
             "forks": self.forks,
             "cow_copies": self.cow_copies,
         }
+
+
+class StateStore:
+    """A fixed recurrent state a live sequence, for layers that keep one
+    (power retention: ``S`` and its normaliser ``z``, float32). Two
+    device arrays hold every layer's states, ``(layers, slots + 1,
+    kv_heads, head_dim, P)`` and ``(layers, slots + 1, kv_heads, R,
+    head_dim)`` (:func:`mxnet_tpu.ops.retention.state_shapes`); the
+    last slot is the NULL slot, where steps of slots that are not live
+    are routed. A state never grows and has no table: a sequence holds
+    one slot id from :meth:`allocate` to :meth:`release`. ``allocate``
+    zeroes the slot on the device (in place, the arrays donated), since
+    prefill starts from the state its slot holds.
+
+    The surface is the pool's, with a block read as one sequence's
+    state: ``num_blocks`` (slots and the null slot), ``blocks_used()``,
+    ``stats()``."""
+
+    _GUARDED_BY = {"_free": "_lock"}
+
+    def __init__(self, layers, kv_heads, head_dim, *, slots, name="model",
+                 gauges=True):
+        import jax
+        import jax.numpy as jnp
+
+        from ..ops.retention import state_shapes
+
+        # beside a paged pool the ``mxtpu_kvcache_*`` gauges are the pool's
+        self._own_gauges = bool(gauges)
+
+        self.layers = int(layers)
+        self.kv_heads = int(kv_heads)
+        self.head_dim = int(head_dim)
+        self.slots = int(slots)
+        self.num_blocks = self.slots + 1
+        self.null = self.slots
+        self.name = str(name)
+        s_shape, z_shape = state_shapes(self.layers, self.slots,
+                                        self.kv_heads, self.head_dim)
+        self.state = jnp.zeros(s_shape, jnp.float32)
+        self.norm = jnp.zeros(z_shape, jnp.float32)
+        self.bytes_per_state = (self.state.nbytes + self.norm.nbytes) \
+            // self.num_blocks
+        self._lock = threading.Lock()
+        self._free = list(range(self.slots - 1, -1, -1))  # pop() -> 0
+
+        def zero(state, norm, slot):
+            return (state.at[:, slot].set(0.0), norm.at[:, slot].set(0.0))
+
+        # compiled here, on the null slot: never inside a served window
+        self._zero = jax.jit(zero, donate_argnums=(0, 1))
+        self._clear(self.null)
+
+    def _clear(self, slot):
+        self.state, self.norm = self._zero(
+            self.state, self.norm, np.int32(slot))
+
+    def arrays(self):
+        return self.state, self.norm
+
+    def adopt(self, state, norm):
+        self.state, self.norm = state, norm
+
+    def allocate(self) -> int:
+        """A zeroed slot for a fresh sequence; typed OOM when every
+        slot is held."""
+        with self._lock:
+            if not self._free:
+                if _obs.ENABLED:
+                    _obs.KVCACHE_OOM_TOTAL.inc(1, model=self.name)
+                raise KVCacheOOM(
+                    f"state store exhausted: all {self.slots} sequence "
+                    "states are held")
+            slot = self._free.pop()
+        self._clear(slot)
+        self._gauges()
+        return slot
+
+    def release(self, slot: int):
+        with self._lock:
+            self._free.append(int(slot))
+        self._gauges()
+
+    def _gauges(self):
+        if _obs.ENABLED and self._own_gauges:
+            used = self.blocks_used()
+            _obs.KVCACHE_BLOCKS_USED.set(used, model=self.name)
+            _obs.KVCACHE_OCCUPANCY.set(used / max(1, self.slots),
+                                       model=self.name)
+
+    def blocks_used(self) -> int:
+        with self._lock:
+            return self.slots - len(self._free)
+
+    def stats(self) -> dict:
+        used = self.blocks_used()
+        return {
+            "num_blocks": self.num_blocks,
+            "blocks_used": used,
+            "occupancy": used / max(1, self.slots),
+            "state_bytes_reserved": self.bytes_per_state * self.num_blocks,
+            "state_bytes_in_use": self.bytes_per_state * used,
+        }
+
+
+class Sequence:
+    """What one live sequence holds of a :class:`SequenceCache`: a
+    block table where the net has attention layers, a state's slot
+    where it has retention layers."""
+
+    __slots__ = ("table", "state")
+
+    def __init__(self, table=None, state=None):
+        self.table, self.state = table, state
+
+
+class SequenceCache:
+    """The generation engine's cache manager: whatever the net's layers
+    keep for a live sequence, configured by the net's ``cache_spec()``
+    (a layer kind -> its geometry). ``attention`` layers get a
+    :class:`PagedKVCache` that grows block by block; ``retention``
+    layers a :class:`StateStore` of one fixed state a decode slot. A
+    sequence is admitted when every part can hold it, grows only where
+    a part grows, and is released from all of them at once.
+
+    The engine threads ``arrays()`` through its executables as one
+    donated pytree and hands back what they return (``adopt``); ``rows``
+    stages the per-slot indices that go beside them (block tables,
+    state slots). ``num_blocks`` / ``blocks_used()`` / ``occupancy()``
+    are the paged pool's where there is one, else the state store's (a
+    block is then one sequence's state); ``stats()`` gives both."""
+
+    def __init__(self, spec, *, slots, max_seq=None, num_blocks=None,
+                 block_size=None, dtype="float32", name="model"):
+        unknown = set(spec) - {"attention", "retention"}
+        if unknown or not spec:
+            raise ValueError(
+                "a cache spec names 'attention' and/or 'retention' "
+                f"layers; got {sorted(spec)}")
+        self.name = str(name)
+        self.pool = self.states = None
+        if "attention" in spec:
+            a = spec["attention"]
+            self.pool = PagedKVCache(
+                a["layers"], a["kv_heads"], a["head_dim"], max_seq=max_seq,
+                num_blocks=num_blocks, block_size=block_size, dtype=dtype,
+                name=name)
+        if "retention" in spec:
+            r = spec["retention"]
+            self.states = StateStore(r["layers"], r["kv_heads"],
+                                     r["head_dim"], slots=slots, name=name,
+                                     gauges=self.pool is None)
+        self._main = self.pool if self.pool is not None else self.states
+
+    # -- what the pool's readers read --------------------------------------
+    @property
+    def k_pool(self):
+        return self.pool.k_pool if self.pool is not None else None
+
+    @property
+    def layers(self):
+        return self._main.layers
+
+    @property
+    def num_blocks(self):
+        return self._main.num_blocks
+
+    def blocks_used(self) -> int:
+        return self._main.blocks_used()
+
+    def occupancy(self) -> float:
+        return self.blocks_used() / max(1, self.num_blocks - 1)
+
+    def array_bytes(self) -> int:
+        """Bytes of the largest array an executable threads through:
+        what a copy of the cache inside one would cost."""
+        return max(a.nbytes for a in self.arrays())
+
+    # -- the arrays, as the executables take them ---------------------------
+    def arrays(self):
+        """``(k_pool, v_pool)``, ``(state, norm)`` or all four: the
+        net's faces take them in this order."""
+        out = ()
+        if self.pool is not None:
+            out += self.pool.pools()
+        if self.states is not None:
+            out += self.states.arrays()
+        return out
+
+    def adopt(self, arrays):
+        """The arrays a dispatch returned (the donated ones are dead)."""
+        arrays = tuple(arrays)
+        if self.pool is not None:
+            self.pool.update_pools(*arrays[:2])
+            arrays = arrays[2:]
+        if self.states is not None:
+            self.states.adopt(*arrays)
+
+    def rows(self, sequences):
+        """The index operands for a batch of sequences (``None`` for an
+        empty slot): block tables ``(B, max_blocks)`` and/or state
+        slots ``(B,)``, int32, in ``arrays()``'s order of kinds."""
+        out = ()
+        if self.pool is not None:
+            mb = self.pool.max_blocks_per_seq
+            tables = np.zeros((len(sequences), mb), np.int32)
+            for i, seq in enumerate(sequences):
+                if seq is not None:
+                    tables[i] = seq.table.device_row(mb)
+            out += (tables,)
+        if self.states is not None:
+            out += (np.asarray(
+                [self.states.null if seq is None else seq.state
+                 for seq in sequences], np.int32),)
+        return out
+
+    def release_arrays(self):
+        """Drops the device arrays (the engine was released)."""
+        if self.pool is not None:
+            self.pool.k_pool = self.pool.v_pool = None
+        if self.states is not None:
+            self.states.state = self.states.norm = None
+
+    # -- a sequence's life ---------------------------------------------------
+    def allocate(self, num_tokens: int) -> Sequence:
+        """Room for a fresh sequence of ``num_tokens`` tokens in every
+        part, or typed OOM with nothing held: this is where the engine
+        learns whether a request can be admitted."""
+        seq = Sequence()
+        if self.pool is not None:
+            seq.table = self.pool.allocate(num_tokens)
+        if self.states is not None:
+            try:
+                seq.state = self.states.allocate()
+            except KVCacheOOM:
+                if seq.table is not None:
+                    self.pool.release(seq.table)
+                raise
+        return seq
+
+    def ensure(self, seq: Sequence, num_tokens: int):
+        """Room for ``seq`` to grow to ``num_tokens`` tokens: blocks of
+        the pool; a state needs none."""
+        if self.pool is not None:
+            self.pool.ensure(seq.table, num_tokens)
+
+    def written(self, seq: Sequence, num_tokens: int):
+        """``seq`` now holds ``num_tokens`` tokens (the pool's
+        copy-on-write looks at a table's length)."""
+        if seq.table is not None:
+            seq.table.length = int(num_tokens)
+
+    def release(self, seq: Sequence):
+        """Gives back everything ``seq`` holds. Idempotent."""
+        if seq.table is not None:
+            self.pool.release(seq.table)
+        if seq.state is not None:
+            self.states.release(seq.state)
+            seq.state = None
+
+    def stats(self) -> dict:
+        out = dict(self._main.stats())
+        if self.pool is not None and self.states is not None:
+            out["states"] = self.states.stats()
+        return out
 
 
 # ---------------------------------------------------------------------------

@@ -159,3 +159,89 @@ def test_decoder_works_on_the_pool_in_place_on_v5e(on_chip, monkeypatch,
     assert exe.memory_analysis().temp_size_in_bytes < 0.25 * pool_bytes
     assert exe.as_text().count(
         'custom_call_target="tpu_custom_call"') == kernels
+
+
+# B slots, H query heads over KVH KV heads of D, layers: the store is
+# (layers, B + 1, KVH, D, 65 * D) float32 and its normaliser, read and
+# rewritten in place at a layer that may be traced (a scan over layers)
+@pytest.mark.parametrize("B,H,KVH,D,L", [
+    (16, 40, 8, 128, 8),   # the Brumby cell: 16 slots of 8 x 34 MB a layer
+    (4, 8, 8, 128, 1),     # one query head a KV head
+], ids=["brumby_16_slots", "mha_d128"])
+def test_retention_decode_compiles_for_v5e(on_chip, B, H, KVH, D, L):
+    from mxnet_tpu.ops import retention as R
+
+    s_shape, z_shape = R.state_shapes(L, B, KVH, D)
+    args = (on_chip((B, H, D), jnp.bfloat16),
+            on_chip((B, KVH, D), jnp.bfloat16),
+            on_chip((B, KVH, D), jnp.bfloat16),
+            on_chip((B, KVH), jnp.float32), on_chip(s_shape, jnp.float32),
+            on_chip(z_shape, jnp.float32), on_chip((B,), jnp.int32),
+            on_chip((B,), bool), on_chip((), jnp.int32))
+    exe = jax.jit(R._pallas_step, donate_argnums=(4, 5)).lower(
+        *args).compile()
+    calls = [line for line in exe.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 1
+    assert calls[0].lstrip().startswith("%mxtpu_retention_decode")
+    # in place: no temporary of a state's size, let alone the store's
+    assert exe.memory_analysis().temp_size_in_bytes < D * 65 * D * 4
+
+
+@pytest.mark.parametrize("which", ["decode_step_fn", "prefill_fn"])
+def test_retention_decoder_works_on_the_states_in_place_on_v5e(
+        on_chip, monkeypatch, which):
+    """The Brumby layer at its published widths (two layers under the
+    scan, a small vocabulary), as the generation engine compiles it:
+    with the states donated the executable's temporaries stay a small
+    share of the state array, and the decode step's one kernel is the
+    retention kernel."""
+    import json
+
+    from chipbench.models import retention_lm as glue
+    from mxnet_tpu.ops import retention as R
+
+    monkeypatch.setattr(R, "_use_pallas", lambda d: True)  # no TPU here
+    layers, slots, bucket = 2, 16, 512
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "chipbench", "configs",
+            "brumby_14b.json")) as f:
+        cfg = {**json.load(f), "num_hidden_layers": layers,
+               "vocab_size": 512}
+    L, d, ff, hd, V = layers, 5120, 17408, 128, 512
+    assert (d, ff, hd) == (cfg["hidden_size"], cfg["intermediate_size"],
+                           cfg["head_dim"])
+    leaves = {"ln1_g": (L, d), "ln2_g": (L, d), "wq": (L, d, 40 * hd),
+              "wk": (L, d, 8 * hd), "wv": (L, d, 8 * hd),
+              "wo": (L, 40 * hd, d), "q_norm": (L, hd), "k_norm": (L, hd),
+              "wg": (L, d, 8), "bg": (L, 8), "w_gate": (L, d, ff),
+              "w_up": (L, d, ff), "w_down": (L, ff, d)}
+    assert set(glue.LAYER_LEAVES) == set(leaves)
+    # 660 MB a layer: shapes only, held as the benchmark's glue holds
+    # its weights
+    net = glue.build_net(cfg, {
+        "embed": on_chip((V, d), jnp.bfloat16),
+        "head": on_chip((d, V), jnp.bfloat16),
+        "lnf_g": on_chip((d,), jnp.bfloat16),
+        **{k: on_chip(shape, jnp.bfloat16) for k, shape in leaves.items()},
+    }, "bfloat16")
+    params = net.params()
+    s_shape, z_shape = R.state_shapes(layers, slots, 8, hd)
+    state = (on_chip(s_shape, jnp.float32), on_chip(z_shape, jnp.float32))
+    if which == "decode_step_fn":
+        args = (params, on_chip((slots,), jnp.int32),
+                on_chip((slots,), jnp.int32), *state,
+                on_chip((slots,), jnp.int32), on_chip((slots,), bool))
+        donate, kernels, share = (3, 4), 1, 0.25
+    else:
+        args = (params, on_chip((1, bucket), jnp.int32), *state,
+                on_chip((1,), jnp.int32), on_chip((1,), jnp.int32))
+        # a chunk's phi(q) alone is 340 MB beside two layers' 1.16 GB of
+        # states: the bound is a copy of them, not a share
+        donate, kernels, share = (2, 3), 0, 1.0
+    exe = jax.jit(getattr(net, which)(),
+                  donate_argnums=donate).lower(*args).compile()
+    assert exe.memory_analysis().temp_size_in_bytes \
+        < share * 4 * math.prod(s_shape)
+    assert exe.as_text().count(
+        'custom_call_target="tpu_custom_call"') == kernels

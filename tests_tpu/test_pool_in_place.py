@@ -100,7 +100,7 @@ def report(eng):
     """What the test asserts on, as one JSON-able dict."""
     import jax
 
-    c = eng.cache
+    c = eng.cache.pool  # the manager's paged part
     geometry = (c.layers, c.num_blocks, c.block_size, c.kv_heads,
                 c.head_dim)
     out = {"cache_blocks": c.num_blocks,
