@@ -23,6 +23,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -684,100 +685,226 @@ flash_attention_core.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 # ---------------------------------------------------------------------------
 
 
-def _paged_decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_scr, l_scr, acc_scr, *, scale, bs, mb, kvh, d):
-    """One grid step per (sequence, kv block): grid ``(B, max_blocks)``.
-    The block tables and context lengths ride the scalar-prefetch lane,
-    so each step's K/V DMA source address is ``(layer, tables[seq, j])``
-    — the pool block of the call's layer, ALL kv heads of it: a ``(1, 1,
-    bs, KVH*D)`` window spans the pool's last two dimensions, which is
-    what Mosaic's tiling rule asks of a block; a head is a static slice
-    of ``D`` lanes of it. Mosaic double-buffers the NEXT block's fetch
-    against THIS block's compute. Online softmax in fp32 VMEM scratch
-    per kv head, exactly the prefill kernel's recurrence with q_len =
-    group (the GQA query heads of one kv head)."""
-    seq = pl.program_id(0)
-    j = pl.program_id(1)
-    ctx = lens_ref[seq]
-    col0 = j * bs
+# VMEM the kernel spends on K/V in flight: two buffers of K and two of
+# V, a group of blocks each
+_PAGED_BUFFER_BYTES = 1 << 20
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    @pl.when(col0 < ctx)
-    def _compute():
-        for h in range(kvh):
-            q = q_ref[0, h]                              # (group, d)
-            k = k_ref[0, 0, :, h * d:(h + 1) * d]        # (bs, d)
-            v = v_ref[0, 0, :, h * d:(h + 1) * d]        # (bs, d)
+def _paged_group_blocks(block_size, width, itemsize, max_blocks):
+    """Blocks of a sequence that land in one VMEM buffer and are
+    attended to in one compute step: as many as the buffer budget
+    holds, a power of two (at a block of 16 tokens a group's rows then
+    fill whole lane tiles of the scores) and no more than a table
+    holds."""
+    fit = _PAGED_BUFFER_BYTES // (4 * block_size * width * itemsize)
+    g = 1
+    while 2 * g <= min(fit, max_blocks):
+        g *= 2
+    return g
+
+
+def _paged_decode_kernel(layer_ref, tables_ref, lens_ref, q_ref, seg_ref,
+                         k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, a_scr,
+                         m_scr, l_scr, acc_scr, *, scale, bs, gb):
+    """One invocation walks the LIVE blocks of every sequence, ``gb`` of
+    them a compute step. The pools stay in HBM; block ``j`` of sequence
+    ``b`` is copied from ``(layer, tables[b, j])`` into rows ``(j % gb)
+    * bs ..`` of a ``(gb * bs, KVH*D)`` buffer by an explicit DMA,
+    double buffered: while one group is attended to the next one is in
+    flight, and that is the next group of the same sequence or, at a
+    sequence's last group, the first group of the next sequence that
+    has a context. A sequence with ``ctx == 0`` costs a scalar test.
+
+    All heads at once: the sequence's queries are spread to a block
+    diagonal ``a`` ``(H, KVH*D)`` (row ``h`` holds query head ``h`` in
+    the lanes of its kv head and zeros elsewhere; ``seg_ref`` is that
+    pattern), so ``a . K^T`` is every head's scores ``(H, rows)`` in
+    one product with K's whole rows, and ``p . V`` ``(H, KVH*D)`` holds
+    each head's weighted sum in the lanes of its kv head; what the
+    other lanes hold is never read. Online softmax in float32 over the
+    groups, exactly the prefill kernel's recurrence with the heads as
+    its rows."""
+    B, group, _ = q_ref.shape
+    mb = tables_ref.shape[1]
+    rows = gb * bs
+    layer = layer_ref[0]
+
+    def ctx_of(b):
+        return jnp.minimum(lens_ref[b], mb * bs)
+
+    def fetch(b, j, slot, wait):
+        """Start, or wait for, the copies of sequence ``b``'s group
+        ``j`` into buffer ``slot``: its live blocks only."""
+        n = pl.cdiv(ctx_of(b), bs)
+        for i in range(gb):
+            blk = j * gb + i
+
+            @pl.when(blk < n)
+            def _(i=i, blk=blk):
+                src = tables_ref[b, blk]
+                dst = pl.ds(i * bs, bs)
+                for s, (pool, buf) in enumerate(((k_hbm, k_buf),
+                                                 (v_hbm, v_buf))):
+                    copy = pltpu.make_async_copy(
+                        pool.at[layer, src], buf.at[slot, dst],
+                        sems.at[s, slot])
+                    if wait:
+                        copy.wait()
+                    else:
+                        copy.start()
+
+    def next_live(b):
+        """The first sequence after ``b`` with a context, or ``B``."""
+        return lax.while_loop(
+            lambda x: jnp.logical_and(
+                x < B, lens_ref[jnp.minimum(x, B - 1)] <= 0),
+            lambda x: x + 1, b + 1)
+
+    # rows of a buffer past a context keep what an earlier group left
+    # there and weigh 0: they only have to be finite
+    k_buf[...] = jnp.zeros_like(k_buf)
+    v_buf[...] = jnp.zeros_like(v_buf)
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+    first = next_live(-1)
+
+    @pl.when(first < B)
+    def _prologue():
+        fetch(first, 0, 0, wait=False)
+
+    def sequence(b, slot):
+        ctx = ctx_of(b)
+        groups = pl.cdiv(ctx, rows)
+
+        @pl.when(groups > 0)
+        def _init():
+            a = jnp.zeros(a_scr.shape, jnp.float32)
+            for g in range(group):
+                a = a + (q_ref[b, g:g + 1, :].astype(jnp.float32)
+                         * seg_ref[g].astype(jnp.float32))
+            a_scr[...] = a.astype(a_scr.dtype)
+            m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+            l_scr[...] = jnp.zeros_like(l_scr)
+            acc_scr[...] = jnp.zeros_like(acc_scr)
+
+        def attend(j, slot):
+            last = j + 1 == groups
+            nb = lax.cond(last, lambda: next_live(b), lambda: b)
+            nj = jnp.where(last, 0, j + 1)
+
+            @pl.when(nb < B)
+            def _prefetch():
+                fetch(nb, nj, 1 - slot, wait=False)
+
+            fetch(b, j, slot, wait=True)
             s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + col0
+                a_scr[...], k_buf[slot], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # (H, rows)
+            cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * rows
             s = jnp.where(cols < ctx, s, _NEG_INF)
-            m_prev = m_scr[h]
+            m_prev = m_scr[...]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             p = jnp.exp(s - m_new)
             alpha = jnp.exp(m_prev - m_new)
-            l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
-            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1,
+                                                      keepdims=True)
+            acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+                p.astype(v_buf.dtype), v_buf[slot], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            m_scr[h] = m_new
+            m_scr[...] = m_new
+            return 1 - slot
 
-    @pl.when(j == mb - 1)
-    def _finish():
-        l = jnp.maximum(l_scr[:], 1e-30)
-        o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
+        slot = lax.fori_loop(0, groups, attend, slot)
+
+        @pl.when(groups > 0)
+        def _finish():
+            o = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+            for g in range(group):
+                o_ref[b, g:g + 1, :] = jnp.sum(
+                    jnp.where(seg_ref[g] != 0, o, 0.0), axis=0,
+                    keepdims=True).astype(o_ref.dtype)
+
+        return slot
+
+    lax.fori_loop(0, B, sequence, 0)
 
 
 def _pallas_paged_decode(q, k_pool, v_pool, tables, lens, scale,
                          layer=0, interpret=False):
     """``k_pool``/``v_pool`` are the WHOLE pool ``(layers, num_blocks,
-    block_size, KVH*D)``; ``layer`` (a static int) goes into the K/V
-    index map, so the kernel's DMAs address the layer's blocks inside
-    the pool and no slice of it is ever made."""
+    block_size, KVH*D)``, handed to the kernel where they lie in HBM;
+    ``layer`` rides the scalar-prefetch lane into the source of the
+    kernel's copies, so they address the layer's blocks inside the pool
+    and no slice of it is ever made."""
+    _, _, bs, width = k_pool.shape
+    gb = _paged_group_blocks(bs, width, k_pool.dtype.itemsize,
+                             tables.shape[1])
+    return _paged_decode_call(jnp.asarray(layer, jnp.int32).reshape(1),
+                              tables, lens, q, k_pool, v_pool, scale=scale,
+                              gb=gb, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "gb", "interpret"))
+def _paged_decode_call(layer, tables, lens, q, k_pool, v_pool, *, scale, gb,
+                       interpret):
+    """The kernel's call, a function of its own under ``jit`` with the
+    layer as an operand: a decoder's layers then share one trace and
+    one lowering of the kernel (traced layer by layer, twelve of them
+    cost a serve cell 9 s of every start). Queries go in and results
+    come out as ``(B, group, KVH*D)``: row ``g`` holds query head ``g``
+    of every kv head, each in its kv head's lanes (for one query head a
+    kv head that is ``q`` itself, reshaped)."""
     B, H, D = q.shape
     _, _, bs, width = k_pool.shape
     KVH = width // D
-    mb = tables.shape[1]
     group = H // KVH
-    qr = q.reshape(B, KVH, group, D)
-    q_spec = pl.BlockSpec((1, KVH, group, D),
-                          lambda i, j, tables, lens: (i, 0, 0, 0))
-    # the indirection: this grid step's K/V block is whichever POOL
-    # block of this layer the sequence's table names for logical block j
-    kv_spec = pl.BlockSpec(
-        (1, 1, bs, width),
-        lambda i, j, tables, lens: (layer, tables[i, j], 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, mb),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=q_spec,
-        scratch_shapes=[
-            pltpu.VMEM((KVH, group, 1), jnp.float32),
-            pltpu.VMEM((KVH, group, 1), jnp.float32),
-            pltpu.VMEM((KVH, group, D), jnp.float32),
-        ],
-    )
+    itemsize = k_pool.dtype.itemsize
+    # heads as whole sublane tiles of either operand dtype; the rows
+    # past H are zero queries that nothing reads
+    hp = -(-H // 16) * 16
+    head = np.arange(hp)[None, :, None]
+    lane_head = (np.arange(width) // D)[None, None, :]
+    seg = jnp.asarray(
+        head == lane_head * group + np.arange(group)[:, None, None],
+        q.dtype)                                         # (group, hp, width)
+    qr = q.reshape(B, KVH, group, D).swapaxes(1, 2).reshape(B, group, width)
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+    # what grows with the shapes: K/V in flight, and q, o and the
+    # pattern whole (each twice, as the pipeline holds them); the
+    # scratch and the values of a step ride in the margin
+    vmem = (4 * gb * bs * width * itemsize
+            + (4 * qr.size + 2 * seg.size) * q.dtype.itemsize)
     out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, scale=scale, bs=bs, mb=mb,
-                          kvh=KVH, d=D),
-        out_shape=jax.ShapeDtypeStruct((B, KVH, group, D), q.dtype),
-        grid_spec=grid_spec,
+        functools.partial(_paged_decode_kernel, scale=scale, bs=bs, gb=gb),
+        out_shape=jax.ShapeDtypeStruct(qr.shape, q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(1,),
+            in_specs=[whole, whole, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=whole,
+            scratch_shapes=[
+                pltpu.VMEM((2, gb * bs, width), k_pool.dtype),
+                pltpu.VMEM((2, gb * bs, width), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((hp, width), q.dtype),
+                pltpu.VMEM((hp, 1), jnp.float32),
+                pltpu.VMEM((hp, 1), jnp.float32),
+                pltpu.VMEM((hp, width), jnp.float32),
+            ],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=int(vmem + (8 << 20))),
         interpret=interpret,
         name="mxtpu_paged_decode",
-    )(tables, lens, qr, k_pool, v_pool)
-    return out.reshape(B, H, D)
+    )(layer, tables, lens, qr, seg, k_pool, v_pool)
+    return out.reshape(B, group, KVH, D).swapaxes(1, 2).reshape(B, H, D)
 
 
 def _jnp_paged_decode(q, k_pool, v_pool, tables, lens, scale, layer=0):
     """CPU path + oracle: materialize each slot's context via the same
-    ``(layer, table)`` gather the kernel's index map performs (one
+    ``(layer, table)`` gather the kernel's copies perform (one
     indexing step into the whole pool, no layer's slice in between),
     then masked softmax."""
     B, H, D = q.shape
@@ -812,23 +939,30 @@ def paged_decode_attention(query, k_pool, v_pool, block_tables,
     sequence, ``(B, H, D)``. With ``layer`` (a static int) K/V are the
     WHOLE paged pool as :class:`~mxnet_tpu.serving.PagedKVCache` keeps
     it, ``(layers, num_blocks, block_size, KVH*D)``, read in place: the
-    index goes into the kernel's index map, so no layer's slice of the
-    pool is ever materialised (a ``k_pool[li]`` operand is a copy of
-    that layer per call). Without ``layer`` they are one layer with the
-    heads apart, ``(num_blocks, block_size, KVH, D)`` — the same path
-    over a pool of one layer. ``block_tables`` ``(B, max_blocks)`` int32
-    names each sequence's pool blocks in logical order and
+    index goes into the source of the kernel's copies, so no layer's
+    slice of the pool is ever materialised (a ``k_pool[li]`` operand is
+    a copy of that layer per call). Without ``layer`` they are one layer
+    with the heads apart, ``(num_blocks, block_size, KVH, D)`` — the
+    same path over a pool of one layer. ``block_tables`` ``(B,
+    max_blocks)`` int32 names each sequence's pool blocks in logical
+    order and
     ``context_lens`` ``(B,)`` int32 is how many positions are valid
     (rows past it — padding and the null block — are masked).
 
-    TPU path: one grid step per (sequence, kv block) with the
-    tables/lengths scalar-prefetched so the index map itself performs
-    the block indirection and Mosaic overlaps the next block's DMA with
-    the current block's compute (``PrefetchScalarGridSpec``). A step
-    fetches all kv heads of its pool block; GQA is native: each kv
-    head's ``H/KVH`` query heads form the q rows, so each K/V block is
-    fetched once per group. CPU/debug path: the same math via a plain
-    gather (the test oracle).
+    TPU path: one kernel invocation a call. The tables and lengths are
+    scalar-prefetched, the pool stays in HBM, and the kernel walks each
+    sequence's ``ceil(ctx / block_size)`` live blocks only: a group of
+    blocks (as many as a fixed VMEM budget holds, 8 at 16 tokens of
+    768 lanes) is copied from ``(layer, tables[seq, j])`` by explicit
+    double-buffered DMAs, the next group (of this sequence, or the
+    first of the next one with a context) in flight while this one is
+    attended to; an empty slot costs a scalar test. All heads of a
+    group of blocks are two products: the queries spread to a block
+    diagonal ``(H, KVH*D)`` against K's whole rows, and the weights
+    against V's; GQA is native (a kv head's ``H/KVH`` query heads are
+    rows of the same diagonal block, so each K/V block is fetched
+    once). CPU/debug path: the same math via a plain gather (the test
+    oracle).
 
     Sequences with ``context_lens == 0`` (empty batch slots) return
     zeros. Grows O(1) per generated token — no T×S score matrix, no

@@ -36,7 +36,7 @@ prefill/decode executables (donated in, returned out); the cache
 object carries the current arrays between dispatches plus the host
 allocator state. The hand-off is IN PLACE: an executable only ever
 scatters rows into the whole pool (``.at[layer, blk, off]``) and reads
-it through the decode kernel's ``(layer, table)`` index map, so no
+it through the decode kernel's copies from ``(layer, table)``, so no
 operation produces a value of the pool's shape or of one layer's slice
 of it (``GenerationEngine.stats()["pool_temp_share"]`` says when a
 copy has come back). Everything device-side (gather/scatter through the

@@ -106,13 +106,15 @@ def test_flash_bwd_split_compiles_for_v5e(on_chip, case):
 
 
 # B, H, KVH, D, block_size, layers, num_blocks, max_blocks per sequence:
-# the kernel reads the whole pool (a token's heads side by side in its
-# row) at a static layer index
+# the kernel takes the whole pool where it lies (a token's heads side
+# by side in its row) and copies the live blocks of a static layer
 @pytest.mark.parametrize("B,H,KVH,D,bs,L,nb,mb", [
     (32, 12, 12, 64, 16, 2, 4096, 64),    # GPT-2-small heads, 1024 ctx
     (32, 32, 8, 128, 16, 2, 4096, 128),   # GQA 32Q/8KV at head_dim 128
     (128, 12, 12, 64, 16, 12, 3073, 64),  # the serve cell's pool, whole
-], ids=["mha_h12_d64", "gqa_h32_kv8_d128", "serve_chat_pool"])
+    (128, 12, 12, 64, 16, 12, 8193, 64),  # the pool an operator reserves
+], ids=["mha_h12_d64", "gqa_h32_kv8_d128", "serve_chat_pool",
+        "serve_chat_pool8193"])
 def test_paged_decode_compiles_for_v5e(on_chip, B, H, KVH, D, bs, L, nb, mb):
     q = on_chip((B, H, D), jnp.bfloat16)
     pool = on_chip((L, nb, bs, KVH * D), jnp.bfloat16)

@@ -263,28 +263,74 @@ def test_env_knob_defaults_and_floors(monkeypatch):
 # the Pallas decode kernel against the gather oracle (interpret mode)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("B,H,KVH,D,bs,mb,dtype,tol", [
-    (5, 4, 4, 16, 8, 6, jnp.float32, 1e-5),      # MHA, toy
-    (5, 8, 2, 32, 8, 5, jnp.float32, 1e-5),      # GQA, group 4
-    (5, 12, 12, 64, 16, 4, jnp.bfloat16, 2e-2),  # GPT-2-small heads
-], ids=["mha_f32", "gqa_f32", "mha_bf16_h12_d64"])
-def test_pallas_paged_decode_matches_gather_oracle(B, H, KVH, D, bs, mb,
-                                                   dtype, tol):
+def _group_lens(gb, bs, mb):
+    """Contexts around a group's edge: inside the first group, exactly
+    at its end, one block past it, one token past it, the full table."""
+    return [gb * bs - 3, gb * bs, (gb + 1) * bs, gb * bs + 1, mb * bs]
+
+
+# H, KVH, D, block_size, max_blocks, dtype, tolerance, then the
+# contexts (a list, or a function of the kernel's group of blocks),
+# whether the tables' unused entries name the null block, and the group
+# of blocks a smaller buffer budget is to force (several a sequence)
+_DECODE_CASES = {
+    "mha_f32": (4, 4, 16, 8, 6, jnp.float32, 1e-5, None, False, None),
+    "gqa_f32": (8, 2, 32, 8, 5, jnp.float32, 1e-5, None, False, None),
+    "mha_bf16_h12_d64": (12, 12, 64, 16, 4, jnp.bfloat16, 2e-2, None, False,
+                         None),
+    "group_edges": (4, 4, 16, 8, 12, jnp.float32, 1e-5, _group_lens, False,
+                    4),
+    "group_edges_gqa": (8, 2, 32, 8, 8, jnp.float32, 1e-5, _group_lens, True,
+                        2),
+    "groups_of_one_block": (4, 4, 16, 8, 6, jnp.float32, 1e-5, None, False,
+                            1),
+    "all_empty": (4, 4, 16, 8, 6, jnp.float32, 1e-5, [0, 0, 0, 0, 0], True,
+                  None),
+    "one_full_among_empty": (4, 2, 16, 8, 6, jnp.float32, 1e-5,
+                             [0, 0, 48, 0, 0], True, 2),
+    "live_and_empty_interleaved": (4, 4, 16, 8, 6, jnp.float32, 1e-5,
+                                   [5, 0, 47, 0, 9], False, 2),
+    "gqa_h32_kv8_d128": (32, 8, 128, 16, 4, jnp.bfloat16, 2e-2,
+                         [0, 17, 64, 33, 0], True, None),
+    "null_block_tail": (4, 4, 16, 8, 6, jnp.float32, 1e-5, None, True, None),
+}
+
+
+@pytest.mark.parametrize("case", list(_DECODE_CASES))
+def test_pallas_paged_decode_matches_gather_oracle(case, monkeypatch):
     """The kernel the TPU path takes, run by the Pallas interpreter
     (steered here, not by a program option), against
     ``_jnp_paged_decode`` on ragged contexts: an empty slot, one token,
-    a block boundary, a full table and a partial last block."""
+    a block boundary, a full table and a partial last block; contexts
+    on either side of a group's edge; nothing live, one slot live,
+    live and empty slots in turn; tables that end in the null block."""
     from mxnet_tpu.ops import flash_attention as fa
 
+    H, KVH, D, bs, mb, dtype, tol, lens, null_tail, gb = _DECODE_CASES[case]
+    itemsize = jnp.dtype(dtype).itemsize
+    if gb is not None:
+        monkeypatch.setattr(fa, "_PAGED_BUFFER_BYTES",
+                            gb * 4 * bs * KVH * D * itemsize)
+        assert gb < mb
+        assert fa._paged_group_blocks(bs, KVH * D, itemsize, mb) == gb
+    gb = fa._paged_group_blocks(bs, KVH * D, itemsize, mb)
+    if lens is None:
+        lens = [0, 1, bs, mb * bs, mb * bs - 3]
+    elif callable(lens):
+        lens = lens(gb, bs, mb)
+    B = len(lens)
     rng = np.random.RandomState(0)
     nb = B * mb + 1
     q = jnp.asarray(rng.randn(B, H, D), dtype)
     k_pool = jnp.asarray(rng.randn(nb, bs, KVH, D), dtype)
     v_pool = jnp.asarray(rng.randn(nb, bs, KVH, D), dtype)
     # every sequence owns distinct, shuffled pool blocks (never null 0)
-    tables = jnp.asarray(1 + rng.permutation(B * mb).reshape(B, mb),
-                         jnp.int32)
-    lens = jnp.asarray([0, 1, bs, mb * bs, mb * bs - 3], jnp.int32)
+    tables = 1 + rng.permutation(B * mb).reshape(B, mb)
+    if null_tail:  # as the cache hands a table over: null past its use
+        used = -(-np.asarray(lens) // bs)
+        tables = np.where(np.arange(mb)[None, :] < used[:, None], tables, 0)
+    tables = jnp.asarray(tables, jnp.int32)
+    lens = jnp.asarray(lens, jnp.int32)
     scale = D ** -0.5
     # the helpers take the whole pool, a token's heads side by side in
     # its row: one layer is a leading 1
@@ -297,7 +343,21 @@ def test_pallas_paged_decode_matches_gather_oracle(B, H, KVH, D, bs, mb,
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                rtol=tol, atol=tol)
-    assert not np.asarray(out, np.float32)[0].any()  # empty slot: zeros
+    # empty slots: zeros
+    assert not np.asarray(out, np.float32)[np.asarray(lens) == 0].any()
+
+
+@pytest.mark.parametrize("bs,width,itemsize,mb,want", [
+    (16, 768, 2, 64, 8),     # the serve cells: 128 rows of 768 lanes
+    (16, 1024, 2, 128, 8),   # 8 kv heads of 128
+    (16, 256, 2, 64, 32),    # a narrow row: more blocks in the budget
+    (16, 768, 2, 4, 4),      # no more than a table holds
+    (16, 65536, 4, 64, 1),   # a row the budget cannot hold twice: one
+], ids=["gpt2", "kv8_d128", "narrow", "short_table", "wide"])
+def test_paged_group_follows_the_shapes(bs, width, itemsize, mb, want):
+    from mxnet_tpu.ops import flash_attention as fa
+
+    assert fa._paged_group_blocks(bs, width, itemsize, mb) == want
 
 
 # ---------------------------------------------------------------------------
