@@ -1,12 +1,11 @@
 #!/usr/bin/env python
 """Operator micro-benchmark harness (reference: ``benchmark/opperf/`` —
-``opperf.py`` + per-category ``nd_operations/``; the BASELINE.md
-"operator micro-benchmarks" row).
+``opperf.py`` + per-category ``nd_operations/``).
 
 Times registered ops at benchmark-scale shapes on the CURRENT backend
 (whatever JAX picks; ``JAX_PLATFORMS=cpu`` pins the CPU).
-Chained-dependent iterations amortize the dispatch+sync cost exactly
-like bench.py (see BASELINE.md methodology).
+Chained-dependent iterations amortize the dispatch+sync cost
+(``mxnet_tpu.test_utils.chain_time_per_iter``).
 
 Usage:
   python benchmark/opperf.py                       # default op set
@@ -114,8 +113,7 @@ def _time_op_graph(name, arrays, attrs, chain=50):
     reference harness's warmed-up native timing. Chains are long
     (2*chain / 42*chain iterations; 100/2100 at the default --chain 50)
     because sub-50us kernels need hundreds of ms of spread to rise above
-    host-clock jitter (bench.py's allreduce section uses the same
-    lengths)."""
+    host-clock jitter."""
     import jax.numpy as jnp
 
     from mxnet_tpu.ops.registry import get

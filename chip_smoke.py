@@ -252,8 +252,8 @@ def _mlm_loss():
 
 def train_bert_base(compiles, *, batch, seq, vocab, steps, make_net=None,
                     dtype="bfloat16", seed=0, platform="tpu"):
-    """``parallel.SPMDTrainStep(net, mlm_loss, "adam", mesh=None)`` as
-    bench.py builds it. On a TPU the step's compiled HLO must hold a
+    """``parallel.SPMDTrainStep(net, mlm_loss, "adam", mesh=None)``.
+    On a TPU the step's compiled HLO must hold a
     ``tpu_custom_call`` (else attention quietly took the jnp path)."""
     import jax
 

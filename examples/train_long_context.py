@@ -4,7 +4,7 @@ Two ways to go past quadratic attention, both in this repo:
 
 1. ``sliding_window`` (this script): Mistral-style local attention — the
    banded Pallas kernels skip out-of-band block compute, O(T*W) FLOPs.
-   One chip handles 32k tokens (bench.py's sldwin line measures it).
+   One chip handles 32k tokens.
 2. Ring attention (``parallel/ring_attention.py``): exact full attention
    with the SEQUENCE sharded over a mesh axis and k/v blocks rotating
    over ICI — for when the context must be global.

@@ -92,7 +92,7 @@ def amp_allreduce_dtype() -> str:
 
 #: K-step superstep: how many full fwd+bwd+update iterations one
 #: gluon.Superstep dispatch runs on device (MXTPU_SUPERSTEP_K, default
-#: 1 = today's one-step behavior). Mutable at runtime for tests/bench.
+#: 1 = today's one-step behavior). Mutable at runtime for tests.
 SUPERSTEP_K = max(1, int(getenv("MXTPU_SUPERSTEP_K", 1, dtype=int)))
 
 

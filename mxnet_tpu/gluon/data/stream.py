@@ -93,7 +93,7 @@ def readahead_records() -> int:
 
 def emulated_latency_ms() -> float:
     """``MXTPU_STREAM_LATENCY_MS`` (default 0): emulated slow-storage
-    latency added to every shard read op — the bench/chaos knob that
+    latency added to every shard read op — the test/chaos knob that
     turns local files into 'remote object storage' so prefetch-ahead
     and backpressure are measurable without a network."""
     return max(0.0, float(getenv("MXTPU_STREAM_LATENCY_MS", 0.0,
@@ -857,7 +857,7 @@ class StreamReader:
         self._cv.notify_all()
 
 
-# -- shard authoring (tests/bench) ---------------------------------------
+# -- shard authoring (tests) ---------------------------------------------
 
 def write_recordio_shards(directory, samples, shard_size,
                           prefix="shard"):

@@ -2,7 +2,7 @@
 
 Same architecture definitions (BasicBlockV1/V2, BottleneckV1/V2,
 resnet18-152 v1/v2) and parameter naming so reference checkpoints load.
-Flagship model for the TPU benchmarks (bench.py).
+The convolutional net of ``chip_smoke.py``'s Gluon loop.
 """
 
 from __future__ import annotations

@@ -314,7 +314,7 @@ OVERLAP_EXPOSED_COMM_SECONDS = _REGISTRY.gauge(
     "mxtpu_overlap_exposed_comm_seconds",
     "per-step wall time NOT hidden behind compute, by comm mode "
     "(step time minus the compute-only probe's; set by the overlap "
-    "measurement probe — bench.py overlap / measure_overlap)")
+    "measurement probe, parallel.measure_overlap)")
 OVERLAP_HIDDEN_FRACTION = _REGISTRY.gauge(
     "mxtpu_overlap_hidden_fraction",
     "fraction of the staged baseline's exposed comm time the "
@@ -662,7 +662,7 @@ FLEET_RECOVERY_SECONDS = _REGISTRY.gauge(
     "mxtpu_fleet_recovery_seconds",
     "wall time from the last detected replica death to the autoscaler's "
     "replacement replica serving again, by model — the chaos "
-    "certification budget in bench.py fleet")
+    "budget (ServingFleet.last_recovery_s)")
 
 # -- autoregressive decode fast path (serving/generation.py, kvcache.py) ---
 
@@ -680,7 +680,7 @@ DECODE_ITL_SECONDS = _REGISTRY.histogram(
     "mxtpu_decode_inter_token_seconds",
     "amortized inter-token latency: decode-chunk wall time / tokens the "
     "slot emitted in that chunk (tokens of one chunk arrive together), "
-    "by model — p50/p99 are the bench's ITL baselines",
+    "by model — p50/p99 are stats()['itl_p50_ms'/'itl_p99_ms']",
     buckets=(0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
              0.025, 0.05, 0.1, 0.25))
 DECODE_PREFILL_SECONDS = _REGISTRY.histogram(

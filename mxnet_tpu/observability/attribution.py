@@ -72,7 +72,7 @@ ENABLED = bool(getenv("MXTPU_ATTRIBUTION", True, dtype=bool))
 PHASES = ("input_wait", "h2d", "ckpt_overhead", "comm_exposed",
           "compute", "host_gap")
 
-#: per-step records kept for the series gauge / flight bundle / bench
+#: per-step records kept for the series gauge / flight bundle
 _RECORDS = 128
 
 _STATE = {
@@ -103,7 +103,7 @@ def set_enabled(on: bool) -> bool:
 
 
 def reset():
-    """Pristine plane state (test isolation / bench scenario boundary):
+    """Pristine plane state (test isolation / scenario boundary):
     cumulative-counter anchors re-seed at the NEXT record_step, so a
     reset mid-run never attributes another scenario's backlog."""
     from . import (CHECKPOINT_TICK_SECONDS, DATA_H2D_SECONDS,
@@ -260,7 +260,7 @@ def record_step(t0: float, t1: float, k: int = 1, site: str = "trainer",
 
 
 # ---------------------------------------------------------------------------
-# read side (reports / flight bundle / bench stamps — off the hot path)
+# read side (reports / flight bundle — off the hot path)
 # ---------------------------------------------------------------------------
 
 def records() -> list:

@@ -198,7 +198,7 @@ def _world_size() -> int:
 
 def ingest(snap: dict, recv_mono=None):
     """Record one rank's snapshot into the cluster table (the seam the
-    exchange path, tests and bench synthetic ranks all feed)."""
+    exchange path and the tests' synthetic ranks both feed)."""
     rank = int(snap.get("rank", 0))
     entry = {"snap": snap,
              "recv": time.monotonic() if recv_mono is None else recv_mono}

@@ -68,9 +68,9 @@ def _block_active(q_pos0, col0, bq, bk, window):
 
 def _block_needs_mask(q_pos0, col0, bq, bk, window):
     """False for INTERIOR blocks (every (row, col) pair legal): skipping
-    the iota/where there recovers most of the causal-vs-dense gap —
-    measured 81 -> see bench (dense runs at 139 TFLOP/s; the mask was
-    a large share of the difference)."""
+    the iota/where there recovers most of the causal-vs-dense gap
+    (a pre-round reading: dense ran at 139 TFLOP/s, causal at 81, and
+    the mask was a large share of the difference)."""
     need = col0 + bk - 1 > q_pos0
     if window > 0:
         need = need | (q_pos0 + bq - 1 - col0 >= window)

@@ -876,7 +876,7 @@ class CheckpointManager:
     def flush(self, timeout=60.0):
         """Block until the writer finishes everything queued. Returns
         True when drained, False on timeout (callers that VERIFY after
-        flushing — bench, tests — must check it; the SIGTERM final
+        flushing — the tests do — must check it; the SIGTERM final
         save proceeds regardless, protected by per-write unique tmp
         dirs and the monotonic LATEST pointer)."""
         with self._cv:

@@ -38,7 +38,7 @@ RUNTIME (ROADMAP item 5):
 
 Zero committed steps are lost across a resize: the snapshot is taken
 at a step boundary, the restored state is bit-exact with the state the
-old mesh produced (regression- and bench-pinned), and the step counter
+old mesh produced (pinned by tests/test_elastic.py), and the step counter
 continues — no step re-runs, none is skipped. Every resize leaves an
 auditable in-memory snapshot descriptor
 (:func:`snapshot_descriptor`; ``tools/verify_checkpoint.py

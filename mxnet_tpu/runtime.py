@@ -19,9 +19,9 @@ _RETRY_RNG = random.Random()
 
 
 # ---------------------------------------------------------------------------
-# transient-failure retry (the PR-5 bench.py backend-init pattern, now a
-# shared primitive: backend bring-up, collective setup and the kvstore
-# barrier all retry through here instead of each growing its own loop)
+# transient-failure retry (a shared primitive: backend bring-up,
+# collective setup and the kvstore barrier all retry through here
+# instead of each growing its own loop)
 # ---------------------------------------------------------------------------
 
 def backoff_delays(attempts, base_delay, max_delay=30.0, jitter=True,
@@ -83,7 +83,7 @@ def init_backend(attempts=3):
     """Resolve the JAX backend with retry + backoff. Returns
     ``(backend_name, None)`` or ``(None, error_string)`` — one
     transient 'Unable to initialize backend' at startup must not erase
-    a run (VERDICT r5; formerly private to bench.py)."""
+    a run (VERDICT r5)."""
     try:
         return retry_with_backoff(jax.default_backend, attempts=attempts,
                                   desc="backend init"), None
@@ -129,7 +129,7 @@ def setup_compile_cache(path=None):
         jax.config.update("jax_compilation_cache_dir", path)
     # cache EVERY executable: the defaults skip sub-second compiles,
     # which is exactly the many-small-executables regime the fused step
-    # produces (and the whole of the CPU test/bench tier)
+    # produces (and the whole of the CPU test tier)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     _CACHE_STATE["dir"] = path

@@ -72,7 +72,9 @@ class SLOAutoscaler:
         # and NEVER .attach()-ed (that would hijack global wiring)
         self.monitor = monitor or MembershipMonitor(
             straggler_factor=0.0, notice_path="")
-        self._last_change_mono = 0.0
+        # no change yet: a monotonic clock starts at the host's boot, and
+        # 0.0 would put a fresh host in cooldown for its first cooldown_s
+        self._last_change_mono = float("-inf")
         self._reported_uids = set()
         self._replaced = 0
         self._thread = None

@@ -112,7 +112,7 @@ class InferenceEngine:
         self._closed = False
         self._paused = False
         # engine-local SLO state: independent of the global telemetry
-        # switch, so stats()/bench read real numbers with telemetry off
+        # switch, so stats() reads real numbers with telemetry off
         self._latency = _Histogram("local_latency")
         self._fill_sum = 0.0
         self._batches = 0
@@ -402,7 +402,8 @@ class InferenceEngine:
     def stats(self) -> dict:
         """Engine-local SLO snapshot (plain floats, works with global
         telemetry off). ``compiles`` is flat after seal — the
-        zero-recompiles-after-warmup contract the bench asserts."""
+        zero-recompiles-after-warmup contract
+        (tests/test_serving.py::test_engine_parity_and_zero_recompiles)."""
         p50 = self._latency.quantile(0.5)
         p99 = self._latency.quantile(0.99)
         return {

@@ -443,7 +443,7 @@ class ServingFleet:
     @property
     def last_recovery_s(self):
         """Detection->replacement latency of the most recent recovered
-        replica death (the bench's ``recovery_s``)."""
+        replica death (``mxtpu_fleet_recovery_seconds``)."""
         return self._last_recovery_s
 
     def note_recovery(self, seconds):

@@ -11,7 +11,8 @@ removes it at three levels:
   bookkeeping all in-graph) is AOT-compiled ONCE at deploy for a fixed
   slot count, so the host touches the loop once per chunk —
   amortized XLA dispatches per generated token are ``<= 1/chunk``
-  (the bench certifies this with a PR-6-style dispatch-count assert);
+  (tests/test_generation.py::test_single_dispatch_chunk_budget counts
+  them);
 - **on-device sampling** (:func:`sample_tokens`): greedy / temperature
   / top-k / top-p per SLOT (every request carries its own knobs as
   operands, so mixed sampling policies share one executable), PRNG
@@ -245,8 +246,9 @@ class GenerateFuture:
         return self._req.result
 
     def token_times(self):
-        """(t_first_token, t_last_token) perf_counter stamps — the
-        bench's ITL source (None until the request finishes)."""
+        """(t_first_token, t_last_token) perf_counter stamps, what a
+        caller computes its inter-token latency from (None until the
+        request finishes)."""
         return self._req.t_first, self._req.t_last
 
 

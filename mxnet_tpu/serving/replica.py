@@ -87,7 +87,7 @@ def rebuild_error(etype, emsg):
 
 def _dense_net(classes=4, feat=8, bias=0.0, scale=0.1):
     """Builtin deterministic worker net — ``y[c] = scale * sum(x) +
-    bias`` for every class ``c``. Process replicas and the bench build
+    bias`` for every class ``c``. Process replicas build
     it child-side without importing any test code; model VERSIONS are
     distinguishable by their bias (the swap-coherence probes rely on
     it)."""

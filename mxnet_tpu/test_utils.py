@@ -227,7 +227,7 @@ def chain_time_per_iter(step_fn, init, n1=5, n2=40, reps=3):
     sub-millisecond kernel, so single-shot timings measure the host,
     not the device. Chaining n iterations inside ONE jit and
     differencing two chain lengths cancels that fixed cost exactly.
-    Used by bench.py and tests_tpu/.
+    Used by tests_tpu/.
     """
     import time
 

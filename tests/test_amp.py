@@ -197,7 +197,7 @@ def _train_losses(dtype, steps=6, multi_precision=True):
 def test_bf16_fp32_loss_trajectory_parity():
     """The acceptance contract: bf16 training (cast policy + fp32
     masters) tracks the fp32 loss trajectory within bf16 tolerance on
-    the bench MLP."""
+    a small MLP."""
     l32 = _train_losses("float32")
     l16 = _train_losses("bfloat16")
     for a, b in zip(l32, l16):

@@ -2,7 +2,7 @@
 consumers: the budget-decomposition invariants, the hot-path hooks
 (trainer / prefetch-wait / watchdog), the zero-added-dispatch contract,
 the multi-track timeline export (tools/timeline.py), and mxtpu-doctor
-verdicts / --diff / --env (tools/mxtpu_doctor.py).
+verdicts / --env (tools/mxtpu_doctor.py).
 
 The plane is arithmetic over host floats the hot paths already record:
 every test here drives either REAL training steps or the exact record
@@ -29,6 +29,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from tools import mxtpu_doctor as doctor  # noqa: E402
+from tools import telemetry_report  # noqa: E402
 from tools import timeline  # noqa: E402
 
 
@@ -171,6 +172,9 @@ def test_series_gauge_and_trace_span_publish():
     assert args["site"] == "trainer"
     assert set(f"{ph}_ms" for ph in attr.PHASES) <= set(args), args
     assert args["period_ms"] == pytest.approx(5.0, rel=1e-4)
+    # and the report tool's section renders from those spans
+    section = telemetry_report.render_attribution(obs.tracer().events())
+    assert "Attribution" in section and "trainer" in section
 
 
 def test_disarmed_plane_records_nothing():
@@ -425,7 +429,7 @@ def test_doctor_cli_seeded_scenarios(tmp_path):
     for i in range(8):  # starved: waits dominate each 10 ms period
         obs.DATA_PREFETCH_WAIT_SECONDS.inc(0.006)
         attr.record_step(base + i * 0.010, base + i * 0.010 + 0.004)
-    attr.reset()  # scenario boundary (bench does the same): the idle
+    attr.reset()  # scenario boundary: the idle
     # gap between the two loops must not attribute as a giant host_gap
     for i in range(8):  # staged comm: the host-timed comm leg dominates
         attr.note_comm(0.005)
@@ -440,6 +444,9 @@ def test_doctor_cli_seeded_scenarios(tmp_path):
         capture_output=True, text=True, timeout=60)
     assert res.returncode == 0, res.stderr
     report = json.loads(res.stdout)
+    assert report["format"] == "mxtpu-doctor-v1" and "top" in report
+    assert all(v["verdict"] in doctor.RECIPES and v["recipe"]
+               for v in report["training"])
     verdicts = {v["site"]: v["verdict"] for v in report["training"]}
     assert verdicts["trainer"] == "input_bound", report
     assert verdicts["spmd_staged"] == "comm_bound", report
@@ -450,47 +457,6 @@ def test_doctor_cli_seeded_scenarios(tmp_path):
         capture_output=True, text=True, timeout=60)
     assert res.returncode == 0, res.stderr
     assert "input_bound" in res.stdout and "comm_bound" in res.stdout
-
-
-# ---------------------------------------------------------------------------
-# mxtpu-doctor --diff: which phase moved
-# ---------------------------------------------------------------------------
-
-def _bench_artifact(path, sps, input_ms):
-    path.write_text(json.dumps({
-        "scenario": "train_step", "steps_per_sec": sps,
-        "_phases": {"fused": {"input_wait_ms": input_ms, "h2d_ms": 0.0,
-                              "ckpt_overhead_ms": 0.0,
-                              "comm_exposed_ms": 0.0, "compute_ms": 5.0,
-                              "host_gap_ms": 0.5}}}))
-
-
-def test_doctor_diff_pinpoints_slowed_phase(tmp_path):
-    a, b = tmp_path / "a.json", tmp_path / "b.json"
-    _bench_artifact(a, 100.0, 0.1)
-    _bench_artifact(b, 60.0, 4.1)  # synthetically starve the input side
-    pd = doctor.phase_diff(str(a), str(b))
-    assert pd["dominant"]["phase"] == "input_wait", pd
-    assert pd["dominant"]["delta_ms"] == pytest.approx(4.0)
-    assert pd["dominant"]["share"] == pytest.approx(1.0)
-    line = doctor.phase_diff_one_liner(str(a), str(b))
-    assert "input_wait" in line and "slower" in line, line
-    # and the bench_diff gate prints that line on its failure path
-    res = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "tools", "bench_diff.py"),
-         str(b), str(a)],
-        capture_output=True, text=True, timeout=60)
-    assert res.returncode == 1, (res.stdout, res.stderr)
-    assert "mxtpu-doctor --diff: 'input_wait'" in res.stdout, res.stdout
-
-
-def test_doctor_diff_silent_without_phase_stamps(tmp_path):
-    """Artifacts without phase fields: the one-liner degrades to empty
-    (bench_diff must not print a bogus attribution)."""
-    a, b = tmp_path / "a.json", tmp_path / "b.json"
-    a.write_text(json.dumps({"steps_per_sec": 100.0}))
-    b.write_text(json.dumps({"steps_per_sec": 50.0}))
-    assert doctor.phase_diff_one_liner(str(a), str(b)) == ""
 
 
 # ---------------------------------------------------------------------------

@@ -383,6 +383,93 @@ def test_rolling_swap_keeps_version_coherent():
         fleet.close()
 
 
+def test_rolling_swap_under_traffic_serves_no_stale_version():
+    """Requests flowing while the fleet swaps replica by replica: every
+    answer is its stamped version's, and whatever is submitted after
+    ``swap()`` has returned is the new version's."""
+    fleet = _fleet(replicas=2)
+    want = {"v1": 0.1 * FEAT + 0.5, "v2": 0.1 * FEAT + 9.0}
+    done, during = threading.Event(), []
+
+    def pump():
+        while not done.is_set():
+            try:
+                fut = fleet.submit(X)
+                during.append((fut.version,
+                               float(np.asarray(fut.result(30.0)).ravel()[0])))
+            except (ReplicaLost, ServerOverloaded):
+                pass
+
+    t = threading.Thread(target=pump, daemon=True)
+    try:
+        t.start()
+        fleet.swap(dict(SPEC, version="v2", net={
+            "dense": {"classes": 4, "feat": FEAT, "bias": 9.0}}))
+        after = []
+        for _ in range(10):
+            fut = fleet.submit(X)
+            fut.result(30.0)
+            after.append(fut.version)
+        done.set()
+        t.join(timeout=30.0)
+        assert not t.is_alive() and during
+        assert after == ["v2"] * 10
+        for version, value in during:  # None: resolved before the stamp
+            answers = [want[version]] if version else want.values()
+            assert min(abs(value - w) for w in answers) < 1e-4, \
+                (version, value)
+    finally:
+        done.set()
+        fleet.close()
+
+
+def test_burst_sheds_by_priority_class_from_the_real_queue():
+    """A burst of all three classes at a twelve-deep queue whose engine
+    is held in its first batch, so the depth is the burst's and not the
+    host's scheduling: the brownout latches from the queue's own depth,
+    bulk is shed first and most, critical is never shed by policy (a
+    full queue's typed reject is backpressure, not policy), and every
+    admitted request is served once the engine runs again."""
+    spec = dict(SPEC, engine={"max_batch": 4, "max_wait_ms": 40.0,
+                              "queue_cap": 12})
+    fleet = ServingFleet(spec, name="burst", replicas=1,
+                         autostart_heartbeat=False, brownout_enter=0.5,
+                         brownout_exit=0.2, brownout_hold_s=30.0)
+    shed = {"bulk": 0, "interactive": 0, "critical": 0}
+    full, gate, held = 0, threading.Event(), threading.Event()
+
+    def hold(bucket, reqs):
+        held.set()
+        gate.wait(60.0)
+        return run(bucket, reqs)
+
+    try:
+        fleet.predict(X, timeout=60.0)
+        batcher = fleet.replica_set.replicas()[0]._repo.engine(
+            "burst")._batcher
+        run, batcher._dispatch = batcher._dispatch, hold
+        futs = [fleet.submit(X, priority="critical") for _ in range(4)]
+        assert held.wait(30.0)  # the engine is in its batch: the queue grows
+        for prio in ["bulk", "interactive", "critical"] * 40:
+            try:
+                futs.append(fleet.submit(X, priority=prio))
+            except BrownoutShed:
+                shed[prio] += 1
+            except ServerOverloaded:
+                full += 1
+        assert fleet.queue_fraction() == 1.0 and full > 0
+        assert fleet.brownout_level() == 2
+        assert len(futs) + full + sum(shed.values()) == 124
+        assert shed["critical"] == 0 and shed["bulk"] > 0, shed
+        assert shed["bulk"] >= shed["interactive"] > 0, shed
+        gate.set()
+        for f in futs:
+            assert f.result(timeout=60.0) is not None  # served, none hung
+    finally:
+        gate.set()
+        fleet.close()
+
+
 def test_heartbeat_walks_suspect_then_dead():
     fleet = _fleet(replicas=2, suspect_misses=2)
     rs = fleet.replica_set
@@ -444,9 +531,11 @@ def test_autoscaler_growth_respects_cooldown_and_max():
     try:
         for _ in range(20):
             fleet.router.record_latency(1.0)
-        scaler.tick()
+        # the clock of a host booted 100 s ago: younger than the cooldown
+        # (an autoscaler that has changed nothing yet is not cooling down)
+        scaler.tick(now=100.0)
         assert fleet.n_live() == 3
-        scaler.tick()  # still breaching, but cooldown + max cap hold
+        scaler.tick(now=101.0)  # still breaching: cooldown + max cap hold
         assert fleet.n_live() == 3
     finally:
         scaler.stop()

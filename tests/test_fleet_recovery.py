@@ -197,12 +197,13 @@ def test_process_replica_sigkill_fails_pending_typed():
         r.submit(X)
 
 
-@pytest.mark.slow
 def test_process_fleet_host_kill_recovery_end_to_end():
     """The tentpole certification in miniature: SIGKILL one of two
     host processes mid-traffic; every in-flight request is retried or
     typed-failed; the autoscaler replaces the host; the fleet serves
-    the same answers afterward."""
+    the same answers afterward. Not marked slow: it is tier-1's one
+    holder of the real OS-process kill, replace and serve (three child
+    processes of one subsystem, about 12 s)."""
     fleet = ServingFleet(SPEC, name="m", replicas=2, process=True,
                          heartbeat_s=0.3, suspect_misses=3)
     scaler = SLOAutoscaler(fleet, min_replicas=2, max_replicas=3,

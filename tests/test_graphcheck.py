@@ -398,5 +398,35 @@ def test_graph_cli_clean_and_canonical_sites_covered():
         f"registered: {out['sites']}")
 
 
+_HARNESS_RUNNER = """
+import json, sys
+sys.path.insert(0, {root!r})
+from tools.mxtpu_lint.graphcheck.harness import collect_records
+records, sites = collect_records()
+print("SITES=" + json.dumps(sites))
+"""
+
+
+# canonical-site coverage is certified every tier-1 run by
+# test_graph_cli_clean_and_canonical_sites_covered (the real CLI);
+# this harness twin compiles the same sites again
+@pytest.mark.slow
+def test_graphcheck_harness_covers_canonical_sites():
+    """The --graph trace harness must register AT LEAST the canonical
+    compiled-site set (trainer_fused, superstep, spmd_step/superstep,
+    kv_bucket, plus one of each prefixed family) — a silently-skipped
+    harness leg would otherwise let the graph gate fake green."""
+    res = subprocess.run(
+        [sys.executable, "-c", _HARNESS_RUNNER.format(root=ROOT)],
+        cwd=ROOT, capture_output=True, text=True, timeout=300)
+    assert res.returncode == 0, res.stderr[-3000:]
+    line = [ln for ln in res.stdout.splitlines()
+            if ln.startswith("SITES=")]
+    assert line, res.stdout[-2000:]
+    sites = json.loads(line[0][len("SITES="):])
+    missing = missing_canonical(sites)
+    assert missing == [], (missing, sites)
+
+
 if __name__ == "__main__":
     sys.exit(pytest.main([__file__, "-v"]))

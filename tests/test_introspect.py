@@ -1,8 +1,7 @@
 """Performance introspection (observability/introspect.py): per-site
 XLA cost/memory registration, donation verification, the MFU/roofline
 estimator's null-with-reason contract, graceful degradation on
-backends whose analyses return None/partial, profiler windows, and the
-bench.py flops_per_step/mfu stamping contract."""
+backends whose analyses return None/partial, and profiler windows."""
 
 import json
 import os
@@ -79,6 +78,24 @@ def test_fused_loop_registers_all_sites():
     # registration happens ONCE per site: the gauge sees the same value
     # and the table stays one row per site over repeated steps
     assert len([s for s in sites if s == "trainer_fused"]) == 1
+
+
+def test_superstep_site_registers_the_scan_cost():
+    """The K-step scan executable is a cost site of its own: its FLOPs
+    cover K iterations of what the one-step trio costs."""
+    from mxnet_tpu.gluon.data.prefetcher import stack_batches
+
+    introspect.set_enabled(True)
+    net, tr = _train_steps()
+    k = 2
+    sstep = gluon.Superstep(net, loss_fn, tr, k=k)
+    xs = stack_batches([mx.nd.ones((8, 8))] * k)
+    ys = stack_batches([mx.nd.zeros((8,))] * k)
+    sstep.step(xs, ys, 8)
+    rec = introspect.site_cost("superstep")
+    assert rec and rec["flops"] > 0
+    one_step, reason = introspect.flops_per_step()
+    assert reason is None and rec["flops"] / k > 0.5 * one_step
 
 
 def test_eager_op_sites_register():
@@ -334,7 +351,7 @@ def test_profile_window_writes_real_trace(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# report tool roofline + bench stamping contracts
+# report tool roofline
 # ---------------------------------------------------------------------------
 
 def _cost_event(site, **args):
@@ -375,30 +392,3 @@ def test_report_tool_renders_roofline(tmp_path, capsys):
     p.write_text("\n".join(json.dumps(ev) for ev in events) + "\n")
     assert tr.main([str(p)]) == 0
     assert "Executable roofline" in capsys.readouterr().out
-
-
-def test_bench_rows_always_carry_flops_and_mfu():
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir))
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
-    bench._EMIT_BUFFER = buf = []
-    try:
-        # no stamping at all -> explicit nulls + reason
-        bench._emit("t_metric_a", 1.0, "u")
-        # flops known, mfu unknowable (CPU) -> mfu null + reason
-        bench._emit("t_metric_b", 1.0, "u", flops_per_step=123.0)
-        # both known -> no reason field
-        bench._emit("t_metric_c", 1.0, "u", flops_per_step=123.0, mfu=0.2)
-    finally:
-        bench._EMIT_BUFFER = None
-    recs = [json.loads(ln) for ln in buf]
-    for rec in recs:
-        assert "flops_per_step" in rec and "mfu" in rec
-        if rec["mfu"] is None:
-            assert rec["mfu_reason"], rec
-    a, b, c = recs
-    assert a["flops_per_step"] is None and a["mfu"] is None
-    assert b["flops_per_step"] == 123.0 and b["mfu"] is None
-    assert c["mfu"] == 0.2 and "mfu_reason" not in c

@@ -24,9 +24,9 @@ def _smoke(name, input_size=224, classes=10, batch=1):
     return net
 
 
-# zoo construction stays tier-1 via resnet50_v1_shape / save-load
-# roundtrip; the train path through a zoo resnet runs every tier-1
-# round inside the bench smoke's resnet scenario
+# zoo construction stays tier-1 via the save-load roundtrip; the train
+# path through a zoo resnet runs every tier-1 round in
+# test_chip_smoke.py::test_train_resnet_phase_holds_the_fused_plan
 @pytest.mark.slow
 def test_resnet18_v1_forward_backward():
     net = vision.get_model("resnet18_v1", classes=10)
@@ -48,8 +48,8 @@ def test_resnet34_v2():
     _smoke("resnet34_v2", input_size=64)
 
 
-# zoo construction stays tier-1 via save-load roundtrip and the bench
-# smoke's resnet scenario (trains a zoo resnet every tier-1 round)
+# zoo construction stays tier-1 via the save-load roundtrip and
+# test_chip_smoke.py (trains a zoo resnet every tier-1 round)
 @pytest.mark.slow
 def test_resnet50_v1_shape():
     net = vision.get_model("resnet50_v1", classes=7)

@@ -313,6 +313,8 @@ def test_telemetry_report_cli(tmp_path):
     assert "Total Count" in out and "Avg (ms)" in out
     line = [l for l in out.splitlines() if l.startswith("trainer.step")][0]
     assert int(line.split()[1]) == 3
+    # the static graph-contracts section rides along on every report
+    assert "Graph contracts" in out and "spmd_step" in out
     # --cat filter drops other categories
     res2 = subprocess.run(
         [sys.executable, os.path.join(TOOLS, "telemetry_report.py"), path,

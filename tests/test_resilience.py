@@ -97,9 +97,14 @@ def test_interval_commits_retention_and_verify(tmp_path):
             # of [6, 8]). Flushing per step pins the schedule to step
             # counts — every interval boundary commits, deterministically
             assert mgr.flush(timeout=120), "checkpoint writer stuck"
-        steps = [s for s, _ in resilience.list_checkpoints(tmp_path / "ck")]
-        assert steps == [6, 8], steps  # keep=2 trimmed 2 and 4
+        kept = resilience.list_checkpoints(tmp_path / "ck")
+        assert [s for s, _ in kept] == [6, 8], kept  # keep=2 trimmed 2, 4
+        # the lifetime count, not what retention left: a dropped
+        # interval must not hide behind the trimming
+        assert mgr.commits == 4
         assert resilience.verify(tmp_path / "ck") == []
+        for _, d in kept:  # every retained step, not only the latest
+            assert resilience.verify(d) == []
         assert resilience.latest_checkpoint(tmp_path / "ck").endswith(
             "step_0000000008")
         assert mgr.last_error is None

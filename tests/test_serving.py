@@ -461,6 +461,19 @@ def test_engine_serves_quantized_net():
 
 # -- SLO observability -----------------------------------------------------
 
+def _tool(name):
+    import importlib
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir,
+                                    "tools"))
+    try:
+        return importlib.import_module(name)
+    finally:
+        sys.path.pop(0)
+
+
 def test_serving_metrics_and_slo_snapshot():
     obs.set_enabled(True)
     obs.reset()
@@ -484,6 +497,12 @@ def test_serving_metrics_and_slo_snapshot():
         assert snap["compiles"] == len(BUCKETS)
         names = [ev["name"] for ev in obs.tracer().events()]
         assert "serving.batch" in names and "serving.compile" in names
+        # the doctor reads its serving verdict from the same ring
+        doctor = _tool("mxtpu_doctor")
+        verdicts = doctor.diagnose(obs.tracer().events())["serving"]
+        assert verdicts and all(
+            v["verdict"] in doctor.RECIPES and v["requests"] > 0
+            for v in verdicts)
         text = obs.registry().dump_prometheus()
         assert "mxtpu_serving_latency_seconds" in text
     finally:
@@ -491,15 +510,7 @@ def test_serving_metrics_and_slo_snapshot():
 
 
 def test_report_serving_section():
-    import os
-    import sys
-
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir,
-                                    "tools"))
-    try:
-        import telemetry_report as tr
-    finally:
-        sys.path.pop(0)
+    tr = _tool("telemetry_report")
 
     events = [
         {"name": "serving.batch", "cat": "serving", "dur": 2000.0,

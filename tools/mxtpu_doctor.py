@@ -7,9 +7,8 @@ watchdog's ``anomaly`` instants, and the serving request phase spans —
 into one ranked verdict per workload instead of five metric families a
 human reads side by side::
 
-    python tools/mxtpu_doctor.py BENCH_telemetry.jsonl
-    python tools/mxtpu_doctor.py BENCH_telemetry.jsonl --json
-    python tools/mxtpu_doctor.py --diff BENCH_pr15_old.json BENCH_pr15.json
+    python tools/mxtpu_doctor.py trace.jsonl
+    python tools/mxtpu_doctor.py trace.jsonl --json
     python tools/mxtpu_doctor.py --env
 
 Verdict vocabulary (training sites): ``input_bound`` (the accelerator
@@ -20,12 +19,9 @@ split at the roofline ridge point when cost analysis is available).
 Every verdict carries evidence lines ("input_wait = 34% of step") and a
 concrete knob recipe ("raise MXTPU_DEVICE_PREFETCH ...").
 
-``--diff A B`` explains WHICH phase moved when the bench_diff gate
-fires: it re-runs the tolerance-banded comparison, then attributes the
-step-time delta to the phase fields both sides stamped
-(``bench_diff`` itself calls :func:`phase_diff_one_liner` on its
-failure path). ``--env`` is the ported ``tools/diagnose.py`` (legacy
-MXNet environment checker): backend visibility + env sanity.
+``trace.jsonl`` is a ring dump (``observability.dump_jsonl``).
+``--env`` is the ported legacy MXNet environment checker
+(``diagnose.py``): backend visibility + env sanity.
 
 Pure stdlib for trace analysis (runs on CI artifact hosts without jax);
 only ``--env`` imports jax/mxnet_tpu, best-effort.
@@ -56,7 +52,7 @@ RECIPES = {
         "gradient communication is exposed, not hidden behind compute",
         "use the bucket-ready overlapped comm mode (MXTPU_OVERLAP=ready) "
         "and/or raise MXTPU_OVERLAP_BUCKET_BYTES so collectives overlap "
-        "the backward (docs/performance.md, bench.py overlap)"),
+        "the backward (docs/performance.md)"),
     "host_bound": (
         "per-step host work (python, bookkeeping, checkpoint entry) "
         "dominates",
@@ -448,151 +444,6 @@ def render(report) -> str:
 
 
 # ---------------------------------------------------------------------------
-# --diff: which phase moved (the bench_diff failure-path one-liner)
-# ---------------------------------------------------------------------------
-
-def _phase_values(path) -> dict:
-    """phase name -> per-step ms, pooled over the phase fields a bench
-    artifact carries: scenario-object ``_phases`` blocks — flat
-    (``{"_phases": {"input_wait_ms": ...}}``) or keyed by leg
-    (``{"_phases": {"fused": {"input_wait_ms": ...}}}``) — and
-    emit-row ``phase_<name>_ms`` extras all load."""
-    with open(path) as f:
-        text = f.read()
-    docs = []
-    try:
-        docs = [json.loads(text)]
-    except ValueError:
-        for line in text.splitlines():
-            if line.strip():
-                try:
-                    docs.append(json.loads(line))
-                except ValueError:
-                    pass
-    pooled = {}
-    weights = {}
-
-    def pool_block(blk):
-        for ph in PHASES:
-            v = _num(blk, f"{ph}_ms")
-            if v is not None:
-                pooled[ph] = pooled.get(ph, 0.0) + v
-                weights[ph] = weights.get(ph, 0) + 1
-        for sub in blk.values():
-            if isinstance(sub, dict):
-                pool_block(sub)
-
-    def visit(obj):
-        if isinstance(obj, dict):
-            for key, val in obj.items():
-                if key == "_phases" and isinstance(val, dict):
-                    pool_block(val)
-                elif key.startswith("phase_") and key.endswith("_ms") \
-                        and isinstance(val, (int, float)):
-                    ph = key[len("phase_"):-len("_ms")]
-                    pooled[ph] = pooled.get(ph, 0.0) + float(val)
-                    weights[ph] = weights.get(ph, 0) + 1
-                else:
-                    visit(val)
-        elif isinstance(obj, list):
-            for v in obj:
-                visit(v)
-
-    visit(docs)
-    return {ph: pooled[ph] / max(weights.get(ph, 1), 1) for ph in pooled}
-
-
-def phase_diff(a_path, b_path) -> dict:
-    """Per-phase ms delta B - A, plus the dominant mover."""
-    a, b = _phase_values(a_path), _phase_values(b_path)
-    names = sorted(set(a) | set(b))
-    deltas = {ph: b.get(ph, 0.0) - a.get(ph, 0.0) for ph in names}
-    out = {"deltas_ms": {ph: round(d, 4) for ph, d in deltas.items()},
-           "a_ms": {ph: round(v, 4) for ph, v in a.items()},
-           "b_ms": {ph: round(v, 4) for ph, v in b.items()}}
-    movers = {ph: d for ph, d in deltas.items() if abs(d) > 0}
-    if movers:
-        dom = max(movers, key=lambda ph: abs(movers[ph]))
-        total = sum(abs(d) for d in movers.values())
-        out["dominant"] = {
-            "phase": dom, "delta_ms": round(movers[dom], 4),
-            "share": round(abs(movers[dom]) / total, 4) if total else 0.0}
-    return out
-
-
-def phase_diff_one_liner(a_path, b_path) -> str:
-    """The single line ``bench_diff`` prints when its gate fires: which
-    phase explains the step-time movement. Empty when neither side
-    stamped phase fields (the caller just skips printing)."""
-    try:
-        pd = phase_diff(a_path, b_path)
-    except Exception:
-        return ""
-    dom = pd.get("dominant")
-    if not dom:
-        return ""
-    direction = "slower" if dom["delta_ms"] > 0 else "faster"
-    return (f"mxtpu-doctor --diff: '{dom['phase']}' moved "
-            f"{dom['delta_ms']:+.3f} ms/step ({dom['share'] * 100:.0f}% "
-            f"of the phase-time movement) — the step got {direction} "
-            f"in that phase; run tools/mxtpu_doctor.py --diff for the "
-            f"full table")
-
-
-def _run_bench_diff(a_path, b_path):
-    """(checked, skipped, failures) via the sibling bench_diff module."""
-    import importlib.util
-
-    here = os.path.dirname(os.path.abspath(__file__))
-    spec = importlib.util.spec_from_file_location(
-        "_doctor_bench_diff", os.path.join(here, "bench_diff.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    a = mod.load_side(a_path)
-    b = mod.load_side(b_path)
-    return mod.diff(a, b)
-
-
-def diff_report(a_path, b_path) -> dict:
-    report = {"format": "mxtpu-doctor-diff-v1",
-              "a": a_path, "b": b_path,
-              "phase_diff": phase_diff(a_path, b_path),
-              "one_liner": phase_diff_one_liner(a_path, b_path)}
-    try:
-        checked, skipped, failures = _run_bench_diff(a_path, b_path)
-        report["bench_diff"] = {"checked": checked, "skipped": skipped,
-                                "regressions": failures}
-    except Exception as e:  # phase attribution still renders
-        report["bench_diff"] = {"error": str(e)}
-    return report
-
-
-def render_diff(report) -> str:
-    lines = [f"mxtpu-doctor --diff {report['a']} -> {report['b']}:"]
-    bd = report.get("bench_diff", {})
-    for f in bd.get("regressions", []) or []:
-        lines.append(f"  REGRESSION {f}")
-    if bd.get("checked") is not None:
-        lines.append(f"  bench_diff: {bd['checked']} metrics checked, "
-                     f"{len(bd.get('regressions') or [])} regressions")
-    pd = report["phase_diff"]
-    if pd.get("deltas_ms"):
-        lines.append(f"  {'Phase':<16}{'A (ms)':>10}{'B (ms)':>10}"
-                     f"{'Delta':>10}")
-        for ph in sorted(pd["deltas_ms"], key=lambda p:
-                         -abs(pd['deltas_ms'][p])):
-            lines.append(
-                f"  {ph:<16}{pd['a_ms'].get(ph, 0.0):>10.3f}"
-                f"{pd['b_ms'].get(ph, 0.0):>10.3f}"
-                f"{pd['deltas_ms'][ph]:>+10.3f}")
-    else:
-        lines.append("  (no phase fields stamped in either artifact)")
-    if report.get("one_liner"):
-        lines.append(f"  {report['one_liner']}")
-    return "\n".join(lines)
-
-
-# ---------------------------------------------------------------------------
 # --env: the ported tools/diagnose.py environment checker
 # ---------------------------------------------------------------------------
 
@@ -687,9 +538,6 @@ def main(argv=None) -> int:
     ap.add_argument("--site", default=None,
                     help="only report this attribution site "
                          "(trainer / superstep / spmd / ...)")
-    ap.add_argument("--diff", nargs=2, metavar=("A", "B"), default=None,
-                    help="explain which phase moved between two bench "
-                         "artifacts (BENCH_*.json or emit-row JSONL)")
     ap.add_argument("--env", action="store_true",
                     help="environment & backend sanity report (the "
                          "ported legacy tools/diagnose.py)")
@@ -700,13 +548,8 @@ def main(argv=None) -> int:
         print(json.dumps(report, indent=2, default=str) if args.json
               else render_env(report))
         return 0
-    if args.diff:
-        report = diff_report(*args.diff)
-        print(json.dumps(report, indent=2, default=str) if args.json
-              else render_diff(report))
-        return 0
     if not args.trace:
-        ap.error("need a trace file (or --diff/--env)")
+        ap.error("need a trace file (or --env)")
     source = sys.stdin.read() if args.trace == "-" else args.trace
     events = load_events(source)
     report = diagnose(events)

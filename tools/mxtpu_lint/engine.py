@@ -35,7 +35,7 @@ import re
 
 #: what a repo-wide run scans, relative to the root (directories walk
 #: recursively; plain files are linted as-is)
-DEFAULT_TARGETS = ("mxnet_tpu", "tools", "bench.py")
+DEFAULT_TARGETS = ("mxnet_tpu", "tools")
 
 _SKIP_DIRS = {"__pycache__", ".git", ".baseline_wt"}
 

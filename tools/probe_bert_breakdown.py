@@ -3,7 +3,7 @@
 samples/s, push toward 50% MFU.)
 
 Variants (all SPMDTrainStep, bs64 seq128 bf16):
-  full      bench configuration (adam, MLM CE over 30522 vocab)
+  full      the whole step (adam, MLM CE over 30522 vocab)
   meanhead  loss = mean(logits) — drops log_softmax+pick, keeps decoder
   nodec     model without the vocab decoder, loss = mean(hidden)
   sgd       full loss but SGD (isolates adam update cost)

@@ -25,7 +25,7 @@ Usage:
 
 The output is plain ``{"traceEvents": [...]}`` JSON — load it in
 chrome://tracing or https://ui.perfetto.dev. Import-safe as a module
-(the bench smoke and the attribution tests call ``build_timeline``).
+(the attribution tests call ``build_timeline``).
 """
 
 from __future__ import annotations
