@@ -16,7 +16,9 @@ removes it at three levels:
 - **on-device sampling** (:func:`sample_tokens`): greedy / temperature
   / top-k / top-p per SLOT (every request carries its own knobs as
   operands, so mixed sampling policies share one executable), PRNG
-  keys folded and threaded device-side — no sync to pick a token;
+  keys folded and threaded device-side — no sync to pick a token; the
+  filter's sort of the vocabulary runs only in a step where some live
+  slot draws (``stats()["filtered_chunks"]``);
 - **token-level continuous batching** (Orca-style iteration-level
   scheduling): the decode batch is ``MXTPU_DECODE_SLOTS`` slots;
   requests JOIN an idle slot between chunks (prefill is its own
@@ -113,7 +115,8 @@ def decode_max_new() -> int:
 # on-device sampling
 # ---------------------------------------------------------------------------
 
-def sample_tokens(logits, key, temperature, top_k, top_p, greedy):
+def sample_tokens(logits, key, temperature, top_k, top_p, greedy,
+                  live=None):
     """Sample one token per row, entirely in-graph. ``logits`` is
     ``(B, V)``; every knob is a ``(B,)`` vector so each batch slot
     applies ITS OWN policy inside the shared executable:
@@ -126,25 +129,38 @@ def sample_tokens(logits, key, temperature, top_k, top_p, greedy):
       the argmax always survives, so filtering can never empty a row).
 
     Filters compose (top-k first, then top-p) by masking to ``-inf``
-    and drawing with ``jax.random.categorical``."""
+    and drawing with ``jax.random.categorical``.
+
+    The filter sorts the whole vocabulary, so it runs under a
+    device-side ``lax.cond`` only when some ``live`` row (bool ``(B,)``;
+    ``None`` = every row) is not greedy; otherwise the step takes the
+    argmax and nothing else. A dead row's policy is whatever its last
+    request left behind and never engages the filter."""
     import jax
     import jax.numpy as jnp
 
-    v = logits.shape[-1]
-    scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
-    sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
-    kk = jnp.where(top_k > 0, jnp.clip(top_k, 1, v), v)
-    kth = jnp.take_along_axis(sorted_desc, (kk - 1)[:, None], axis=-1)
-    limited = jnp.where(scaled < kth, -jnp.inf, scaled)
-    probs = jax.nn.softmax(sorted_desc, axis=-1)
-    mass_before = jnp.cumsum(probs, axis=-1) - probs
-    keep = mass_before < top_p[:, None]
-    thresh = jnp.min(jnp.where(keep, sorted_desc, jnp.inf), axis=-1,
-                     keepdims=True)
-    limited = jnp.where(scaled < thresh, -jnp.inf, limited)
-    drawn = jax.random.categorical(key, limited, axis=-1)
-    return jnp.where(greedy, jnp.argmax(logits, axis=-1),
-                     drawn).astype(jnp.int32)
+    def filter_and_draw():
+        v = logits.shape[-1]
+        scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
+        sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
+        kk = jnp.where(top_k > 0, jnp.clip(top_k, 1, v), v)
+        kth = jnp.take_along_axis(sorted_desc, (kk - 1)[:, None], axis=-1)
+        limited = jnp.where(scaled < kth, -jnp.inf, scaled)
+        probs = jax.nn.softmax(sorted_desc, axis=-1)
+        mass_before = jnp.cumsum(probs, axis=-1) - probs
+        keep = mass_before < top_p[:, None]
+        thresh = jnp.min(jnp.where(keep, sorted_desc, jnp.inf), axis=-1,
+                         keepdims=True)
+        limited = jnp.where(scaled < thresh, -jnp.inf, limited)
+        drawn = jax.random.categorical(key, limited, axis=-1)
+        return jnp.where(greedy, jnp.argmax(logits, axis=-1),
+                         drawn).astype(jnp.int32)
+
+    def argmax_only():
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    draws = ~greedy if live is None else live & ~greedy
+    return jax.lax.cond(jnp.any(draws), filter_and_draw, argmax_only)
 
 
 # ---------------------------------------------------------------------------
@@ -279,7 +295,7 @@ def generation_programs(net, chunk):
                                   active)
             rng, sub = jax.random.split(rng)
             nxt = sample_tokens(logits, sub, temp, top_k, top_p,
-                                greedy)
+                                greedy, active)
             emitted = active
             nxt = jnp.where(emitted, nxt, 0)
             lens = lens + active.astype(lens.dtype)
@@ -383,6 +399,7 @@ class GenerationEngine:
         self._itl = collections.deque(maxlen=8192)
         self._tokens = 0
         self._chunks = 0
+        self._filtered_chunks = 0  # chunks with a live slot that draws
         self._prefills = 0
         self._requests_ok = 0
         self._refused = 0
@@ -786,6 +803,9 @@ class GenerationEngine:
                 index = self._grow_sequences()
                 if index is None:
                     return
+                # the sampler's own predicate, before the chunk
+                if (self._active & ~self._greedy).any():
+                    self._filtered_chunks += 1
                 t0 = time.perf_counter()  # dt: staging, the call and the syncs
                 operands = self._chunk_operands(index)
             if sp is not _obs.NO_SPAN:
@@ -975,6 +995,7 @@ class GenerationEngine:
             "tokens_generated": self._tokens,
             "prefills": self._prefills,
             "decode_chunks": self._chunks,
+            "filtered_chunks": self._filtered_chunks,
             "dispatches": dispatches,
             "tokens_per_dispatch": self._tokens / max(1, dispatches),
             "tokens_per_s": (self._tokens / self._decode_wall

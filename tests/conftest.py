@@ -42,6 +42,56 @@ def natsorted_items(items):
     return sorted(items, key=lambda kv: natsort_key(kv[0]))
 
 
+def sorts_by_conditional(hlo_text):
+    """``(outside, inside)``: the ``sort`` instructions of a compiled
+    module's HLO text that run whenever the program does, and those
+    reached only through a ``conditional``'s branch computation. Walks
+    the computations from ENTRY along every callee edge (``body=``,
+    ``calls=``, ``to_apply=`` ...) but a conditional's branches."""
+    import re
+
+    comps, entry, name = {}, None, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"(ENTRY\s+)?%?([\w.\-]+)\s+\(.*\)\s+->.*\{\s*$", line)
+        if head:
+            name = head.group(2)
+            comps[name] = []
+            if head.group(1):
+                entry = name
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(re.sub(r'"[^"]*"', '""', line))
+    assert entry is not None, "no ENTRY computation in the HLO text"
+
+    def callees(line):
+        branches = set()
+        for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
+            branches.update(re.findall(r"[\w.\-]+", group))
+        branches.update(re.findall(
+            r"(?:true|false)_computation=%?([\w.\-]+)", line))
+        rest = set(re.findall(
+            r"(?:to_apply|calls|body|condition)=%?([\w.\-]+)", line))
+        for group in re.findall(r"called_computations=\{([^}]*)\}", line):
+            rest.update(re.findall(r"[\w.\-]+", group))
+        return rest - branches, branches
+
+    always, todo = set(), [entry]
+    while todo:
+        comp = todo.pop()
+        if comp in always or comp not in comps:
+            continue
+        always.add(comp)
+        for line in comps[comp]:
+            todo.extend(callees(line)[0])
+    outside, inside = [], []
+    for comp, lines in comps.items():
+        for line in lines:
+            if " sort(" in line:
+                (outside if comp in always else inside).append(line.strip())
+    return outside, inside
+
+
 def pytest_configure(config):
     # XLA:CPU has no buffer donation; the fused step donates anyway
     # (no-op) and jax warns once per compiled function — pure noise here

@@ -126,6 +126,17 @@ def test_paged_decode_compiles_for_v5e(on_chip, B, H, KVH, D, bs, L, nb, mb):
         q, pool, pool, tables, lens, names=["mxtpu_paged_decode"])
 
 
+def _gpt2_net(on_chip, layers, vocab):
+    """GPT-2-small's widths; the parameters as shapes on the chip."""
+    from mxnet_tpu.serving import TransformerDecoderLM
+
+    net = TransformerDecoderLM(vocab_size=vocab, num_layers=layers,
+                               d_model=768, num_heads=12, max_seq=1024,
+                               dtype="bfloat16")
+    return net, jax.tree_util.tree_map(
+        lambda a: on_chip(a.shape, a.dtype), net.params())
+
+
 @pytest.mark.parametrize("which", ["decode_step_fn", "prefill_fn"])
 def test_decoder_works_on_the_pool_in_place_on_v5e(on_chip, monkeypatch,
                                                    which):
@@ -136,15 +147,9 @@ def test_decoder_works_on_the_pool_in_place_on_v5e(on_chip, monkeypatch,
     heads on an axis of their own the device kept the pool in another
     axis order and every executable transposed it on the way in and on
     the way out: temporaries of five pools."""
-    from mxnet_tpu.serving import TransformerDecoderLM
-
     monkeypatch.setattr(fa, "_use_pallas", lambda d: True)  # no TPU here
     layers, slots, mb, bucket = 2, 128, 64, 64
-    net = TransformerDecoderLM(vocab_size=512, num_layers=layers,
-                               d_model=768, num_heads=12, max_seq=1024,
-                               dtype="bfloat16")
-    params = jax.tree_util.tree_map(
-        lambda a: on_chip(a.shape, a.dtype), net.params())
+    net, params = _gpt2_net(on_chip, layers, vocab=512)
     pool = on_chip((layers, 3073, 16, 768), jnp.bfloat16)
     if which == "decode_step_fn":
         args = (params, on_chip((slots,), jnp.int32),
@@ -190,27 +195,20 @@ def test_retention_decode_compiles_for_v5e(on_chip, B, H, KVH, D, L):
     assert exe.memory_analysis().temp_size_in_bytes < D * 65 * D * 4
 
 
-@pytest.mark.parametrize("which", ["decode_step_fn", "prefill_fn"])
-def test_retention_decoder_works_on_the_states_in_place_on_v5e(
-        on_chip, monkeypatch, which):
-    """The Brumby layer at its published widths (two layers under the
-    scan, a small vocabulary), as the generation engine compiles it:
-    with the states donated the executable's temporaries stay a small
-    share of the state array, and the decode step's one kernel is the
-    retention kernel."""
+def _brumby_net(on_chip, layers, vocab):
+    """Brumby-14B's layer at its published widths, ``layers`` of them
+    under the scan; 660 MB a layer: shapes only, held as the benchmark's
+    glue holds its weights."""
     import json
 
     from chipbench.models import retention_lm as glue
-    from mxnet_tpu.ops import retention as R
 
-    monkeypatch.setattr(R, "_use_pallas", lambda d: True)  # no TPU here
-    layers, slots, bucket = 2, 16, 512
     with open(os.path.join(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))), "chipbench", "configs",
             "brumby_14b.json")) as f:
         cfg = {**json.load(f), "num_hidden_layers": layers,
-               "vocab_size": 512}
-    L, d, ff, hd, V = layers, 5120, 17408, 128, 512
+               "vocab_size": vocab}
+    L, d, ff, hd, V = layers, 5120, 17408, 128, vocab
     assert (d, ff, hd) == (cfg["hidden_size"], cfg["intermediate_size"],
                            cfg["head_dim"])
     leaves = {"ln1_g": (L, d), "ln2_g": (L, d), "wq": (L, d, 40 * hd),
@@ -219,15 +217,28 @@ def test_retention_decoder_works_on_the_states_in_place_on_v5e(
               "wg": (L, d, 8), "bg": (L, 8), "w_gate": (L, d, ff),
               "w_up": (L, d, ff), "w_down": (L, ff, d)}
     assert set(glue.LAYER_LEAVES) == set(leaves)
-    # 660 MB a layer: shapes only, held as the benchmark's glue holds
-    # its weights
     net = glue.build_net(cfg, {
         "embed": on_chip((V, d), jnp.bfloat16),
         "head": on_chip((d, V), jnp.bfloat16),
         "lnf_g": on_chip((d,), jnp.bfloat16),
         **{k: on_chip(shape, jnp.bfloat16) for k, shape in leaves.items()},
     }, "bfloat16")
-    params = net.params()
+    return net, net.params()
+
+
+@pytest.mark.parametrize("which", ["decode_step_fn", "prefill_fn"])
+def test_retention_decoder_works_on_the_states_in_place_on_v5e(
+        on_chip, monkeypatch, which):
+    """The Brumby layer at its published widths (two layers under the
+    scan, a small vocabulary), as the generation engine compiles it:
+    with the states donated the executable's temporaries stay a small
+    share of the state array, and the decode step's one kernel is the
+    retention kernel."""
+    from mxnet_tpu.ops import retention as R
+
+    monkeypatch.setattr(R, "_use_pallas", lambda d: True)  # no TPU here
+    layers, slots, bucket, hd = 2, 16, 512, 128
+    net, params = _brumby_net(on_chip, layers, vocab=512)
     s_shape, z_shape = R.state_shapes(layers, slots, 8, hd)
     state = (on_chip(s_shape, jnp.float32), on_chip(z_shape, jnp.float32))
     if which == "decode_step_fn":
@@ -247,3 +258,65 @@ def test_retention_decoder_works_on_the_states_in_place_on_v5e(
         < share * 4 * math.prod(s_shape)
     assert exe.as_text().count(
         'custom_call_target="tpu_custom_call"') == kernels
+
+
+# the serve cells' engines: vocabulary, slots, a prefill bucket (the
+# compiler takes 20 s over a sort of such a vocabulary, so prefill, whose
+# sampler is the same call on one row, sorts a small one)
+_ENGINES = {
+    "gpt2_small": (50257, 128, 64),
+    "brumby_14b": (151936, 16, 512),
+}
+
+
+@pytest.mark.parametrize("which", ["chunk_fn", "prefill_fn"])
+@pytest.mark.parametrize("family", sorted(_ENGINES))
+def test_engine_sorts_the_vocabulary_only_under_a_conditional_on_v5e(
+        on_chip, monkeypatch, family, which):
+    """The two programs ``GenerationEngine`` compiles, at the serve
+    cells' slots and, the chunk, their vocabularies (two layers): the
+    sampler's sort of the vocabulary is in the compiled program, and
+    only inside a conditional's branch, so a step whose live slots are
+    all greedy does not run it."""
+    from conftest import sorts_by_conditional
+    from mxnet_tpu.ops import retention as R
+    from mxnet_tpu.serving import generation
+
+    monkeypatch.setattr(fa, "_use_pallas", lambda d: True)  # no TPU here
+    monkeypatch.setattr(R, "_use_pallas", lambda d: True)
+    vocab, slots, bucket = _ENGINES[family]
+    layers, vocab = 2, vocab if which == "chunk_fn" else 512
+    if family == "gpt2_small":
+        net, params = _gpt2_net(on_chip, layers, vocab)
+        pool = on_chip((layers, 3073, 16, 768), jnp.bfloat16)
+        cache, per_row = (pool, pool), (64,)  # a block table a row
+    else:
+        net, params = _brumby_net(on_chip, layers, vocab)
+        cache = tuple(on_chip(shape, jnp.float32)
+                      for shape in R.state_shapes(layers, slots, 8, 128))
+        per_row = ()  # a state's slot a row
+    chunk_fn, prefill_fn = generation.generation_programs(net, 8)
+
+    def vec(rows, dtype):
+        return on_chip((rows,), dtype)
+
+    def index(rows):
+        return (on_chip((rows,) + per_row, jnp.int32),)
+
+    if which == "chunk_fn":
+        n = slots
+        args = (params, cache, index(n), vec(n, jnp.int32),
+                vec(n, jnp.int32), vec(n, bool), vec(n, jnp.int32),
+                on_chip((2,), jnp.uint32), vec(n, jnp.float32),
+                vec(n, jnp.int32), vec(n, jnp.float32), vec(n, bool),
+                vec(n, jnp.int32))
+        exe = jax.jit(chunk_fn, donate_argnums=(1,)).lower(*args).compile()
+    else:
+        args = (params, on_chip((1, bucket), jnp.int32), cache, index(1),
+                vec(1, jnp.int32), vec(1, jnp.int32), vec(1, jnp.float32),
+                vec(1, jnp.int32), vec(1, jnp.float32), vec(1, bool))
+        exe = jax.jit(prefill_fn, donate_argnums=(2,)).lower(*args).compile()
+    outside, inside = sorts_by_conditional(exe.as_text())
+    # (the retention step sorts its 16 slots by liveness, always)
+    assert [line for line in outside if f"{vocab}]" in line] == []
+    assert any(f"{vocab}]" in line for line in inside), inside

@@ -228,6 +228,172 @@ def test_sample_tokens_policies():
     assert np.all(draw() >= 0) and np.all(draw() < 16)
 
 
+def _sample_tokens_unconditional(logits, key, temperature, top_k, top_p,
+                                 greedy, live=None):
+    """The sampler as it was before it branched: every row filtered and
+    drawn, the greedy rows' draws then thrown away. The plain reference
+    for what a row gets (``live`` is taken, so that it can stand in for
+    the module's, and ignored)."""
+    import jax
+    import jax.numpy as jnp
+
+    v = logits.shape[-1]
+    scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
+    sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
+    kk = jnp.where(top_k > 0, jnp.clip(top_k, 1, v), v)
+    kth = jnp.take_along_axis(sorted_desc, (kk - 1)[:, None], axis=-1)
+    limited = jnp.where(scaled < kth, -jnp.inf, scaled)
+    probs = jax.nn.softmax(sorted_desc, axis=-1)
+    mass_before = jnp.cumsum(probs, axis=-1) - probs
+    keep = mass_before < top_p[:, None]
+    thresh = jnp.min(jnp.where(keep, sorted_desc, jnp.inf), axis=-1,
+                     keepdims=True)
+    limited = jnp.where(scaled < thresh, -jnp.inf, limited)
+    drawn = jax.random.categorical(key, limited, axis=-1)
+    return jnp.where(greedy, jnp.argmax(logits, axis=-1),
+                     drawn).astype(jnp.int32)
+
+
+def _sampler_operands(greedy):
+    """Six rows over a vocabulary of 40, a policy of its own each."""
+    import jax.numpy as jnp
+
+    rs = np.random.RandomState(7)
+    return (jnp.asarray(rs.randn(6, 40), jnp.float32),
+            jnp.asarray(rs.uniform(0.5, 3.0, 6), jnp.float32),
+            jnp.asarray(rs.choice([0, 3, 12], 6), jnp.int32),
+            jnp.asarray(rs.choice([1.0, 0.9, 0.5], 6), jnp.float32),
+            jnp.asarray(greedy, bool))
+
+
+@pytest.mark.parametrize("live", [None, [True, True, False, True, True,
+                                         False]],
+                         ids=["every_row_live", "two_rows_dead"])
+@pytest.mark.parametrize("greedy", [[True] * 6,
+                                    [True, False, True, True, False, True]],
+                         ids=["all_greedy", "mixed"])
+def test_sample_tokens_returns_what_the_unconditional_sampler_did(
+        greedy, live):
+    """All greedy (the argmax branch) or mixed with a live sampled row
+    (the filter's branch): every row gets the id the unconditional
+    sampler gave it under the same key, the dead rows too."""
+    import jax
+
+    logits, temp, top_k, top_p, greedy = _sampler_operands(greedy)
+    live = None if live is None else np.asarray(live)
+    for seed in range(4):
+        key = jax.random.PRNGKey(seed)
+        got = sample_tokens(logits, key, temp, top_k, top_p, greedy, live)
+        want = _sample_tokens_unconditional(logits, key, temp, top_k,
+                                            top_p, greedy)
+        assert got.dtype == want.dtype == np.int32
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_a_dead_sampled_row_does_not_engage_the_filter():
+    """The only rows that ask for a draw are dead (a slot keeps its last
+    request's policy): the live rows get the argmax, as from the
+    unconditional sampler, and so do the dead ones, because the filter
+    never ran. In the compiled sampler the vocabulary's sort lies in a
+    conditional's branch and nowhere else."""
+    import jax
+    from conftest import sorts_by_conditional
+
+    logits, temp, top_k, top_p, greedy = _sampler_operands(
+        [True, True, False, True, True, False])
+    live = np.asarray(greedy)  # exactly the rows that draw are dead
+    key = jax.random.PRNGKey(11)
+    got = np.asarray(sample_tokens(logits, key, temp, top_k, top_p, greedy,
+                                   live))
+    want = np.asarray(_sample_tokens_unconditional(logits, key, temp, top_k,
+                                                   top_p, greedy))
+    assert np.array_equal(got[live], want[live])
+    assert np.array_equal(got, np.asarray(logits).argmax(-1))
+    assert not np.array_equal(got, want)  # the dead rows' draws are gone
+    hlo = jax.jit(sample_tokens).lower(
+        logits, key, temp, top_k, top_p, greedy, live).compile().as_text()
+    outside, inside = sorts_by_conditional(hlo)
+    assert outside == [] and len(inside) >= 1
+    hlo = jax.jit(_sample_tokens_unconditional).lower(
+        logits, key, temp, top_k, top_p, greedy).compile().as_text()
+    assert len(sorts_by_conditional(hlo)[0]) >= 1  # the check can fail
+
+
+_SAMPLED = dict(max_new_tokens=11, greedy=False, temperature=1.3, top_k=9,
+                top_p=0.9, seed=77)
+
+
+@pytest.mark.parametrize("company", ["alone", "among_greedy"])
+def test_sampled_request_gets_the_unconditional_samplers_tokens(
+        net, monkeypatch, company):
+    """A greedy burst first (chunks of the argmax branch), then a
+    sampled request, alone or sharing its chunks with greedy ones: token
+    for token what an engine built on the unconditional sampler serves,
+    because the key is split once a step whichever branch runs. The
+    counter reads the chunks that held a live sampled slot."""
+    from mxnet_tpu.serving import generation
+
+    def run():
+        e = GenerationEngine(net, BUCKETS, slots=SLOTS, chunk=CHUNK,
+                             cache_blocks=96, cache_block_size=4, seed=5,
+                             name=f"gen-{company}")
+        try:
+            burst = [e.predict(np.array([3, 1, 4], np.int32),
+                               max_new_tokens=9, greedy=True, timeout=60.0)]
+            before = e.stats()
+            assert before["filtered_chunks"] == 0
+            futs = [e.submit(np.array([7, 2, 9, 11], np.int32), **_SAMPLED)]
+            if company == "among_greedy":
+                # admitted behind it, in the same turn of the scheduler or
+                # the next: they never run a chunk before it does
+                futs += [e.submit(np.array([5, 3, i], np.int32),
+                                  max_new_tokens=14, greedy=True)
+                         for i in range(2)]
+            outs = [f.result(120.0) for f in futs]
+            after = e.stats()
+            return burst + outs, before, after
+        finally:
+            e.close()
+
+    got, before, after = run()
+    chunks = after["decode_chunks"] - before["decode_chunks"]
+    filtered = after["filtered_chunks"] - before["filtered_chunks"]
+    # 10 tokens after the prefill's: ceil(10 / 4) chunks hold the slot
+    assert filtered == -(-(_SAMPLED["max_new_tokens"] - 1) // CHUNK)
+    assert filtered == chunks if company == "alone" else filtered < chunks
+    monkeypatch.setattr(generation, "sample_tokens",
+                        _sample_tokens_unconditional)
+    want, _, _ = run()
+    for g, w in zip(got, want):
+        assert list(g) == list(w)
+    assert len(got[1]) == _SAMPLED["max_new_tokens"]
+
+
+def test_a_finished_sampled_request_leaves_no_filter_behind(eng, net):
+    """Nothing resets a slot's policy when its request leaves: after a
+    sampled request has run in a slot, an all-greedy burst over every
+    slot counts no filtered chunk, and its tokens are the dense
+    recompute's."""
+    st0 = eng.stats()
+    toks = eng.predict(np.array([9, 8, 7], np.int32), timeout=60.0,
+                       **_SAMPLED)
+    assert len(toks) == _SAMPLED["max_new_tokens"]
+    _drain(eng)
+    st1 = eng.stats()
+    assert st1["filtered_chunks"] - st0["filtered_chunks"] \
+        == st1["decode_chunks"] - st0["decode_chunks"] > 0
+    assert not eng._greedy.all()  # the stale policy is still there
+    prompts = [[3, 1, 4], [2, 7], [1, 8, 2, 8], [6, 6, 6], [4, 4], [9, 1]]
+    futs = [eng.submit(np.array(p, np.int32), max_new_tokens=10,
+                       greedy=True) for p in prompts]
+    outs = [f.result(120.0) for f in futs]
+    st2 = eng.stats()
+    assert st2["decode_chunks"] > st1["decode_chunks"]
+    assert st2["filtered_chunks"] == st1["filtered_chunks"]
+    for p, o in zip(prompts, outs):
+        _assert_matches_dense(net, p, o)
+
+
 # ---------------------------------------------------------------------------
 # sealed-engine + dispatch-budget contracts
 # ---------------------------------------------------------------------------
