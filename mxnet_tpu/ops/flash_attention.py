@@ -703,9 +703,8 @@ def _paged_group_blocks(block_size, width, itemsize, max_blocks):
     return g
 
 
-def _paged_decode_kernel(layer_ref, tables_ref, lens_ref, q_ref, seg_ref,
-                         k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, a_scr,
-                         m_scr, l_scr, acc_scr, *, scale, bs, gb):
+def _paged_decode_kernel(layer_ref, tables_ref, lens_ref, *refs, scale, bs,
+                         gb, latent=False):
     """One invocation walks the LIVE blocks of every sequence, ``gb`` of
     them a compute step. The pools stay in HBM; block ``j`` of sequence
     ``b`` is copied from ``(layer, tables[b, j])`` into rows ``(j % gb)
@@ -723,8 +722,24 @@ def _paged_decode_kernel(layer_ref, tables_ref, lens_ref, q_ref, seg_ref,
     each head's weighted sum in the lanes of its kv head; what the
     other lanes hold is never read. Online softmax in float32 over the
     groups, exactly the prefill kernel's recurrence with the heads as
-    its rows."""
-    B, group, _ = q_ref.shape
+    its rows.
+
+    ``latent``: ONE pool whose row is a token's latent, shared by every
+    query head (:func:`latent_decode_attention`). The queries are their
+    own diagonal (``q_ref`` is ``(B, H, width)``, no pattern), one copy
+    a block brings the tile that serves both products (the scores over
+    all of a row's lanes, the weighted sum over the same rows: the
+    caller keeps the lanes that are values), and ``o_ref`` takes all
+    heads' rows whole."""
+    if latent:
+        q_ref, k_hbm, o_ref, k_buf, sems, a_scr, m_scr, l_scr, acc_scr = refs
+        seg_ref, v_buf, pools = None, k_buf, ((k_hbm, k_buf),)
+        B, group = q_ref.shape[0], 1
+    else:
+        (q_ref, seg_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, a_scr,
+         m_scr, l_scr, acc_scr) = refs
+        pools = ((k_hbm, k_buf), (v_hbm, v_buf))
+        B, group, _ = q_ref.shape
     mb = tables_ref.shape[1]
     rows = gb * bs
     layer = layer_ref[0]
@@ -743,8 +758,7 @@ def _paged_decode_kernel(layer_ref, tables_ref, lens_ref, q_ref, seg_ref,
             def _(i=i, blk=blk):
                 src = tables_ref[b, blk]
                 dst = pl.ds(i * bs, bs)
-                for s, (pool, buf) in enumerate(((k_hbm, k_buf),
-                                                 (v_hbm, v_buf))):
+                for s, (pool, buf) in enumerate(pools):
                     copy = pltpu.make_async_copy(
                         pool.at[layer, src], buf.at[slot, dst],
                         sems.at[s, slot])
@@ -762,8 +776,8 @@ def _paged_decode_kernel(layer_ref, tables_ref, lens_ref, q_ref, seg_ref,
 
     # rows of a buffer past a context keep what an earlier group left
     # there and weigh 0: they only have to be finite
-    k_buf[...] = jnp.zeros_like(k_buf)
-    v_buf[...] = jnp.zeros_like(v_buf)
+    for _, buf in pools:
+        buf[...] = jnp.zeros_like(buf)
     o_ref[...] = jnp.zeros_like(o_ref)
 
     first = next_live(-1)
@@ -778,11 +792,14 @@ def _paged_decode_kernel(layer_ref, tables_ref, lens_ref, q_ref, seg_ref,
 
         @pl.when(groups > 0)
         def _init():
-            a = jnp.zeros(a_scr.shape, jnp.float32)
-            for g in range(group):
-                a = a + (q_ref[b, g:g + 1, :].astype(jnp.float32)
-                         * seg_ref[g].astype(jnp.float32))
-            a_scr[...] = a.astype(a_scr.dtype)
+            if latent:
+                a_scr[...] = q_ref[b]
+            else:
+                a = jnp.zeros(a_scr.shape, jnp.float32)
+                for g in range(group):
+                    a = a + (q_ref[b, g:g + 1, :].astype(jnp.float32)
+                             * seg_ref[g].astype(jnp.float32))
+                a_scr[...] = a.astype(a_scr.dtype)
             m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
             l_scr[...] = jnp.zeros_like(l_scr)
             acc_scr[...] = jnp.zeros_like(acc_scr)
@@ -819,6 +836,9 @@ def _paged_decode_kernel(layer_ref, tables_ref, lens_ref, q_ref, seg_ref,
         @pl.when(groups > 0)
         def _finish():
             o = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+            if latent:
+                o_ref[b] = o.astype(o_ref.dtype)
+                return
             for g in range(group):
                 o_ref[b, g:g + 1, :] = jnp.sum(
                     jnp.where(seg_ref[g] != 0, o, 0.0), axis=0,
@@ -990,6 +1010,120 @@ def paged_decode_attention(query, k_pool, v_pool, block_tables,
     decode = _pallas_paged_decode if _use_pallas(D) else _jnp_paged_decode
     return decode(query, k_pool, v_pool, tables, lens, float(scale),
                   layer=int(layer))
+
+
+# ---------------------------------------------------------------------------
+# latent decode attention: the same walk over ONE pool whose row is a
+# token's compressed key-value latent, shared by every query head
+# ---------------------------------------------------------------------------
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _pallas_latent_decode(layer, tables, lens, q, pool, *, scale,
+                          interpret=False):
+    """:func:`_paged_decode_kernel` over one pool ``(layers, num_blocks,
+    block_size, width)`` with one "KV head" of ``width`` lanes: ``q``
+    is ``(B, H, width)``, each head against a token's whole row, and
+    the result ``(B, H, width)`` is the weights' sum over the same
+    rows. A group of blocks is one ``(gb * bs, width)`` tile in VMEM,
+    fetched once for both products."""
+    B, H, width = q.shape
+    _, _, bs, _ = pool.shape
+    gb = _paged_group_blocks(bs, width, pool.dtype.itemsize, tables.shape[1])
+    hp = -(-H // 16) * 16
+    qp = jnp.pad(q, ((0, 0), (0, hp - H), (0, 0)))
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+    vmem = 2 * gb * bs * width * pool.dtype.itemsize \
+        + 4 * qp.size * q.dtype.itemsize
+    out = pl.pallas_call(
+        functools.partial(_paged_decode_kernel, scale=scale, bs=bs, gb=gb,
+                          latent=True),
+        out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(1,),
+            in_specs=[whole, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=whole,
+            scratch_shapes=[
+                pltpu.VMEM((2, gb * bs, width), pool.dtype),
+                pltpu.SemaphoreType.DMA((1, 2)),
+                pltpu.VMEM((hp, width), q.dtype),
+                pltpu.VMEM((hp, 1), jnp.float32),
+                pltpu.VMEM((hp, 1), jnp.float32),
+                pltpu.VMEM((hp, width), jnp.float32),
+            ],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=int(vmem + (8 << 20))),
+        interpret=interpret,
+        name="mxtpu_latent_decode",
+    )(layer, tables, lens, qp, pool)
+    return out[:, :H]
+
+
+def _jnp_latent_decode(layer, tables, lens, q, pool, *, scale):
+    """CPU path + oracle: each slot's rows through the ``(layer,
+    table)`` gather, a masked softmax in float32, the weights' sum over
+    the rows themselves."""
+    B, mb = tables.shape
+    rows = pool[layer, tables].reshape(B, mb * pool.shape[2], -1)
+    rows = rows.astype(jnp.float32)
+    s = jnp.einsum("bhw,bsw->bhs", q.astype(jnp.float32), rows) * scale
+    mask = jnp.arange(rows.shape[1], dtype=jnp.int32)[None, :] < lens[:, None]
+    s = jnp.where(mask[:, None, :], s, _NEG_INF)
+    p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+    out = jnp.einsum("bhs,bsw->bhw", p, rows)
+    # an empty slot gives zeros, not a uniform mean of the null block
+    return jnp.where((lens > 0)[:, None, None], out, 0.0).astype(q.dtype)
+
+
+def latent_decode_attention(q_lat, q_rope, pool, block_tables, context_lens,
+                            scale, layer=0):
+    """Decode attention in the absorbed form of latent attention (MLA):
+    a token keeps ONE row for all heads, ``[c_kv | k_rope]`` (``rank +
+    rope`` numbers: the compressed key-value latent after its norm and
+    the shared rotary key after its rotation), in the paged pool
+    ``(layers, num_blocks, block_size, lanes)``: ``lanes`` is ``rank +
+    rope`` in whole lane tiles of 128 (576 -> 640, which is what the
+    device's tiling makes of a 576-wide row whatever the array says; the
+    kernel's copies take whole tiles), zeros past the row. ``q_lat``
+    ``(B, H, rank)`` is each head's no-position query carried into the
+    latent's space (``q_nope W_uk^T``), ``q_rope`` ``(B, H, rope)`` its
+    rotated part. Head ``h``'s score against a token is ``([q_lat |
+    q_rope]_h . row) * scale``; the result ``(B, H, rank)`` is the
+    softmax-weighted sum of the rows' latent parts, which the caller
+    expands a head through ``W_uv``. ``layer`` may be traced (a scanned
+    stack of layers): it rides the scalar-prefetch lane into the source
+    of the kernel's copies, so the pool is read where it lies.
+
+    TPU path: :func:`paged_decode_attention`'s kernel body with one
+    pool (``mxtpu_latent_decode``): live blocks only, 8 blocks a
+    double-buffered copy, one ``(128, lanes)`` tile a group that
+    serves the scores (all its lanes against the 32 heads' rows) and the
+    weighted sum (the same tile; the rotary lanes of the result are
+    dropped here). CPU/debug path: a plain gather (the oracle).
+    Sequences with ``context_lens == 0`` return zeros."""
+    rank = q_lat.shape[-1]
+    pad = pool.shape[-1] - rank - q_rope.shape[-1]
+    if pool.ndim != 4 or pad < 0:
+        raise ValueError(
+            "the latent pool is (layers, num_blocks, block_size, lanes) "
+            f"with lanes >= rank + rope = {rank} + {q_rope.shape[-1]}; got "
+            f"{pool.shape}")
+    # lanes past rank + rope (the pool's row in whole lane tiles) hold
+    # zeros on both sides and add nothing to a score
+    q = jnp.concatenate(
+        [q_lat, q_rope, jnp.zeros(q_lat.shape[:-1] + (pad,), q_lat.dtype)],
+        axis=-1).astype(pool.dtype)
+    # (any row width: the kernel's choice is the backend's alone)
+    decode = _pallas_latent_decode if _use_pallas(0) else _jnp_latent_decode
+    out = decode(jnp.asarray(layer, jnp.int32).reshape(1),
+                 block_tables.astype(jnp.int32),
+                 context_lens.astype(jnp.int32), q, pool,
+                 scale=float(scale))
+    return out[..., :rank].astype(q_lat.dtype)
 
 
 @register("flash_attention", aliases=("_contrib_flash_attention",))

@@ -6,6 +6,13 @@ classic mesh-tensorflow/GShard algorithm — top-1/top-2 gating with
 capacity, einsum dispatch/combine, experts sharded over an ``ep`` mesh
 axis inside ``shard_map`` so each device runs only its local experts.
 
+This is the TRAINING router: a capacity that drops the tokens over it,
+two-matrix ReLU experts, a softmax gate. Nothing in ``serving/`` calls
+it. A generation server's expert layer (a sigmoid router with a
+balancing bias, top-k with renormalisation, gated experts as grouped
+products over tokens sorted by expert, nothing dropped) is
+:mod:`mxnet_tpu.ops.experts`.
+
 Two dispatch paths:
 
 - :func:`moe_apply` — tokens replicated, the dispatch einsum reshards

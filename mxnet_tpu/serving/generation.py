@@ -279,20 +279,28 @@ def generation_programs(net, chunk):
     ``prefill_fn`` (one padded prompt and its first token). ``cache``
     is the cache's arrays as one pytree, donated and returned; ``index``
     the per-slot indices that go beside them (block tables, states'
-    slots), in the order the net's faces take them."""
+    slots), in the order the net's faces take them. For a net with
+    expert layers ``chunk_fn`` returns one thing more: the int32
+    ``[routed_pairs, experts_hit, load_max]`` summed over the chunk's
+    decode steps and the expert layers, of the live slots' tokens."""
     import jax
     import jax.numpy as jnp
 
-    step = net.decode_step_fn()
+    with_load = bool(getattr(net, "expert_layers", 0))
+    step = net.decode_step_fn(with_load=True) if with_load \
+        else net.decode_step_fn()
     prefill = net.prefill_fn()
     chunk_t = int(chunk)
 
     def chunk_fn(params, cache, index, lens, token, active,
                  remaining, rng, temp, top_k, top_p, greedy, eos):
         def body(carry, _):
-            cache, lens, token, active, remaining, rng = carry
+            cache, lens, token, active, remaining, rng, *load = carry
             logits, *cache = step(params, token, lens, *cache, *index,
                                   active)
+            if with_load:
+                *cache, more = cache
+                load = [load[0] + more]
             rng, sub = jax.random.split(rng)
             nxt = sample_tokens(logits, sub, temp, top_k, top_p,
                                 greedy, active)
@@ -302,14 +310,16 @@ def generation_programs(net, chunk):
             remaining = remaining - active.astype(remaining.dtype)
             hit_eos = (nxt == eos) & (eos >= 0)
             active = active & ~hit_eos & (remaining > 0)
-            return ((tuple(cache), lens, nxt, active, remaining, rng),
+            return ((tuple(cache), lens, nxt, active, remaining, rng, *load),
                     (nxt, emitted))
 
-        carry = (tuple(cache), lens, token, active, remaining, rng)
+        carry = (tuple(cache), lens, token, active, remaining, rng) \
+            + ((jnp.zeros(3, jnp.int32),) if with_load else ())
         carry, (toks, flags) = jax.lax.scan(body, carry, None,
                                             length=chunk_t)
-        cache, lens, token, active, remaining, rng = carry
-        return (cache, lens, token, active, remaining, rng, toks, flags)
+        cache, lens, token, active, remaining, rng, *load = carry
+        return (cache, lens, token, active, remaining, rng, toks, flags,
+                *load)
 
     def prefill_fn(params, tokens, cache, index, length,
                    seed_v, temp, top_k, top_p, greedy):
@@ -400,6 +410,10 @@ class GenerationEngine:
         self._tokens = 0
         self._chunks = 0
         self._filtered_chunks = 0  # chunks with a live slot that draws
+        # a net with expert layers: [routed_pairs, experts_hit, load_max]
+        # summed over every decode step and expert layer so far
+        self._expert_load = _np.zeros(3, _np.int64) \
+            if getattr(net, "expert_layers", 0) else None
         self._prefills = 0
         self._requests_ok = 0
         self._refused = 0
@@ -858,10 +872,12 @@ class GenerationEngine:
     def _run_chunk(self, operands):
         """The chunk's executable, to the last byte the scheduler needs
         of it: ``(tokens, emitted flags)``, each ``(chunk, slots)``."""
-        (arrays, lens, token, active, remaining, rng, toks, flags) = \
+        (arrays, lens, token, active, remaining, rng, toks, flags, *load) = \
             self._chunk_exe(self._params, *operands)
         self.cache.adopt(arrays)
         self._rng = rng
+        if load:
+            self._expert_load += _np.asarray(load[0])  # mxtpu-lint: host-sync-ok
         # ONE host sync per chunk: everything the scheduler needs
         # (np.array copies — jax device views are read-only and the
         # slot mirrors are mutated at admission)
@@ -1011,6 +1027,9 @@ class GenerationEngine:
             "retraces_after_warmup": 0 if self._sealed else None,
             "recompiles_after_warmup": 0 if self._sealed else None,
             "cache": self.cache.stats(),
+            **({} if self._expert_load is None else {"experts": dict(zip(
+                ("routed_pairs", "experts_hit", "load_max"),
+                map(int, self._expert_load)))}),
         }
 
     def canary(self):

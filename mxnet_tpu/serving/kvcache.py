@@ -43,11 +43,17 @@ copy has come back). Everything device-side (gather/scatter through the
 table) lives in the pure helpers at the bottom so the decode model and
 the tests target the same code.
 
-A net whose layers keep a fixed recurrent state a sequence (power
-retention) gets a second kind of cache beside the pool, or in its
-place: :class:`StateStore`, one state a decode slot, no table, nothing
-that grows. :class:`SequenceCache` is the one manager the generation
-engine talks to; the net's ``cache_spec()`` says which parts it has.
+Three kinds of layer keep something for a live sequence
+(:data:`CACHE_ARRAYS`). ``attention`` layers keep K and V rows: two
+pools of ``kv_heads * head_dim`` lanes. ``latent`` layers (latent
+attention) keep ONE row a token for all heads, the compressed key-value
+latent and the shared rotary key: one pool of that width, in whole lane
+tiles; the same :class:`PagedKVCache`, which is a tuple of pools of a
+stated row width over one allocator. ``retention`` layers (power
+retention) keep a fixed recurrent state a sequence: :class:`StateStore`,
+one state a decode slot, no table, nothing that grows.
+:class:`SequenceCache` is the one manager the generation engine talks
+to; the net's ``cache_spec()`` says which parts it has.
 
 Knobs: ``MXTPU_KVCACHE_BLOCKS`` (pool size), ``MXTPU_KVCACHE_BLOCK_SIZE``
 (tokens per block). Gauges: ``mxtpu_kvcache_blocks_used`` /
@@ -64,6 +70,12 @@ import numpy as np
 from .. import base
 from .. import observability as _obs
 from .errors import KVCacheOOM
+
+# device arrays a layer kind keeps for its sequences, in the order the
+# net's faces take the kinds: K and V pools; a state and its
+# normaliser; one pool of latent rows
+CACHE_ARRAYS = {"attention": 2, "retention": 2, "latent": 1}
+_LANES = 128  # the TPU tiles an array's minor axis in lanes of 128
 
 
 def kvcache_blocks() -> int:
@@ -116,7 +128,10 @@ class PagedKVCache:
     >>> child = cache.fork(t)           # refcount bump, no copy
     >>> cache.ensure(child, 18)         # COW copies ONE shared block
     >>> cache.release(t); cache.release(child)
-    """
+
+    ``arrays`` pools of ``width`` lanes a token's row: K and V of
+    ``kv_heads * head_dim`` by default; ``width=640, arrays=1`` is the
+    latent kind's one pool."""
 
     # machine-checked lock protocol (mxtpu-lint thread-guard rule)
     _GUARDED_BY = {
@@ -124,14 +139,16 @@ class PagedKVCache:
         "_ref": "_lock",
     }
 
-    def __init__(self, layers, kv_heads, head_dim, *, max_seq=None,
-                 num_blocks=None, block_size=None, dtype="float32",
-                 name="model"):
+    def __init__(self, layers, kv_heads=None, head_dim=None, *, width=None,
+                 arrays=2, max_seq=None, num_blocks=None, block_size=None,
+                 dtype="float32", name="model"):
         import jax.numpy as jnp
 
         self.layers = int(layers)
-        self.kv_heads = int(kv_heads)
-        self.head_dim = int(head_dim)
+        if width is None:
+            self.kv_heads, self.head_dim = int(kv_heads), int(head_dim)
+            width = self.kv_heads * self.head_dim
+        self.width = int(width)
         self.block_size = int(block_size or kvcache_block_size())
         self.num_blocks = int(num_blocks or kvcache_blocks())
         if self.num_blocks < 2:
@@ -142,10 +159,9 @@ class PagedKVCache:
         self.max_blocks_per_seq = (
             -(-int(max_seq) // self.block_size) if max_seq
             else self.num_blocks - 1)
-        shape = (self.layers, self.num_blocks, self.block_size,
-                 self.kv_heads * self.head_dim)
-        self.k_pool = jnp.zeros(shape, dtype=self._dtype)
-        self.v_pool = jnp.zeros(shape, dtype=self._dtype)
+        shape = (self.layers, self.num_blocks, self.block_size, self.width)
+        self._pools = tuple(jnp.zeros(shape, dtype=self._dtype)
+                            for _ in range(int(arrays)))
         self._lock = threading.Lock()
         self._free = list(range(self.num_blocks - 1, 0, -1))  # pop() -> 1
         self._ref = np.zeros((self.num_blocks,), dtype=np.int64)
@@ -155,14 +171,30 @@ class PagedKVCache:
 
     # -- pool threading ----------------------------------------------------
     def pools(self):
-        """Current ``(k_pool, v_pool)`` device arrays — the operands to
-        hand the next prefill/decode dispatch (which donates them)."""
-        return self.k_pool, self.v_pool
+        """The current device arrays, ``(k_pool, v_pool)`` or the one
+        latent pool — the operands to hand the next prefill/decode
+        dispatch (which donates them)."""
+        return self._pools
 
-    def update_pools(self, k_pool, v_pool):
+    def update_pools(self, *pools):
         """Adopt the pool arrays a dispatch returned (the donated
         inputs are dead after the call — this is the hand-over)."""
-        self.k_pool, self.v_pool = k_pool, v_pool
+        if len(pools) != len(self._pools):
+            raise ValueError(f"this cache holds {len(self._pools)} pool(s); "
+                             f"got {len(pools)}")
+        self._pools = tuple(pools)
+
+    # the names a SequenceCache's parts share
+    arrays, adopt = pools, update_pools
+
+    @property
+    def k_pool(self):
+        """The first pool: K, or the latent rows."""
+        return self._pools[0]
+
+    @property
+    def v_pool(self):
+        return self._pools[1]
 
     # -- allocator ---------------------------------------------------------
     def _blocks_for(self, num_tokens: int) -> int:
@@ -245,11 +277,10 @@ class PagedKVCache:
         self._gauges()
 
     def _copy_block(self, src: int, dst: int):
-        """Device-copy one block (all layers, K and V) — the COW path.
-        One fused dispatch pair per copy; copies are rare (only shared
-        partial blocks on first divergence)."""
-        self.k_pool = self.k_pool.at[:, dst].set(self.k_pool[:, src])
-        self.v_pool = self.v_pool.at[:, dst].set(self.v_pool[:, src])
+        """Device-copy one block (all layers, every pool) — the COW
+        path. One fused dispatch a pool per copy; copies are rare (only
+        shared partial blocks on first divergence)."""
+        self._pools = tuple(p.at[:, dst].set(p[:, src]) for p in self._pools)
 
     # -- accounting --------------------------------------------------------
     def blocks_used(self) -> int:
@@ -393,8 +424,8 @@ class StateStore:
 
 class Sequence:
     """What one live sequence holds of a :class:`SequenceCache`: a
-    block table where the net has attention layers, a state's slot
-    where it has retention layers."""
+    block table where the net has attention or latent layers, a state's
+    slot where it has retention layers."""
 
     __slots__ = ("table", "state")
 
@@ -406,39 +437,52 @@ class SequenceCache:
     """The generation engine's cache manager: whatever the net's layers
     keep for a live sequence, configured by the net's ``cache_spec()``
     (a layer kind -> its geometry). ``attention`` layers get a
-    :class:`PagedKVCache` that grows block by block; ``retention``
-    layers a :class:`StateStore` of one fixed state a decode slot. A
-    sequence is admitted when every part can hold it, grows only where
-    a part grows, and is released from all of them at once.
+    :class:`PagedKVCache` of K and V pools that grows block by block;
+    ``latent`` layers the same allocator over one pool of their row's
+    ``width`` (in whole lane tiles: 576 -> 640); ``retention`` layers a
+    :class:`StateStore` of one fixed state a decode slot. A sequence is
+    admitted when every part can hold it, grows only where a part
+    grows, and is released from all of them at once.
 
     The engine threads ``arrays()`` through its executables as one
     donated pytree and hands back what they return (``adopt``); ``rows``
     stages the per-slot indices that go beside them (block tables,
-    state slots). ``num_blocks`` / ``blocks_used()`` / ``occupancy()``
-    are the paged pool's where there is one, else the state store's (a
-    block is then one sequence's state); ``stats()`` gives both."""
+    state slots); both in :data:`CACHE_ARRAYS`'s order of kinds, each
+    kind as many arrays as it says there. ``num_blocks`` /
+    ``blocks_used()`` / ``occupancy()`` are the paged pool's where there
+    is one, else the state store's (a block is then one sequence's
+    state); ``stats()`` gives both."""
 
     def __init__(self, spec, *, slots, max_seq=None, num_blocks=None,
                  block_size=None, dtype="float32", name="model"):
-        unknown = set(spec) - {"attention", "retention"}
-        if unknown or not spec:
+        unknown = set(spec) - set(CACHE_ARRAYS)
+        if unknown or not spec or {"attention", "latent"} <= set(spec):
             raise ValueError(
-                "a cache spec names 'attention' and/or 'retention' "
-                f"layers; got {sorted(spec)}")
+                f"a cache spec names layer kinds of {tuple(CACHE_ARRAYS)}, "
+                "'attention' or 'latent' but not both (a sequence has one "
+                f"block table); got {sorted(spec)}")
         self.name = str(name)
         self.pool = self.states = None
+        paged = dict(max_seq=max_seq, num_blocks=num_blocks,
+                     block_size=block_size, dtype=dtype, name=name)
         if "attention" in spec:
             a = spec["attention"]
+            self.pool = PagedKVCache(a["layers"], a["kv_heads"],
+                                     a["head_dim"], **paged)
+        if "latent" in spec:
+            c = spec["latent"]
             self.pool = PagedKVCache(
-                a["layers"], a["kv_heads"], a["head_dim"], max_seq=max_seq,
-                num_blocks=num_blocks, block_size=block_size, dtype=dtype,
-                name=name)
+                c["layers"], width=-(-int(c["width"]) // _LANES) * _LANES,
+                arrays=CACHE_ARRAYS["latent"], **paged)
         if "retention" in spec:
             r = spec["retention"]
             self.states = StateStore(r["layers"], r["kv_heads"],
                                      r["head_dim"], slots=slots, name=name,
                                      gauges=self.pool is None)
         self._main = self.pool if self.pool is not None else self.states
+        # the parts in the faces' order of kinds
+        self._parts = [{"retention": self.states}.get(kind, self.pool)
+                       for kind in CACHE_ARRAYS if kind in spec]
 
     # -- what the pool's readers read --------------------------------------
     @property
@@ -466,48 +510,42 @@ class SequenceCache:
 
     # -- the arrays, as the executables take them ---------------------------
     def arrays(self):
-        """``(k_pool, v_pool)``, ``(state, norm)`` or all four: the
-        net's faces take them in this order."""
-        out = ()
-        if self.pool is not None:
-            out += self.pool.pools()
-        if self.states is not None:
-            out += self.states.arrays()
-        return out
+        """Every part's arrays side by side: ``(k_pool, v_pool)``,
+        ``(state, norm)``, ``(latent_pool,)`` or two of them; the net's
+        faces take them in this order."""
+        return tuple(a for part in self._parts for a in part.arrays())
 
     def adopt(self, arrays):
         """The arrays a dispatch returned (the donated ones are dead)."""
         arrays = tuple(arrays)
-        if self.pool is not None:
-            self.pool.update_pools(*arrays[:2])
-            arrays = arrays[2:]
-        if self.states is not None:
-            self.states.adopt(*arrays)
+        for part in self._parts:
+            n = len(part.arrays())
+            part.adopt(*arrays[:n])
+            arrays = arrays[n:]
 
     def rows(self, sequences):
         """The index operands for a batch of sequences (``None`` for an
         empty slot): block tables ``(B, max_blocks)`` and/or state
         slots ``(B,)``, int32, in ``arrays()``'s order of kinds."""
         out = ()
-        if self.pool is not None:
-            mb = self.pool.max_blocks_per_seq
-            tables = np.zeros((len(sequences), mb), np.int32)
-            for i, seq in enumerate(sequences):
-                if seq is not None:
-                    tables[i] = seq.table.device_row(mb)
-            out += (tables,)
-        if self.states is not None:
-            out += (np.asarray(
-                [self.states.null if seq is None else seq.state
-                 for seq in sequences], np.int32),)
+        for part in self._parts:
+            if isinstance(part, PagedKVCache):
+                mb = part.max_blocks_per_seq
+                tables = np.zeros((len(sequences), mb), np.int32)
+                for i, seq in enumerate(sequences):
+                    if seq is not None:
+                        tables[i] = seq.table.device_row(mb)
+                out += (tables,)
+            else:
+                out += (np.asarray(
+                    [part.null if seq is None else seq.state
+                     for seq in sequences], np.int32),)
         return out
 
     def release_arrays(self):
         """Drops the device arrays (the engine was released)."""
-        if self.pool is not None:
-            self.pool.k_pool = self.pool.v_pool = None
-        if self.states is not None:
-            self.states.state = self.states.norm = None
+        for part in self._parts:
+            part.adopt(*(None,) * len(part.arrays()))
 
     # -- a sequence's life ---------------------------------------------------
     def allocate(self, num_tokens: int) -> Sequence:

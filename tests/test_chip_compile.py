@@ -320,3 +320,113 @@ def test_engine_sorts_the_vocabulary_only_under_a_conditional_on_v5e(
     # (the retention step sorts its 16 slots by liveness, always)
     assert [line for line in outside if f"{vocab}]" in line] == []
     assert any(f"{vocab}]" in line for line in inside), inside
+
+
+# ---------------------------------------------------------------------------
+# latent attention over the latent pool, and the expert layer's grouped
+# products, at JoyAI-LLM-Flash's published widths
+# ---------------------------------------------------------------------------
+
+def test_latent_decode_compiles_for_v5e(on_chip):
+    """The serve cell's shapes: 64 slots, 32 query heads against one
+    row of 576 numbers in 640 lanes, blocks of 16 tokens, 288 a table,
+    the whole pool of 5 layers x 18,433 blocks, the layer an operand."""
+    _compiles_to_kernel(
+        lambda layer, t, l, q, pool: fa._pallas_latent_decode(
+            layer, t, l, q, pool, scale=192 ** -0.5)[..., :512],
+        on_chip((1,), jnp.int32), on_chip((64, 288), jnp.int32),
+        on_chip((64,), jnp.int32), on_chip((64, 32, 640), jnp.bfloat16),
+        on_chip((5, 18433, 16, 640), jnp.bfloat16),
+        names=["mxtpu_latent_decode"])
+
+
+@pytest.mark.parametrize("tokens", [64, 4096], ids=["decode", "prefill"])
+def test_experts_product_compiles_for_v5e(on_chip, monkeypatch, tokens):
+    """A layer's 256 experts of 2,048 x 768 under 8 pairs a token: the
+    three grouped products compile to the kernel ``mxtpu_experts_gmm``
+    (row tiles of 64 for the decode batch's 512 pairs, 256 for a 4,096
+    prompt's 32,768), and nothing copies a stack of experts."""
+    from mxnet_tpu.ops import experts as ex
+
+    monkeypatch.setattr(ex, "_use_pallas", lambda: True)  # no TPU here
+    d, E, ff, k = 2048, 256, 768, 8
+    exe = jax.jit(ex.experts_apply).lower(
+        on_chip((tokens, d), jnp.bfloat16), on_chip((tokens, k), jnp.float32),
+        on_chip((tokens, k), jnp.int32), on_chip((E, d, ff), jnp.bfloat16),
+        on_chip((E, d, ff), jnp.bfloat16), on_chip((E, ff, d), jnp.bfloat16),
+        on_chip((tokens,), bool)).compile()
+    calls = [line.lstrip() for line in exe.as_text().splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(calls) == 3 and all(
+        c.startswith("%mxtpu_experts_gmm") for c in calls), calls
+    assert [line for line in exe.as_text().splitlines()
+            if ("256,2048,768]" in line or "256,768,2048]" in line)
+            and (" copy(" in line or " transpose(" in line)] == []
+
+
+def _joyai_net(on_chip, layers, vocab):
+    """JoyAI-LLM-Flash's layers at their published widths (the first
+    dense), the parameters as shapes on the chip, held as the
+    benchmark's glue holds them."""
+    import json
+
+    from chipbench.models import latent_experts_lm as glue
+    from chipbench.references import latent_experts_lm as ref
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "chipbench", "configs",
+            "joyai_llm_flash.json")) as f:
+        cfg = {**json.load(f), "num_hidden_layers": layers,
+               "vocab_size": vocab, "served_positions": 4608}
+    shapes = jax.eval_shape(lambda: ref.init_params(cfg, 1, "bfloat16"))
+    net = glue.build_net(cfg, {k: on_chip(v.shape, v.dtype)
+                               for k, v in shapes.items()}, "bfloat16")
+    return net, net.params()
+
+
+@pytest.mark.parametrize("which", ["chunk_fn", "prefill_fn"])
+def test_latent_engine_works_on_the_pool_in_place_on_v5e(on_chip,
+                                                         monkeypatch, which):
+    """The two programs ``GenerationEngine`` compiles for the latent
+    net (the dense layer and one expert layer, a small vocabulary) over
+    the serve cell's pool: with the pool donated the temporaries stay a
+    small share of it and nothing of its size is copied (the 4,096
+    bucket's own activations are a third of it); the chunk's kernels
+    are the two latent decodes and the expert layer's three products."""
+    from mxnet_tpu.ops import experts as ex
+    from mxnet_tpu.serving import generation
+
+    monkeypatch.setattr(fa, "_use_pallas", lambda d: True)  # no TPU here
+    monkeypatch.setattr(ex, "_use_pallas", lambda: True)
+    layers, slots, mb = 2, 64, 288
+    net, params = _joyai_net(on_chip, layers, vocab=512)
+    pool = on_chip((layers, 18433, 16, 640), jnp.bfloat16)
+    chunk_fn, prefill_fn = generation.generation_programs(net, 8)
+
+    def vec(rows, dtype):
+        return on_chip((rows,), dtype)
+
+    if which == "chunk_fn":
+        n = slots
+        args = (params, (pool,), (on_chip((n, mb), jnp.int32),),
+                vec(n, jnp.int32), vec(n, jnp.int32), vec(n, bool),
+                vec(n, jnp.int32), on_chip((2,), jnp.uint32),
+                vec(n, jnp.float32), vec(n, jnp.int32), vec(n, jnp.float32),
+                vec(n, bool), vec(n, jnp.int32))
+        exe = jax.jit(chunk_fn, donate_argnums=(1,)).lower(*args).compile()
+        share, kernels = 0.1, 2 + 3
+        # the counters ride out beside what the chunk returned before
+        assert jax.eval_shape(chunk_fn, *args)[-1].shape == (3,)
+    else:
+        args = (params, on_chip((1, 4096), jnp.int32), (pool,),
+                (on_chip((1, mb), jnp.int32),), vec(1, jnp.int32),
+                vec(1, jnp.int32), vec(1, jnp.float32), vec(1, jnp.int32),
+                vec(1, jnp.float32), vec(1, bool))
+        exe = jax.jit(prefill_fn, donate_argnums=(2,)).lower(*args).compile()
+        share, kernels = 1.0, 3
+    pool_bytes = 2 * math.prod(pool.shape)
+    assert exe.memory_analysis().temp_size_in_bytes < share * pool_bytes
+    hlo = exe.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == kernels
+    assert [line for line in hlo.splitlines() if "18433,16,640]" in line
+            and (" copy(" in line or " transpose(" in line)] == []

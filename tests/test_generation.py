@@ -729,3 +729,83 @@ def test_every_chunk_carries_the_pools_blocks_in_use(traced):
     longest = max(len(p) + n for p, n, toks in traced["answers"])
     assert max(seen) >= (longest - 1) // st["block_size"]
     assert st["blocks_used"] == 0  # all retired
+
+
+# ---------------------------------------------------------------------------
+# latent layers over the latent pool, expert layers and their counters
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def joyai():
+    """``joyai_tiny`` (a dense latent layer, then an expert layer)
+    behind an engine of two slots."""
+    import jax
+
+    net = TransformerDecoderLM.from_preset("joyai_tiny", seed=7)
+    eng = GenerationEngine(net, [16, 32], slots=2, chunk=4, cache_blocks=24,
+                           cache_block_size=4, name="joyai-test")
+    yield net, eng, jax.jit(net.forward_fn())
+    eng.close()
+
+
+def test_latent_prefill_then_decode_gives_the_forward_logits(joyai):
+    """Prefill writes the latent rows through the block table, decode
+    attends to them absorbed: every greedy token is the oracle's."""
+    net, eng, fwd = joyai
+    assert eng.cache.pool is not None and eng.cache.states is None
+    assert len(eng.cache.arrays()) == 1  # one pool, not K and V
+    rng = np.random.RandomState(3)
+    for plen in (5, 16, 27):
+        p = rng.randint(0, 128, plen).astype(np.int32)
+        toks = eng.predict(p, max_new_tokens=9, greedy=True, timeout=120.0)
+        logits = np.asarray(fwd(net.params(),
+                                np.concatenate([p, toks])[None]))[0]
+        assert list(toks) == list(
+            logits[plen - 1:plen - 1 + len(toks)].argmax(-1))
+
+
+def test_latent_slots_and_blocks_are_reused_after_release(joyai):
+    """Seven requests at once through two slots: blocks are freed and
+    taken again (a stale row in a re-used block would show), and the
+    pool drains."""
+    net, eng, fwd = joyai
+    rng = np.random.RandomState(4)
+    prompts = [rng.randint(0, 128, n).astype(np.int32)
+               for n in (9, 30, 17, 5, 32, 12, 25)]
+    futs = [eng.submit(p, max_new_tokens=11, greedy=True) for p in prompts]
+    for p, f in zip(prompts, futs):
+        toks = f.result(timeout=120.0)
+        logits = np.asarray(fwd(net.params(),
+                                np.concatenate([p, toks])[None]))[0]
+        assert list(toks) == list(
+            logits[len(p) - 1:len(p) - 1 + len(toks)].argmax(-1))
+    assert eng.stats()["cache"]["blocks_used"] == 0
+
+
+def test_the_expert_counters_add_up(joyai):
+    """``stats()["experts"]``: every decoded token of the one expert
+    layer routes two pairs; a step hits between one expert and as many
+    as it has pairs; the fullest expert holds at least the mean."""
+    net, eng, _ = joyai
+    before = dict(eng.stats()["experts"])
+    s0 = eng.stats()
+    rng = np.random.RandomState(6)
+    futs = [eng.submit(rng.randint(0, 128, n).astype(np.int32),
+                       max_new_tokens=m, greedy=True)
+            for n, m in ((7, 6), (20, 9), (11, 1))]
+    for f in futs:
+        f.result(timeout=120.0)
+    s1 = eng.stats()
+    got = {k: s1["experts"][k] - before[k] for k in before}
+    decoded = (s1["tokens_generated"] - s0["tokens_generated"]) \
+        - (s1["prefills"] - s0["prefills"])
+    assert decoded == 5 + 8 + 0
+    assert got["routed_pairs"] == 2 * decoded
+    steps = (s1["decode_chunks"] - s0["decode_chunks"]) * 4
+    assert decoded / 2 <= got["experts_hit"] <= got["routed_pairs"]
+    assert got["routed_pairs"] / 8 <= got["load_max"] <= got["routed_pairs"]
+    assert got["load_max"] <= 2 * steps  # two live tokens a step at most
+
+
+def test_a_net_without_experts_reports_no_expert_counters(eng):
+    assert "experts" not in eng.stats()

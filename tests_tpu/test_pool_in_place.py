@@ -142,3 +142,57 @@ def test_serve_cell_engine_holds_its_pool_in_place(cache_blocks):
     assert got["chunk"]["tpu_custom_calls"] == eng.cache.layers
     assert got["chunk"]["kernel_named"]
     assert got["prefill64"]["tpu_custom_calls"] == 0
+
+
+def latent_cell_engine():
+    """``joyai_llm_flash.serve_assist``'s engine over its whole latent
+    pool's blocks, with the dense layer and one expert layer at their
+    published widths, a vocabulary of 8,192 and the 256 bucket alone
+    (the full net is the benchmark's to build: 11 GB)."""
+    from chipbench.models import latent_experts_lm as glue
+    from chipbench.references import latent_experts_lm as ref
+    from mxnet_tpu.serving import GenerationEngine
+
+    with open(os.path.join(_ROOT, "chipbench", "workloads",
+                           "joyai_llm_flash.serve_assist.json")) as f:
+        cell = json.load(f)
+    with open(os.path.join(_ROOT, "chipbench", "configs",
+                           "joyai_llm_flash.json")) as f:
+        cfg = {**json.load(f), "num_hidden_layers": 2, "vocab_size": 8192,
+               "served_positions": 4608}
+    net = glue.build_net(cfg, ref.init_params(cfg, 1, cell["dtype"]),
+                         cell["dtype"])
+    e = cell["engine"]
+    return GenerationEngine(
+        net, [min(e["buckets"])], slots=e["slots"], chunk=e["chunk"],
+        cache_block_size=e["cache_block_size"],
+        cache_blocks=e["cache_blocks"], name="latent-in-place",
+        autostart=False)
+
+
+def test_latent_cell_engine_holds_its_pool_in_place():
+    eng = latent_cell_engine()
+    try:
+        c = eng.cache.pool
+        assert len(c.pools()) == 1 and c.width == 640
+        out = {"cache_blocks": c.num_blocks,
+               "pool_bytes": int(c.k_pool.nbytes),
+               "pool_temp_share": eng.stats()["pool_temp_share"]}
+        for name, exe in (("chunk", eng._chunk_exe),
+                          ("prefill256", eng._prefill_exes[256])):
+            hlo = exe.as_text()
+            out[name] = {
+                "temp_over_pool": (exe.memory_analysis().temp_size_in_bytes
+                                   / c.k_pool.nbytes),
+                "pool_shaped_ops": sorted(
+                    [op, shape, n] for (op, shape), n in pool_shaped_ops(
+                        hlo, c.layers, c.num_blocks, c.block_size, 1,
+                        c.width).items()),
+                "kernel_named": "%mxtpu_latent_decode" in hlo}
+    finally:
+        eng.close()
+    print("\n" + json.dumps({"latent_pool_in_place": out}))
+    assert out["pool_temp_share"] < 0.25, out
+    assert out["chunk"]["kernel_named"]
+    for name in ("chunk", "prefill256"):
+        assert out[name]["pool_shaped_ops"] == [], out[name]
