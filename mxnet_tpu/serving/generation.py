@@ -12,7 +12,9 @@ removes it at three levels:
   slot count, so the host touches the loop once per chunk —
   amortized XLA dispatches per generated token are ``<= 1/chunk``
   (tests/test_generation.py::test_single_dispatch_chunk_budget counts
-  them);
+  them), and a dispatch crosses to the device once each way: the
+  slots' state goes up as one packed array and the chunk's results
+  come back in one fetch (``stats()["host_transfers"]``);
 - **on-device sampling** (:func:`sample_tokens`): greedy / temperature
   / top-k / top-p per SLOT (every request carries its own knobs as
   operands, so mixed sampling policies share one executable), PRNG
@@ -78,7 +80,7 @@ from .errors import (
     ServerOverloaded,
     ServingError,
 )
-from .kvcache import SequenceCache
+from .kvcache import SequenceCache, split_index
 
 _SLOTS_DEFAULT = 8
 _CHUNK_DEFAULT = 8
@@ -272,17 +274,44 @@ class GenerateFuture:
 # the engine
 # ---------------------------------------------------------------------------
 
+# A dispatch crosses to the device once: what the host knows of each slot
+# goes up as one int32 row a slot. The chunk's row is these columns (the
+# two floats bit-cast), then the slot's index row as the cache lays it
+# out (``SequenceCache.write_row``: block table, state slot); the first
+# four come back in the chunk's result.
+(_LENS, _TOKEN, _ACTIVE, _REMAINING, _TOP_K, _GREEDY, _EOS, _TEMP,
+ _TOP_P) = range(9)
+_CARRY_COLS, _SLOT_COLS = 4, 9
+# a prefill's one row: these, then the sequence's index row
+_P_LENGTH, _P_SEED, _P_TOP_K, _P_GREEDY, _P_TEMP, _P_TOP_P = range(6)
+_PREFILL_COLS = 6
+
+
 def generation_programs(net, chunk):
     """The two programs the engine compiles for ``net``, as pure
     functions: ``chunk_fn`` (``chunk`` decode steps of the whole slot
     batch with sampling and the EOS/budget bookkeeping in-graph) and
     ``prefill_fn`` (one padded prompt and its first token). ``cache``
-    is the cache's arrays as one pytree, donated and returned; ``index``
-    the per-slot indices that go beside them (block tables, states'
-    slots), in the order the net's faces take them. For a net with
-    expert layers ``chunk_fn`` returns one thing more: the int32
-    ``[routed_pairs, experts_hit, load_max]`` summed over the chunk's
-    decode steps and the expert layers, of the live slots' tokens."""
+    is the cache's arrays as one pytree, donated and returned. Whatever
+    else the host says goes up as ONE packed int32 operand, taken apart
+    in the program:
+
+    - ``chunk_fn(params, cache, rows, rng)``: ``rows`` is ``(slots,
+      9 + W)``, a row a slot: ``lens, token, active, remaining, top_k,
+      greedy, eos``, ``temperature`` and ``top_p`` as their float32
+      bits, then the slot's ``W`` index columns (its block table and/or
+      state slot, as the net's ``cache_spec()`` kinds have them:
+      :func:`~.kvcache.split_index`). Returns ``(cache, rng, result)``,
+      ``result`` ``(chunk + 4, slots)`` int32: a row a step of the
+      token each slot emitted (-1 where it emitted none), then the
+      final ``lens, token, active, remaining``. For a net with expert
+      layers one thing more: the int32 ``[routed_pairs, experts_hit,
+      load_max]`` summed over the chunk's decode steps and the expert
+      layers, of the live slots' tokens.
+    - ``prefill_fn(params, tokens, cache, row)``: ``row`` is
+      ``(1, 6 + W)``: ``length, seed, top_k, greedy``, ``temperature``
+      and ``top_p`` as bits, then the sequence's index row. Returns
+      ``(first token, cache)``."""
     import jax
     import jax.numpy as jnp
 
@@ -291,9 +320,20 @@ def generation_programs(net, chunk):
         else net.decode_step_fn()
     prefill = net.prefill_fn()
     chunk_t = int(chunk)
+    kinds = tuple(net.cache_spec()) if hasattr(net, "cache_spec") \
+        else ("attention",)
 
-    def chunk_fn(params, cache, index, lens, token, active,
-                 remaining, rng, temp, top_k, top_p, greedy, eos):
+    def floats(bits):
+        return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+    def chunk_fn(params, cache, rows, rng):
+        lens, token = rows[:, _LENS], rows[:, _TOKEN]
+        active, remaining = rows[:, _ACTIVE] != 0, rows[:, _REMAINING]
+        top_k, greedy = rows[:, _TOP_K], rows[:, _GREEDY] != 0
+        eos = rows[:, _EOS]
+        temp, top_p = floats(rows[:, _TEMP]), floats(rows[:, _TOP_P])
+        index = split_index(kinds, rows[:, _SLOT_COLS:])
+
         def body(carry, _):
             cache, lens, token, active, remaining, rng, *load = carry
             logits, *cache = step(params, token, lens, *cache, *index,
@@ -318,14 +358,20 @@ def generation_programs(net, chunk):
         carry, (toks, flags) = jax.lax.scan(body, carry, None,
                                             length=chunk_t)
         cache, lens, token, active, remaining, rng, *load = carry
-        return (cache, lens, token, active, remaining, rng, toks, flags,
-                *load)
+        result = jnp.concatenate([
+            jnp.where(flags, toks, -1),
+            jnp.stack([lens, token, active.astype(jnp.int32), remaining])])
+        return (cache, rng, result, *load)
 
-    def prefill_fn(params, tokens, cache, index, length,
-                   seed_v, temp, top_k, top_p, greedy):
-        logits, *cache = prefill(params, tokens, *cache, *index, length)
-        key = jax.random.fold_in(jax.random.PRNGKey(0), seed_v[0])
-        tok = sample_tokens(logits, key, temp, top_k, top_p, greedy)
+    def prefill_fn(params, tokens, cache, row):
+        index = split_index(kinds, row[:, _PREFILL_COLS:])
+        logits, *cache = prefill(params, tokens, *cache, *index,
+                                 row[:, _P_LENGTH])
+        key = jax.random.fold_in(jax.random.PRNGKey(0),
+                                 row[0, _P_SEED])
+        tok = sample_tokens(
+            logits, key, floats(row[:, _P_TEMP]), row[:, _P_TOP_K],
+            floats(row[:, _P_TOP_P]), row[:, _P_GREEDY] != 0)
         return tok, tuple(cache)
 
     return chunk_fn, prefill_fn
@@ -415,6 +461,9 @@ class GenerationEngine:
         self._expert_load = _np.zeros(3, _np.int64) \
             if getattr(net, "expert_layers", 0) else None
         self._prefills = 0
+        # arrays staged for and fetched from the served dispatches
+        self._uploads = 0
+        self._fetches = 0
         self._requests_ok = 0
         self._refused = 0
         self._shed = 0
@@ -425,19 +474,26 @@ class GenerationEngine:
         self._pool_temp_worst = None
         self._decode_wall = 0.0
         self._sealed = False
-        # slot state (scheduler-thread-private after start)
+        # slot state (scheduler-thread-private after start): the chunk's
+        # packed operand, kept on the host as the scheduler's truth. Its
+        # columns are the mirrors that admission, shedding and retiring
+        # read and write (``_active`` and ``_greedy`` hold 0 or 1), so a
+        # chunk stages this one buffer as it stands
         n = self._slots
         self._slot_req = [None] * n
         self._slot_seqs = [None] * n
-        self._lens = _np.zeros(n, _np.int32)
-        self._token = _np.zeros(n, _np.int32)
-        self._active = _np.zeros(n, bool)
-        self._remaining = _np.zeros(n, _np.int32)
-        self._temp = _np.ones(n, _np.float32)
-        self._topk = _np.zeros(n, _np.int32)
-        self._topp = _np.ones(n, _np.float32)
-        self._greedy = _np.ones(n, bool)
-        self._eos = _np.full(n, -1, _np.int32)
+        self._rows = _np.zeros((n, _SLOT_COLS + self.cache.index_width),
+                               _np.int32)
+        (self._lens, self._token, self._active, self._remaining, self._topk,
+         self._greedy, self._eos) = (self._rows[:, c] for c in (
+             _LENS, _TOKEN, _ACTIVE, _REMAINING, _TOP_K, _GREEDY, _EOS))
+        self._temp = self._rows[:, _TEMP].view(_np.float32)
+        self._topp = self._rows[:, _TOP_P].view(_np.float32)
+        self._temp[:] = self._topp[:] = 1.0
+        self._greedy[:] = 1
+        self._eos[:] = -1
+        for row in self._rows:  # every slot empty: null blocks, null state
+            self.cache.write_row(row[_SLOT_COLS:], None)
         self._deploy(seed)
         self._thread = threading.Thread(
             target=self._loop, daemon=True,
@@ -470,17 +526,10 @@ class GenerationEngine:
 
         chunk_fn, prefill_fn = generation_programs(self._net, self._chunk)
         params = self._net.params()
-        n = self._slots
         self._params = params
         self._rng = jax.random.PRNGKey(int(seed))
-        # every slot empty: block tables of null blocks, the null state
-        chunk_args = (params, self.cache.arrays(),
-                      self.cache.rows([None] * n),
-                      jnp.zeros(n, jnp.int32), jnp.zeros(n, jnp.int32),
-                      jnp.zeros(n, bool), jnp.zeros(n, jnp.int32),
-                      self._rng, jnp.ones(n, jnp.float32),
-                      jnp.zeros(n, jnp.int32), jnp.ones(n, jnp.float32),
-                      jnp.ones(n, bool), jnp.full(n, -1, jnp.int32))
+        chunk_args = (params, self.cache.arrays(), jnp.asarray(self._rows),
+                      self._rng)
         jfn = jax.jit(chunk_fn, donate_argnums=(1,))
         t0 = time.perf_counter()
         self._chunk_exe = jfn.lower(*chunk_args).compile()
@@ -496,7 +545,7 @@ class GenerationEngine:
         out = self._chunk_exe(*chunk_args)
         jax.block_until_ready(out[0])
         self.cache.adopt(out[0])
-        self._rng = out[5]
+        self._rng = out[1]
 
         self._prefill_exes = {}
         jpf = jax.jit(prefill_fn, donate_argnums=(2,))
@@ -506,10 +555,8 @@ class GenerationEngine:
                     f"prompt bucket {tb} exceeds the net's max_seq "
                     f"{self.max_seq}")
             args = (params, jnp.zeros((1, tb), jnp.int32),
-                    self.cache.arrays(), self.cache.rows([None]),
-                    jnp.zeros(1, jnp.int32), jnp.zeros(1, jnp.int32),
-                    jnp.ones(1, jnp.float32), jnp.zeros(1, jnp.int32),
-                    jnp.ones(1, jnp.float32), jnp.ones(1, bool))
+                    self.cache.arrays(),
+                    jnp.asarray(self._prefill_row(None)))
             t0 = time.perf_counter()
             exe = jpf.lower(*args).compile()
             self._prefill_exes[tb] = exe
@@ -715,9 +762,9 @@ class GenerationEngine:
                     "generation deadline expired before a slot opened"),
                     "timeout")
         while True:
-            free = [s for s in range(self._slots) if not self._active[s]
-                    and self._slot_req[s] is None]
-            if not free:
+            # between chunks a slot is live or holds no request
+            free = _np.flatnonzero(self._active == 0)
+            if not free.size:
                 return admitted
             with self._lock:
                 req = self._queue.popleft() if self._queue else None
@@ -741,7 +788,7 @@ class GenerationEngine:
             req.t_admit = time.perf_counter()
             admitted += 1
             try:
-                self._prefill(req, seq, free[0])
+                self._prefill(req, seq, int(free[0]))
             except BaseException as e:  # noqa: BLE001 - typed to waiter
                 self.cache.release(seq)
                 self._fail(req, e if isinstance(e, ServingError) else
@@ -754,28 +801,42 @@ class GenerationEngine:
                        bucket=tb, prompt_len=plen, slot=slot):
             self._prefill_traced(req, seq, slot, plen, tb)
 
+    def _prefill_row(self, seq, req=None):
+        """A prefill's packed operand, one row: the request's scalars,
+        then ``seq``'s index row (``None``: the deploy's warm run, a
+        prompt of no tokens into the null block and the null state)."""
+        row = _np.zeros((1, _PREFILL_COLS + self.cache.index_width),
+                        _np.int32)
+        bits = row.view(_np.float32)
+        row[0, _P_GREEDY] = bits[0, _P_TEMP] = bits[0, _P_TOP_P] = 1
+        if req is not None:
+            row[0, _P_LENGTH] = len(req.prompt)
+            row[0, _P_SEED] = req.seed
+            row[0, _P_TOP_K] = req.top_k
+            row[0, _P_GREEDY] = req.greedy
+            bits[0, _P_TEMP] = max(req.temperature, 1e-6)
+            bits[0, _P_TOP_P] = req.top_p
+        self.cache.write_row(row[0, _PREFILL_COLS:], seq)
+        return row
+
     def _prefill_traced(self, req, seq, slot, plen, tb):
-        import jax.numpy as jnp
+        import jax
 
         padded = _np.zeros((1, tb), _np.int32)
         padded[0, :plen] = req.prompt
+        row = self._prefill_row(seq, req)
         t0 = time.perf_counter()  # dt: staging, the call and the sync
-        operands = (
-            jnp.asarray(padded), self.cache.arrays(),
-            self.cache.rows([seq]),
-            _np.array([plen], _np.int32),  # mxtpu-lint: host-sync-ok
-            _np.array([req.seed], _np.int32),  # mxtpu-lint: host-sync-ok
-            _np.array([max(req.temperature, 1e-6)], _np.float32),  # mxtpu-lint: host-sync-ok
-            _np.array([req.top_k], _np.int32),  # mxtpu-lint: host-sync-ok
-            _np.array([req.top_p], _np.float32),  # mxtpu-lint: host-sync-ok
-            _np.array([req.greedy], bool))  # host operand staging  # mxtpu-lint: host-sync-ok
+        tokens, staged = jax.device_put((padded, row))
+        self._uploads += 2
         with _obs.span("gen.prefill.device", cat="generation", bucket=tb):
-            tok, arrays = self._prefill_exes[tb](self._params, *operands)
+            tok, arrays = self._prefill_exes[tb](
+                self._params, tokens, self.cache.arrays(), staged)
             self.cache.adopt(arrays)
             # the ONE deliberate per-request sync: the first token
             # decides retire-or-seat before the next chunk can include
             # this slot
-            first = int(_np.asarray(tok)[0])  # mxtpu-lint: host-sync-ok
+            first = int(jax.device_get(tok)[0])  # mxtpu-lint: host-sync-ok
+            self._fetches += 1
         dt = time.perf_counter() - t0
         self.cache.written(seq, plen)
         self._prefills += 1
@@ -794,6 +855,7 @@ class GenerationEngine:
             return
         self._slot_req[slot] = req
         self._slot_seqs[slot] = seq
+        self._rows[slot, _SLOT_COLS:] = row[0, _PREFILL_COLS:]
         self._lens[slot] = plen  # next decode step writes position plen
         self._token[slot] = first
         self._active[slot] = True
@@ -814,106 +876,100 @@ class GenerationEngine:
         at the boundary (where the NEXT _admit can seat a newcomer)."""
         with _obs.span("gen.chunk", cat="generation") as sp:
             with _obs.span("gen.chunk.prep", cat="generation"):
-                index = self._grow_sequences()
-                if index is None:
+                live = self._grow_sequences()
+                if not live.size:
                     return
                 # the sampler's own predicate, before the chunk
-                if (self._active & ~self._greedy).any():
+                if (self._greedy[live] == 0).any():
                     self._filtered_chunks += 1
-                t0 = time.perf_counter()  # dt: staging, the call and the syncs
-                operands = self._chunk_operands(index)
+                t0 = time.perf_counter()  # dt: staging, the call and the sync
+                operands = self._chunk_operands()
             if sp is not _obs.NO_SPAN:
                 # read after the chunk's growth, where the cache is fullest
                 sp.set(blocks_used=self.cache.blocks_used())
             with _obs.span("gen.chunk.device", cat="generation",
                            steps=self._chunk):
-                toks, flags = self._run_chunk(operands)
+                toks = self._run_chunk(operands)
             dt = time.perf_counter() - t0
             with _obs.span("gen.chunk.deliver", cat="generation") as out:
-                emitted, retired = self._deliver(toks, flags, dt)
+                emitted, retired = self._deliver(live, toks, dt)
                 out.set(emitted=emitted, retired=retired)
 
     def _grow_sequences(self):
-        """Backs the chunk's cache growth slot by slot (blocks of the
-        pool; a state grows by nothing); the slots' index operands
-        (block tables, states' slots), or ``None`` when no slot is left
+        """Backs the chunk's cache growth, live slot by live slot
+        (blocks of the pool; a state grows by nothing), and rewrites the
+        index row of a slot whose sequence took a block; the slots left
         to step."""
-        # a pool too full to grow a sequence retires that request early
-        # (typed OOM)
-        for s in range(self._slots):
-            if not self._active[s]:
-                continue
-            need = int(self._lens[s]) + min(  # mxtpu-lint: host-sync-ok
-                self._chunk,
-                int(self._remaining[s]))  # host numpy mirror  # mxtpu-lint: host-sync-ok
+        live = _np.flatnonzero(self._active)
+        need = _np.minimum(
+            self._lens[live] + _np.minimum(self._chunk,
+                                           self._remaining[live]),
+            self.max_seq)
+        for s, tokens in zip(live.tolist(), need.tolist()):
+            seq = self._slot_seqs[s]
             try:
-                self.cache.ensure(self._slot_seqs[s],
-                                  min(need, self.max_seq))
+                if self.cache.ensure(seq, tokens):
+                    self.cache.write_row(self._rows[s, _SLOT_COLS:], seq)
             except KVCacheOOM as e:
+                # a pool too full to grow a sequence retires that
+                # request early (typed OOM)
                 req = self._slot_req[s]
-                self.cache.release(self._slot_seqs[s])
+                self.cache.release(seq)
                 self._clear_slot(s)
                 self._fail(req, e, "shed")
-        if not self._active.any():
-            return None
-        return self.cache.rows(self._slot_seqs)
+        return live[self._active[live] != 0]
 
-    def _chunk_operands(self, index):
-        """Stages the chunk's operands."""
-        import jax.numpy as jnp
+    def _chunk_operands(self):
+        """Stages the chunk's operands: the slots' rows are the one
+        upload (the cache's arrays and the key are resident)."""
+        import jax
 
-        return (self.cache.arrays(), tuple(jnp.asarray(i) for i in index),
-                jnp.asarray(self._lens), jnp.asarray(self._token),
-                jnp.asarray(self._active), jnp.asarray(self._remaining),
-                self._rng, jnp.asarray(self._temp),
-                jnp.asarray(self._topk), jnp.asarray(self._topp),
-                jnp.asarray(self._greedy), jnp.asarray(self._eos))
+        self._uploads += 1
+        return self.cache.arrays(), jax.device_put(self._rows), self._rng
 
     def _run_chunk(self, operands):
         """The chunk's executable, to the last byte the scheduler needs
-        of it: ``(tokens, emitted flags)``, each ``(chunk, slots)``."""
-        (arrays, lens, token, active, remaining, rng, toks, flags, *load) = \
-            self._chunk_exe(self._params, *operands)
-        self.cache.adopt(arrays)
-        self._rng = rng
-        if load:
-            self._expert_load += _np.asarray(load[0])  # mxtpu-lint: host-sync-ok
-        # ONE host sync per chunk: everything the scheduler needs
-        # (np.array copies — jax device views are read-only and the
-        # slot mirrors are mutated at admission)
-        toks = _np.asarray(toks)  # (chunk, slots)  # mxtpu-lint: host-sync-ok
-        flags = _np.asarray(flags)  # mxtpu-lint: host-sync-ok
-        self._lens = _np.array(lens)  # mxtpu-lint: host-sync-ok
-        self._token = _np.array(token)  # mxtpu-lint: host-sync-ok
-        self._active = _np.array(active)  # mxtpu-lint: host-sync-ok
-        self._remaining = _np.array(remaining)  # mxtpu-lint: host-sync-ok
-        return toks, flags
+        of it: the token each slot emitted a step, ``(chunk, slots)``,
+        -1 where it emitted none. The slots' ``lens, token, active,
+        remaining`` are written back into their mirrors."""
+        import jax
 
-    def _deliver(self, toks, flags, dt):
-        """Hands each slot its tokens of a chunk that took ``dt``
-        seconds and retires what finished; ``(tokens emitted, requests
-        retired)``."""
+        arrays, self._rng, result, *load = self._chunk_exe(self._params,
+                                                           *operands)
+        self.cache.adopt(arrays)
+        jax.block_until_ready(result)
+        with _obs.span("gen.chunk.fetch", cat="generation"):
+            # ONE host sync per chunk: everything the scheduler needs
+            result, *load = jax.device_get(  # mxtpu-lint: host-sync-ok
+                (result, *load))
+        self._fetches += 1
+        if load:
+            self._expert_load += load[0]
+        self._rows[:, :_CARRY_COLS] = result[self._chunk:].T
+        return result[:self._chunk]
+
+    def _deliver(self, live, toks, dt):
+        """Hands each of the ``live`` slots its tokens of a chunk that
+        took ``dt`` seconds and retires what finished; ``(tokens
+        emitted, requests retired)``."""
         self._decode_wall += dt
         self._chunks += 1
         now = time.perf_counter()
         emitted_total = retired = 0
-        for s in range(self._slots):
+        for s in live.tolist():
             req = self._slot_req[s]
-            if req is None:
-                continue
-            mask = flags[:, s]
-            n = int(mask.sum())  # host numpy  # mxtpu-lint: host-sync-ok
+            new = toks[:, s]
+            new = new[new >= 0].tolist()
+            n = len(new)
             if n:
-                req.tokens.extend(
-                    int(t) for t in toks[mask, s])  # mxtpu-lint: host-sync-ok
+                req.tokens.extend(new)
                 # tokens of one chunk arrive together: the honest
                 # inter-token latency is the amortized chunk wall time
                 per_tok = dt / n
                 if req.t_first is None:
                     req.t_first = now
                 req.t_last = now
-                for _ in range(n):
-                    self._itl.append(per_tok)
+                self._itl.extend([per_tok] * n)
                 if _obs.ENABLED:
                     _obs.DECODE_ITL_SECONDS.observe(per_tok,
                                                     model=self._name)
@@ -936,12 +992,12 @@ class GenerationEngine:
         return emitted_total, retired
 
     def _clear_slot(self, s):
+        """The slot holds nothing: no request, its index row the null
+        block's and the null state's again (its policy stays behind)."""
         self._slot_req[s] = None
         self._slot_seqs[s] = None
-        self._active[s] = False
-        self._lens[s] = 0
-        self._token[s] = 0
-        self._remaining[s] = 0
+        self._rows[s, :_CARRY_COLS] = 0  # lens, token, active, remaining
+        self.cache.write_row(self._rows[s, _SLOT_COLS:], None)
 
     def _retire(self, req, seq):
         self.cache.release(seq)
@@ -988,7 +1044,7 @@ class GenerationEngine:
             return len(self._queue)
 
     def active_slots(self) -> int:
-        return int(self._active.sum())
+        return int(self._active.sum())  # the column holds 0 or 1
 
     def stats(self) -> dict:
         """Engine-local snapshot (plain floats; telemetry-independent).
@@ -1013,6 +1069,8 @@ class GenerationEngine:
             "decode_chunks": self._chunks,
             "filtered_chunks": self._filtered_chunks,
             "dispatches": dispatches,
+            "host_transfers": {"uploads": self._uploads,
+                               "fetches": self._fetches},
             "tokens_per_dispatch": self._tokens / max(1, dispatches),
             "tokens_per_s": (self._tokens / self._decode_wall
                              if self._decode_wall else 0.0),

@@ -98,8 +98,8 @@ def kvcache_block_size() -> int:
 class BlockTable:
     """One sequence's view into the pool: ordered block ids + how many
     tokens are written. Host-side bookkeeping only — the device sees a
-    padded ``int32`` row (:meth:`device_row`) with the null block in
-    unused slots."""
+    padded ``int32`` row (:meth:`SequenceCache.write_row`) with the null
+    block in unused slots."""
 
     __slots__ = ("blocks", "length")
 
@@ -109,14 +109,6 @@ class BlockTable:
 
     def __repr__(self):
         return f"BlockTable(blocks={self.blocks}, length={self.length})"
-
-    def device_row(self, max_blocks: int) -> np.ndarray:
-        """Padded ``int32`` row for the decode batch's table operand —
-        unused entries point at the null block (id 0)."""
-        row = np.zeros((int(max_blocks),), dtype=np.int32)
-        n = min(len(self.blocks), int(max_blocks))
-        row[:n] = self.blocks[:n]
-        return row
 
 
 class PagedKVCache:
@@ -226,7 +218,8 @@ class PagedKVCache:
     def ensure(self, table: BlockTable, num_tokens: int):
         """Grow ``table`` to cover ``num_tokens`` tokens, triggering
         copy-on-write first if new tokens would land in a shared
-        partial block. Returns the table."""
+        partial block. True when the table's blocks changed (its row on
+        the device is then stale)."""
         need = self._blocks_for(num_tokens) - len(table.blocks)
         will_append = num_tokens > table.length
         copy = None
@@ -251,7 +244,7 @@ class PagedKVCache:
             self._copy_block(*copy)
             self.cow_copies += 1
         self._gauges()
-        return table
+        return need > 0 or copy is not None
 
     def fork(self, table: BlockTable) -> BlockTable:
         """Share ``table``'s prefix with a new sequence: refcount bump
@@ -445,10 +438,14 @@ class SequenceCache:
     grows, and is released from all of them at once.
 
     The engine threads ``arrays()`` through its executables as one
-    donated pytree and hands back what they return (``adopt``); ``rows``
-    stages the per-slot indices that go beside them (block tables,
-    state slots); both in :data:`CACHE_ARRAYS`'s order of kinds, each
-    kind as many arrays as it says there. ``num_blocks`` /
+    donated pytree and hands back what they return (``adopt``). What
+    goes beside them is an index row a sequence, ``index_width`` int32
+    wide (its block table and/or its state's slot): ``write_row`` puts
+    one into a row of the engine's packed operand, and
+    :func:`split_index` takes the rows apart again, in the program, into
+    the index operands the net's faces take (``rows`` does both on the
+    host); arrays and indices both in :data:`CACHE_ARRAYS`'s order of
+    kinds, each kind as many arrays as it says there. ``num_blocks`` /
     ``blocks_used()`` / ``occupancy()`` are the paged pool's where there
     is one, else the state store's (a block is then one sequence's
     state); ``stats()`` gives both."""
@@ -480,9 +477,13 @@ class SequenceCache:
                                      r["head_dim"], slots=slots, name=name,
                                      gauges=self.pool is None)
         self._main = self.pool if self.pool is not None else self.states
-        # the parts in the faces' order of kinds
+        # the kinds in the faces' order, and the part that serves each
+        self.kinds = tuple(kind for kind in CACHE_ARRAYS if kind in spec)
         self._parts = [{"retention": self.states}.get(kind, self.pool)
-                       for kind in CACHE_ARRAYS if kind in spec]
+                       for kind in self.kinds]
+        self.index_width = sum(
+            1 if part is self.states else part.max_blocks_per_seq
+            for part in self._parts)
 
     # -- what the pool's readers read --------------------------------------
     @property
@@ -523,24 +524,31 @@ class SequenceCache:
             part.adopt(*arrays[:n])
             arrays = arrays[n:]
 
+    def write_row(self, row, seq):
+        """``seq``'s index row into ``row`` (int32, ``index_width``
+        long): its table's blocks with the null block behind them, its
+        state's slot. ``None`` is an empty slot's row: a table of null
+        blocks, the null state."""
+        at = 0
+        for part in self._parts:
+            if part is self.states:
+                row[at] = part.null if seq is None else seq.state
+                at += 1
+                continue
+            mb = part.max_blocks_per_seq
+            blocks = () if seq is None else seq.table.blocks[:mb]
+            row[at:at + len(blocks)] = blocks
+            row[at + len(blocks):at + mb] = 0
+            at += mb
+
     def rows(self, sequences):
         """The index operands for a batch of sequences (``None`` for an
         empty slot): block tables ``(B, max_blocks)`` and/or state
         slots ``(B,)``, int32, in ``arrays()``'s order of kinds."""
-        out = ()
-        for part in self._parts:
-            if isinstance(part, PagedKVCache):
-                mb = part.max_blocks_per_seq
-                tables = np.zeros((len(sequences), mb), np.int32)
-                for i, seq in enumerate(sequences):
-                    if seq is not None:
-                        tables[i] = seq.table.device_row(mb)
-                out += (tables,)
-            else:
-                out += (np.asarray(
-                    [part.null if seq is None else seq.state
-                     for seq in sequences], np.int32),)
-        return out
+        packed = np.empty((len(sequences), self.index_width), np.int32)
+        for row, seq in zip(packed, sequences):
+            self.write_row(row, seq)
+        return split_index(self.kinds, packed)
 
     def release_arrays(self):
         """Drops the device arrays (the engine was released)."""
@@ -564,11 +572,12 @@ class SequenceCache:
                 raise
         return seq
 
-    def ensure(self, seq: Sequence, num_tokens: int):
+    def ensure(self, seq: Sequence, num_tokens: int) -> bool:
         """Room for ``seq`` to grow to ``num_tokens`` tokens: blocks of
-        the pool; a state needs none."""
-        if self.pool is not None:
-            self.pool.ensure(seq.table, num_tokens)
+        the pool; a state needs none. True when its index row changed
+        with it."""
+        return self.pool is not None \
+            and self.pool.ensure(seq.table, num_tokens)
 
     def written(self, seq: Sequence, num_tokens: int):
         """``seq`` now holds ``num_tokens`` tokens (the pool's
@@ -595,6 +604,25 @@ class SequenceCache:
 # pure in-graph helpers (used under jit by the decode model AND the tests —
 # one implementation of the table indirection, exercised from both sides)
 # ---------------------------------------------------------------------------
+
+def split_index(kinds, rows):
+    """Packed index rows ``(B, W)`` (:meth:`SequenceCache.write_row`) as
+    the index operands the net's faces take, for a net whose layers are
+    of ``kinds``: in :data:`CACHE_ARRAYS`'s order a ``(B,)`` column of
+    state slots for retention layers, the ``(B, max_blocks)`` table for
+    attention or latent layers. ``rows`` is a numpy array or a traced
+    one."""
+    out, at = (), 0
+    table = rows.shape[1] - ("retention" in kinds)
+    for kind in CACHE_ARRAYS:
+        if kind == "retention" and kind in kinds:
+            out += (rows[:, at],)
+            at += 1
+        elif kind in kinds:
+            out += (rows[:, at:at + table],)
+            at += table
+    return out
+
 
 def slot_coords(tables, pos, block_size, active=None):
     """``(block_id, offset)`` pool coordinates for writing each batch

@@ -289,32 +289,24 @@ def test_engine_sorts_the_vocabulary_only_under_a_conditional_on_v5e(
     if family == "gpt2_small":
         net, params = _gpt2_net(on_chip, layers, vocab)
         pool = on_chip((layers, 3073, 16, 768), jnp.bfloat16)
-        cache, per_row = (pool, pool), (64,)  # a block table a row
+        cache, index = (pool, pool), 64  # a block table a row
     else:
         net, params = _brumby_net(on_chip, layers, vocab)
         cache = tuple(on_chip(shape, jnp.float32)
                       for shape in R.state_shapes(layers, slots, 8, 128))
-        per_row = ()  # a state's slot a row
+        index = 1  # a state's slot a row
     chunk_fn, prefill_fn = generation.generation_programs(net, 8)
 
-    def vec(rows, dtype):
-        return on_chip((rows,), dtype)
-
-    def index(rows):
-        return (on_chip((rows,) + per_row, jnp.int32),)
-
     if which == "chunk_fn":
-        n = slots
-        args = (params, cache, index(n), vec(n, jnp.int32),
-                vec(n, jnp.int32), vec(n, bool), vec(n, jnp.int32),
-                on_chip((2,), jnp.uint32), vec(n, jnp.float32),
-                vec(n, jnp.int32), vec(n, jnp.float32), vec(n, bool),
-                vec(n, jnp.int32))
+        # the slots' packed rows and the key are all the host sends
+        args = (params, cache,
+                on_chip((slots, generation._SLOT_COLS + index), jnp.int32),
+                on_chip((2,), jnp.uint32))
         exe = jax.jit(chunk_fn, donate_argnums=(1,)).lower(*args).compile()
+        assert jax.eval_shape(chunk_fn, *args)[2].shape == (8 + 4, slots)
     else:
-        args = (params, on_chip((1, bucket), jnp.int32), cache, index(1),
-                vec(1, jnp.int32), vec(1, jnp.int32), vec(1, jnp.float32),
-                vec(1, jnp.int32), vec(1, jnp.float32), vec(1, bool))
+        args = (params, on_chip((1, bucket), jnp.int32), cache,
+                on_chip((1, generation._PREFILL_COLS + index), jnp.int32))
         exe = jax.jit(prefill_fn, donate_argnums=(2,)).lower(*args).compile()
     outside, inside = sorts_by_conditional(exe.as_text())
     # (the retention step sorts its 16 slots by liveness, always)
@@ -403,25 +395,18 @@ def test_latent_engine_works_on_the_pool_in_place_on_v5e(on_chip,
     pool = on_chip((layers, 18433, 16, 640), jnp.bfloat16)
     chunk_fn, prefill_fn = generation.generation_programs(net, 8)
 
-    def vec(rows, dtype):
-        return on_chip((rows,), dtype)
-
     if which == "chunk_fn":
-        n = slots
-        args = (params, (pool,), (on_chip((n, mb), jnp.int32),),
-                vec(n, jnp.int32), vec(n, jnp.int32), vec(n, bool),
-                vec(n, jnp.int32), on_chip((2,), jnp.uint32),
-                vec(n, jnp.float32), vec(n, jnp.int32), vec(n, jnp.float32),
-                vec(n, bool), vec(n, jnp.int32))
+        args = (params, (pool,),
+                on_chip((slots, generation._SLOT_COLS + mb), jnp.int32),
+                on_chip((2,), jnp.uint32))
         exe = jax.jit(chunk_fn, donate_argnums=(1,)).lower(*args).compile()
         share, kernels = 0.1, 2 + 3
-        # the counters ride out beside what the chunk returned before
-        assert jax.eval_shape(chunk_fn, *args)[-1].shape == (3,)
+        # the counters ride out beside the chunk's packed result
+        out = jax.eval_shape(chunk_fn, *args)
+        assert out[2].shape == (8 + 4, slots) and out[-1].shape == (3,)
     else:
         args = (params, on_chip((1, 4096), jnp.int32), (pool,),
-                (on_chip((1, mb), jnp.int32),), vec(1, jnp.int32),
-                vec(1, jnp.int32), vec(1, jnp.float32), vec(1, jnp.int32),
-                vec(1, jnp.float32), vec(1, bool))
+                on_chip((1, generation._PREFILL_COLS + mb), jnp.int32))
         exe = jax.jit(prefill_fn, donate_argnums=(2,)).lower(*args).compile()
         share, kernels = 1.0, 3
     pool_bytes = 2 * math.prod(pool.shape)

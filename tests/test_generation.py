@@ -56,12 +56,13 @@ def eng(net):
     e.close()
 
 
-def _assert_matches_dense(net, prompt, toks):
+def _assert_matches_dense(net, prompt, toks, fwd=None):
     """Dense full-context recompute check: ONE causal forward over
     prompt+generated must greedy-predict every generated token from its
     own prefix (equivalent to re-running the dense net per step — the
-    first mismatch fails exactly where a stepwise oracle would)."""
-    fwd, params = net.forward_fn(), net.params()
+    first mismatch fails exactly where a stepwise oracle would).
+    ``fwd`` is the net's forward, jitted, where a caller keeps one."""
+    fwd, params = fwd or net.forward_fn(), net.params()
     seq = np.array([int(t) for t in prompt] + [int(t) for t in toks],
                    np.int32)
     logits = np.asarray(fwd(params, seq[None]))
@@ -658,7 +659,7 @@ def test_scheduler_spans_tile_its_thread(traced):
         # a span open when the session began is not recorded, and its
         # children stand as top-level spans
         "gen.prefill", "gen.prefill.device", "gen.chunk.prep",
-        "gen.chunk.device", "gen.chunk.deliver"}
+        "gen.chunk.device", "gen.chunk.fetch", "gen.chunk.deliver"}
     assert {"gen.admit", "gen.chunk", "gen.idle"} \
         <= {ev["name"] for ev in top}
     for a, b in zip(top, top[1:]):
@@ -682,15 +683,21 @@ def test_scheduler_spans_tile_its_thread(traced):
     gaps = [b["ts"] - (a["ts"] + a["dur"]) for a, b in zip(top, top[1:])]
     assert sum(own.values()) + sum(gaps) == pytest.approx(
         thread, abs=1.0 + 0.5 * len(top))
-    # every prefill sits in an admit, every phase of a chunk in a chunk
+    # every prefill sits in an admit, every phase of a chunk in a chunk,
+    # the one fetch at the end of the chunk's wait for the device
     by_id = {ev["id"]: ev for ev in gen}
     for ev in gen:
         parent = by_id.get(ev["args"].get("parent"))
         if parent is not None:
             assert parent["name"] == {
                 "gen.prefill": "gen.admit",
-                "gen.prefill.device": "gen.prefill"}.get(
+                "gen.prefill.device": "gen.prefill",
+                "gen.chunk.fetch": "gen.chunk.device"}.get(
                     ev["name"], "gen.chunk")
+    fetched = [ev["args"]["parent"] for ev in gen
+               if ev["name"] == "gen.chunk.fetch"]
+    assert sorted(fetched) == sorted(
+        ev["id"] for ev in gen if ev["name"] == "gen.chunk.device")
 
 
 def test_request_phases_share_a_rid_and_sum_to_the_request(traced):
@@ -809,3 +816,177 @@ def test_the_expert_counters_add_up(joyai):
 
 def test_a_net_without_experts_reports_no_expert_counters(eng):
     assert "experts" not in eng.stats()
+
+
+# ---------------------------------------------------------------------------
+# a dispatch crosses to the device once each way: one packed upload a
+# chunk, one fetch; the packed rows are the scheduler's own mirrors
+# ---------------------------------------------------------------------------
+
+_FAMILIES = {
+    # an attention net (K and V pools, a block table a slot), a retention
+    # net (a state a slot, no table) and an expert net over the latent
+    # pool (a table, and the expert counters in the chunk's result)
+    "attention": lambda: TransformerDecoderLM(
+        vocab_size=VOCAB, num_layers=2, d_model=32, num_heads=4,
+        kv_heads=2, max_seq=MAX_SEQ, seed=0),
+    "retention": lambda: TransformerDecoderLM.from_preset("brumby_tiny",
+                                                          seed=3),
+    "experts": lambda: TransformerDecoderLM.from_preset("joyai_tiny",
+                                                        seed=7),
+}
+
+
+@pytest.fixture(scope="module", params=sorted(_FAMILIES))
+def family(request):
+    import jax
+
+    net = _FAMILIES[request.param]()
+    return request.param, net, jax.jit(net.forward_fn())
+
+
+def _family_engine(net, name, **kw):
+    kw = {"cache_blocks": 40, **kw}
+    return GenerationEngine(net, [8, 16], slots=2, chunk=4,
+                            cache_block_size=4, seed=11, name=name, **kw)
+
+
+def _assert_mirrors(eng):
+    """The packed rows say what the scheduler holds: a live slot's
+    index row is its sequence's, an empty slot's the null block's and
+    the null state's, with no length, token or budget left in it."""
+    from mxnet_tpu.serving import generation as G
+
+    for s, (req, seq) in enumerate(zip(eng._slot_req, eng._slot_seqs)):
+        index = eng._rows[s, G._SLOT_COLS:]
+        want = np.empty_like(index)
+        eng.cache.write_row(want, seq)  # no sequence: the null row
+        assert index.tolist() == want.tolist()
+        if req is None:
+            assert seq is None
+            assert not eng._rows[s, :G._CARRY_COLS].any()
+        else:
+            assert eng._active[s] == 1
+            assert eng._lens[s] == len(req.prompt) + len(req.tokens) - 1
+            assert eng._token[s] == req.tokens[-1]
+            assert eng._remaining[s] == req.max_new - len(req.tokens)
+
+
+def test_a_dispatch_crosses_to_the_device_once_each_way(family, monkeypatch):
+    """Five greedy requests and a sampled one through two slots: every
+    chunk is one upload and one fetch, every prefill two uploads (the
+    padded prompt and the request's packed row) and its one deliberate
+    fetch; the greedy tokens are the dense recompute's and the sampled
+    ones what an engine built on the unconditional sampler serves."""
+    from mxnet_tpu.serving import generation
+
+    kind, net, fwd = family
+    rng = np.random.RandomState(5)
+    prompts = [rng.randint(1, 40, n).astype(np.int32)
+               for n in (3, 9, 14, 6, 11)]
+    sampled = rng.randint(1, 40, 7).astype(np.int32)
+
+    def run(name):
+        e = _family_engine(net, name)
+        try:
+            futs = [e.submit(p, max_new_tokens=5 + 3 * i, greedy=True)
+                    for i, p in enumerate(prompts)]
+            outs = [f.result(120.0) for f in futs]
+            drawn = e.predict(sampled, timeout=120.0, **_SAMPLED)
+            return outs, drawn, e.stats()
+        finally:
+            e.close()
+
+    outs, drawn, st = run(f"cross-{kind}")
+    chunks, prefills = st["decode_chunks"], st["prefills"]
+    assert prefills == 6 and chunks > 0
+    assert st["dispatches"] == chunks + prefills
+    assert st["host_transfers"] == {"uploads": chunks + 2 * prefills,
+                                    "fetches": chunks + prefills}
+    assert st["host_transfers"]["uploads"] <= 2 * st["dispatches"]
+    assert st["host_transfers"]["fetches"] <= st["dispatches"]
+    for i, (p, toks) in enumerate(zip(prompts, outs)):
+        assert len(toks) == 5 + 3 * i
+        _assert_matches_dense(net, p, toks, fwd)
+    assert len(drawn) == _SAMPLED["max_new_tokens"]
+    monkeypatch.setattr(generation, "sample_tokens",
+                        _sample_tokens_unconditional)
+    want_outs, want_drawn, _ = run(f"cross-ref-{kind}")
+    assert list(drawn) == list(want_drawn)
+    for got, want in zip(outs, want_outs):
+        assert list(got) == list(want)
+
+
+def test_a_slot_that_changes_hands_takes_the_newcomers_row(family):
+    """The scheduler driven by hand, turn by turn: a request seated in
+    a slot that another just left gets its own index row there, the
+    other slot's row is the null block's again, and between any two
+    turns the packed rows agree with what the slots hold."""
+    kind, net, fwd = family
+    eng = _family_engine(net, f"hands-{kind}", autostart=False)
+    try:
+        _assert_mirrors(eng)  # deployed: every row a null row
+        rng = np.random.RandomState(8)
+        first = [rng.randint(1, 40, n).astype(np.int32) for n in (5, 13)]
+        futs = [eng.submit(first[0], max_new_tokens=4, greedy=True),
+                eng.submit(first[1], max_new_tokens=14, greedy=True)]
+        eng._admit()
+        assert [r is not None for r in eng._slot_req] == [True, True]
+        _assert_mirrors(eng)
+        eng._step_chunk()  # the short one leaves slot 0
+        assert futs[0].done() and eng._slot_req[0] is None
+        _assert_mirrors(eng)
+        late = rng.randint(1, 40, 10).astype(np.int32)
+        futs.append(eng.submit(late, max_new_tokens=9, greedy=True))
+        eng._admit()       # and the newcomer takes it
+        assert eng._slot_req[0] is not None
+        _assert_mirrors(eng)
+        for _ in range(8):
+            eng._admit()
+            if not eng._active.any():
+                break
+            eng._step_chunk()
+            _assert_mirrors(eng)
+        assert all(f.done() for f in futs)
+        for p, f in zip(first + [late], futs):
+            _assert_matches_dense(net, p, f.result(0), fwd)
+        _assert_mirrors(eng)  # every slot empty: null rows
+        assert eng.stats()["cache"]["blocks_used"] == 0
+        st = eng.stats()
+        assert st["host_transfers"]["fetches"] == st["dispatches"]
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("kind", ["attention", "experts"])
+def test_a_request_shed_for_want_of_blocks_leaves_a_null_row(kind):
+    """A pool of three usable blocks under two sequences of one block
+    each: the chunk's growth backs the first and sheds the second
+    (typed OOM); its slot's row is the null block's again, its block is
+    free, and the first request runs on to the dense recompute's
+    tokens, taking that block."""
+    from mxnet_tpu.serving import KVCacheOOM
+
+    net = _FAMILIES[kind]()
+    eng = _family_engine(net, f"shed-{kind}", autostart=False,
+                         cache_blocks=4)
+    try:
+        a, b = (np.array(p, np.int32) for p in ([3, 1, 4, 1], [2, 7, 1, 8]))
+        fa = eng.submit(a, max_new_tokens=8, greedy=True)
+        fb = eng.submit(b, max_new_tokens=8, greedy=True)
+        eng._admit()
+        assert eng.cache.blocks_used() == 2
+        eng._step_chunk()
+        with pytest.raises(KVCacheOOM):
+            fb.result(0)
+        assert eng._slot_req[1] is None
+        _assert_mirrors(eng)
+        assert eng.cache.blocks_used() == 2  # a's two; b's came back
+        while eng._active.any():
+            eng._step_chunk()
+            _assert_mirrors(eng)
+        _assert_matches_dense(net, a, fa.result(0))
+        assert eng.cache.blocks_used() == 0
+        assert eng.stats()["failed"] == 1
+    finally:
+        eng.close()

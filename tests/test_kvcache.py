@@ -12,6 +12,8 @@ import pytest
 from mxnet_tpu import observability as obs
 from mxnet_tpu.serving import BlockTable, KVCacheOOM, PagedKVCache
 from mxnet_tpu.serving.kvcache import (
+    Sequence,
+    SequenceCache,
     paged_gather,
     paged_prefill_write,
     paged_write,
@@ -170,10 +172,25 @@ def test_oom_counter_increments():
 
 
 def test_block_table_device_row_pads_with_null():
-    t = BlockTable([5, 9, 2], 0)
-    row = t.device_row(6)
-    assert row.dtype == np.int32
+    """A sequence's index row as the device sees it: its blocks, then
+    the null block, over whatever a longer table left in the row; an
+    empty slot's row is all null blocks. ``ensure`` says when a row is
+    stale."""
+    c = SequenceCache({"attention": {"layers": 1, "kv_heads": 1,
+                                     "head_dim": 4}},
+                      slots=2, max_seq=24, num_blocks=8, block_size=4)
+    assert c.index_width == 6
+    row = np.full(6, 7, np.int32)  # a stale, longer table
+    c.write_row(row, Sequence(table=BlockTable([5, 9, 2], 0)))
     assert row.tolist() == [5, 9, 2, 0, 0, 0]
+    seq = c.allocate(5)
+    assert not c.ensure(seq, 8)   # covered by its two blocks
+    assert c.ensure(seq, 9)       # took a third
+    (tables,) = c.rows([seq, None])
+    assert tables.dtype == np.int32
+    assert tables.tolist() == [seq.table.blocks + [0] * 3, [0] * 6]
+    c.write_row(row, None)
+    assert row.tolist() == [0] * 6
 
 
 # ---------------------------------------------------------------------------
