@@ -38,7 +38,10 @@ Three forms of the same mathematics, pinned against each other by
 - :func:`power_retention_chunked`: prefill. Inside a chunk the
   attention form, between chunks the state; positions at or beyond
   ``length`` leave the state untouched, so a padded bucket gives the
-  state after ``length`` tokens.
+  state after ``length`` tokens. On a TPU, for half-width operands,
+  the Pallas kernel ``mxtpu_retention_prefill``, which makes ``phi``
+  in VMEM and keeps the state there across the chunks; on the CPU and
+  for float32 operands the same mathematics in ``jax.numpy``.
 - :func:`power_retention_step`: one token a slot against the store, in
   place. On a TPU the Pallas kernel ``mxtpu_retention_decode``; on the
   CPU the same mathematics in ``jax.numpy`` (chosen by what the backend
@@ -103,21 +106,9 @@ def state_shapes(layers, slots, kv_heads, head_dim):
 # prefill: the chunked form
 # ---------------------------------------------------------------------------
 
-def power_retention_chunked(q, k, v, log_g, state, length, chunk=256):
-    """One sequence through one layer. ``q`` ``(T, H, d)``, ``k`` and
-    ``v`` ``(T, KVH, d)``, ``log_g`` ``(T, KVH)`` (the log of the gate,
-    at or under 0), ``state = (S, z)`` entering, ``(KVH, d, P)`` and
-    ``(KVH, R, d)`` float32. Returns ``(o (T, H, d) float32, state)``
-    where the state is the one after ``length`` tokens: positions at or
-    beyond ``length`` neither decay it nor add to it, and their outputs
-    are zero.
-
-    The matrix products take their operands in the activations' own
-    width, as every other product of the net does: under a bfloat16 net
-    ``phi(q)`` and the state it meets are rounded to bfloat16 on their
-    way into the matrix unit (accumulated in float32; the stored state
-    stays float32), under a float32 net they are float32 at ``highest``
-    precision."""
+def _jnp_chunked(q, k, v, log_g, state, length, chunk):
+    """CPU path, float32 path and oracle: a ``lax.scan`` over chunks,
+    ``phi(q)`` of a whole chunk built as one array."""
     T, H, d = q.shape
     half = q.dtype in (jnp.bfloat16, jnp.float16)
     low = q.dtype if half else jnp.float32
@@ -181,6 +172,212 @@ def power_retention_chunked(q, k, v, log_g, state, length, chunk=256):
 
     state, o = jax.lax.scan(one, state, (qc, kc, vc, gc, starts))
     return o.reshape(n * C, H, d)[:T], state
+
+
+def _prefill_kernel(len_ref, q_ref, k_ref, v_ref, vo_ref, g_ref, s_in, z_in,
+                    o_ref, s_ref, z_ref, qf_ref, qw_ref, num_ref, den_ref,
+                    *, groups, chunk, d):
+    """One grid step a (KV head, chunk): grid ``(KVH, n)``, the chunks
+    in order. The head's state is one block whose index does not move
+    along the chunks: fetched when the head starts, carried in VMEM,
+    written back after its last chunk. ``q_ref`` holds the chunk's
+    positions, the group's queries side by side on the lanes; ``vo_ref``
+    is ``(v * out)^T``, the left operand of the state's update; ``g_ref``
+    row 0 the running sum of the log gate, row 1 the chunk's decay
+    ``through`` on every lane, row 2 ``out``, what a position leaves in
+    the state.
+
+    ``phi``'s rows are walked as the decode kernel walks them: row ``r``
+    of ``phi(q)`` is made in VMEM, meets the state's ``(d, d)`` block
+    ``r`` in the matrix unit (and the normaliser's row on the vector
+    unit), and the same block is then decayed and updated with row
+    ``r`` of ``phi(k)``. A chunk wholly at or past ``length`` writes
+    zeros and leaves the state alone."""
+    C, G = chunk, groups
+    f32 = jnp.float32
+    i = pl.program_id(1)
+    left = len_ref[0] - i * C              # the chunk's positions in the prompt
+
+    @pl.when(i == 0)
+    def _enter():
+        s_ref[...] = s_in[...]
+        z_ref[...] = z_in[...]
+
+    @pl.when(left <= 0)
+    def _padding():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(left > 0)
+    def _chunk():
+        low = q_ref.dtype
+        for m in range(G):                 # the group's queries as rows
+            qm = q_ref[:, m * d:(m + 1) * d].astype(f32)
+            qf_ref[m * C:(m + 1) * C, :] = qm
+            qw_ref[m * C:(m + 1) * C, :] = math.sqrt(2.0) * qm
+        k = k_ref[...].astype(f32)
+        kw = math.sqrt(2.0) * k
+        vo = vo_ref[...]                   # (d, C)
+        through = g_ref[1:2, 0:d]          # (1, d)
+        rows = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+        cols = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+
+        def column(row):                   # (1, C) -> (C, 1), exactly
+            return jnp.sum(jnp.where(rows == cols, row, 0.0), axis=1,
+                           keepdims=True)
+
+        out = column(g_ref[2:3, 0:C])
+        num_ref[...] = jnp.zeros_like(num_ref)
+        den_ref[...] = jnp.zeros_like(den_ref)
+
+        def row(r, shift, weighted):
+            # row r of phi is (w_r a_i) a_{(i + r) mod d}, in float32,
+            # then rounded to the activations' width for the matrix unit
+            qf = qf_ref[...]
+            pq = ((qw_ref[...] if weighted else qf)
+                  * (qf if shift is None else pltpu.roll(qf, shift, 1))).astype(low)
+            pk = ((kw if weighted else k)
+                  * (k if shift is None else pltpu.roll(k, shift, 1)))
+            lanes = pl.ds(pl.multiple_of(r * d, d), d)
+            s_r = s_ref[:, lanes]                        # entering the chunk
+            z_r = z_ref[pl.ds(r, 1), :]
+            num_ref[...] += jax.lax.dot_general(
+                pq, s_r.astype(low), (((1,), (1,)), ((), ())),
+                preferred_element_type=f32)
+            den_ref[...] += pq.astype(f32) * z_r.astype(low).astype(f32)
+            s_ref[:, lanes] = through * s_r + jnp.dot(
+                vo, pk.astype(low), preferred_element_type=f32)
+            # the normaliser's update in float32, as the jnp form's is
+            z_ref[pl.ds(r, 1), :] = through * z_r + jnp.sum(
+                out * pk, axis=0, keepdims=True)
+
+        row(0, None, False)
+        jax.lax.fori_loop(1, d // 2, lambda r, c: (row(r, d - r, True), c)[1],
+                          0)
+        row(d // 2, d // 2, False)
+
+        # the chunk's own positions, in the attention form
+        run_row = g_ref[0:1, 0:C]                        # (1, C)
+        run_col = column(run_row)
+        keep = (cols <= rows) & (cols < left)
+        decay = jnp.where(keep, jnp.exp(jnp.where(keep, run_col - run_row,
+                                                  0.0)), 0.0)
+        into = jnp.exp(run_col)
+        ok = rows[:, 0:1] < left
+        for m in range(G):
+            at = slice(m * C, (m + 1) * C)
+            s = jax.lax.dot_general(
+                q_ref[:, m * d:(m + 1) * d], k_ref[...],
+                (((1,), (1,)), ((), ())), preferred_element_type=f32)
+            w = decay * (s * s)
+            num = num_ref[at, :] * into + jnp.dot(
+                w.astype(low), v_ref[...], preferred_element_type=f32)
+            den = jnp.sum(den_ref[at, :], axis=1, keepdims=True) * into \
+                + jnp.sum(w, axis=1, keepdims=True)
+            o_ref[:, m * d:(m + 1) * d] = jnp.where(ok, num, 0.0) \
+                / jnp.where(ok, den, 1.0)
+
+
+def _pallas_chunked(q, k, v, log_g, state, length, chunk, interpret=False):
+    """The kernel ``mxtpu_retention_prefill`` over ``chunk``-position
+    chunks (the bucket padded to a whole number of them). The gate's
+    running sums, the update's left operand ``(v * out)^T`` and
+    the operands' layouts are made here; ``q``, ``k``, ``v`` and the
+    output are their ``(T, heads x d)`` rows as they lie, a block a
+    chunk's rows by one KV head's lanes."""
+    T, H, d = q.shape
+    KVH = k.shape[1]
+    G = H // KVH
+    R = feature_rows(d)
+    P = R * d
+    C = int(chunk)
+    pad = -T % C
+    f32 = jnp.float32
+    low = q.dtype
+    if pad:
+        q, k, v = (jnp.pad(a, ((0, pad), (0, 0), (0, 0))) for a in (q, k, v))
+        log_g = jnp.pad(log_g, ((0, pad), (0, 0)))
+    n = (T + pad) // C
+    valid = jnp.arange(n * C, dtype=jnp.int32) < length
+    lg = jnp.where(valid[:, None], log_g.astype(f32), 0.0).reshape(n, C, KVH)
+    run = jnp.cumsum(lg, axis=1)                         # G_i - G_0
+    last = run[:, -1:]
+    out = jnp.where(valid.reshape(n, C, 1), jnp.exp(last - run), 0.0)
+    vo = (v.astype(f32).reshape(n, C, KVH, d)
+          * out[..., None]).transpose(2, 0, 3, 1).astype(low)  # (KVH, n, d, C)
+    W = max(C, d)
+    gate = jnp.zeros((KVH, n, 8, W), f32)
+    gate = gate.at[:, :, 0, :C].set(run.transpose(2, 0, 1))
+    gate = gate.at[:, :, 1, :].set(jnp.exp(last[:, 0].T)[..., None])
+    gate = gate.at[:, :, 2, :C].set(out.transpose(2, 0, 1))
+    meta = jnp.asarray(length, jnp.int32).reshape(1)
+
+    def seen(i, meta):  # a chunk past the prompt keeps the last live one's
+        return jnp.minimum(i, jnp.maximum(meta[0] - 1, 0) // C)
+
+    sq = pl.squeezed
+    rows_spec = pl.BlockSpec((C, G * d), lambda h, i, meta: (seen(i, meta), h))
+    kv_spec = pl.BlockSpec((C, d), lambda h, i, meta: (seen(i, meta), h))
+    s_spec = pl.BlockSpec((sq, d, P), lambda h, i, meta: (h, 0, 0))
+    z_spec = pl.BlockSpec((sq, R, d), lambda h, i, meta: (h, 0, 0))
+    block = d * P * 4
+    o, S, z = pl.pallas_call(
+        functools.partial(_prefill_kernel, groups=G, chunk=C, d=d),
+        out_shape=(jax.ShapeDtypeStruct((n * C, H * d), f32),
+                   jax.ShapeDtypeStruct((KVH, d, P), f32),
+                   jax.ShapeDtypeStruct((KVH, R, d), f32)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(KVH, n),
+            in_specs=[
+                rows_spec, kv_spec, kv_spec,
+                pl.BlockSpec((sq, sq, d, C),
+                             lambda h, i, meta: (h, seen(i, meta), 0, 0)),
+                pl.BlockSpec((sq, sq, 8, W),
+                             lambda h, i, meta: (h, seen(i, meta), 0, 0)),
+                s_spec, z_spec],
+            out_specs=[
+                pl.BlockSpec((C, G * d), lambda h, i, meta: (i, h)),
+                s_spec, z_spec],
+            scratch_shapes=[pltpu.VMEM((G * C, d), f32) for _ in range(4)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            # the state's block in and out, each double-buffered, and
+            # the chunk's rows and accumulators beside them
+            vmem_limit_bytes=int(4 * block + (32 << 20))),
+        interpret=interpret,
+        name="mxtpu_retention_prefill",
+    )(meta, q.reshape(n * C, H * d), k.reshape(n * C, KVH * d),
+      v.reshape(n * C, KVH * d), vo, gate,
+      *(a.astype(f32) for a in state))
+    return o.reshape(n * C, H, d)[:T], (S, z)
+
+
+def power_retention_chunked(q, k, v, log_g, state, length, chunk=256):
+    """One sequence through one layer. ``q`` ``(T, H, d)``, ``k`` and
+    ``v`` ``(T, KVH, d)``, ``log_g`` ``(T, KVH)`` (the log of the gate,
+    at or under 0), ``state = (S, z)`` entering, ``(KVH, d, P)`` and
+    ``(KVH, R, d)`` float32. Returns ``(o (T, H, d) float32, state)``
+    where the state is the one after ``length`` tokens: positions at or
+    beyond ``length`` neither decay it nor add to it, and their outputs
+    are zero.
+
+    The matrix products take their operands in the activations' own
+    width, as every other product of the net does: under a bfloat16 net
+    ``phi(q)`` and the state it meets are rounded to bfloat16 on their
+    way into the matrix unit (accumulated in float32; the stored state
+    stays float32), under a float32 net they are float32 at ``highest``
+    precision.
+
+    On a TPU, for bfloat16 or float16 operands, heads of whole 128-lane
+    rows and chunks of them, the chunked form is the Pallas kernel
+    ``mxtpu_retention_prefill``: ``phi(q)`` and ``phi(k)`` are made in
+    VMEM a row at a time and the state stays there across the chunks.
+    Elsewhere, and for float32 operands, it is :func:`_jnp_chunked`
+    (chosen by what the operands and the backend are, as
+    :func:`power_retention_step` chooses)."""
+    half = q.dtype in (jnp.bfloat16, jnp.float16)
+    if half and chunk % 128 == 0 and _use_pallas(q.shape[-1]):
+        return _pallas_chunked(q, k, v, log_g, state, length, chunk)
+    return _jnp_chunked(q, k, v, log_g, state, length, chunk)
 
 
 # ---------------------------------------------------------------------------

@@ -195,6 +195,52 @@ def test_retention_decode_compiles_for_v5e(on_chip, B, H, KVH, D, L):
     assert exe.memory_analysis().temp_size_in_bytes < D * 65 * D * 4
 
 
+def _largest_array_bytes(hlo_text):
+    """The bytes of the largest array any instruction of a compiled
+    module's HLO text yields (a tuple's parts are their own lines)."""
+    import re
+
+    width = {"f32": 4, "s32": 4, "u32": 4, "bf16": 2, "f16": 2, "s8": 1,
+             "u8": 1, "pred": 1}
+    sizes = [math.prod(int(n) for n in dims.split(",") if n) * width[dtype]
+             for dtype, dims in re.findall(
+                 r"^\s*(?:ROOT )?%\S+ = (\w+)\[([\d,]*)\]", hlo_text,
+                 re.MULTILINE) if dtype in width]
+    return max(sizes)
+
+
+def test_retention_prefill_compiles_for_v5e(on_chip, monkeypatch):
+    """Brumby's layer at its published widths (40 query heads over 8 KV
+    heads of 128, chunks of 256), one sequence in an 8,192-token bucket:
+    the chunked form is one Mosaic call, and no array of the program is
+    as large as one chunk's ``phi(q)`` (``C x H x P`` bfloat16, 170 MB),
+    which the jnp form's program holds."""
+    from mxnet_tpu.ops import retention as R
+
+    T, H, KVH, D, C = 8192, 40, 8, 128, 256
+    args = (on_chip((T, H, D), jnp.bfloat16),
+            on_chip((T, KVH, D), jnp.bfloat16),
+            on_chip((T, KVH, D), jnp.bfloat16),
+            on_chip((T, KVH), jnp.float32),
+            (on_chip((KVH, D, R.feature_dim(D)), jnp.float32),
+             on_chip((KVH, R.feature_rows(D), D), jnp.float32)),
+            on_chip((), jnp.int32))
+    phi_q = C * H * R.feature_dim(D) * 2
+
+    def chunked(*a):  # a new function a path: jit caches what it traced
+        return R.power_retention_chunked(*a, chunk=C)
+
+    monkeypatch.setattr(R, "_use_pallas", lambda d: True)  # no TPU here
+    hlo = jax.jit(chunked).lower(*args).compile().as_text()
+    calls = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 1
+    assert calls[0].lstrip().startswith("%mxtpu_retention_prefill")
+    assert _largest_array_bytes(hlo) < phi_q
+    monkeypatch.setattr(R, "_use_pallas", lambda d: False)
+    plain = jax.jit(lambda *a: chunked(*a)).lower(*args).compile().as_text()
+    assert _largest_array_bytes(plain) >= phi_q
+
+
 def _brumby_net(on_chip, layers, vocab):
     """Brumby-14B's layer at its published widths, ``layers`` of them
     under the scan; 660 MB a layer: shapes only, held as the benchmark's
@@ -232,8 +278,8 @@ def test_retention_decoder_works_on_the_states_in_place_on_v5e(
     """The Brumby layer at its published widths (two layers under the
     scan, a small vocabulary), as the generation engine compiles it:
     with the states donated the executable's temporaries stay a small
-    share of the state array, and the decode step's one kernel is the
-    retention kernel."""
+    share of the state array, and each program's one kernel is its
+    retention kernel (the decode step's, or the chunked prefill's)."""
     from mxnet_tpu.ops import retention as R
 
     monkeypatch.setattr(R, "_use_pallas", lambda d: True)  # no TPU here
@@ -245,19 +291,17 @@ def test_retention_decoder_works_on_the_states_in_place_on_v5e(
         args = (params, on_chip((slots,), jnp.int32),
                 on_chip((slots,), jnp.int32), *state,
                 on_chip((slots,), jnp.int32), on_chip((slots,), bool))
-        donate, kernels, share = (3, 4), 1, 0.25
+        donate = (3, 4)
     else:
         args = (params, on_chip((1, bucket), jnp.int32), *state,
                 on_chip((1,), jnp.int32), on_chip((1,), jnp.int32))
-        # a chunk's phi(q) alone is 340 MB beside two layers' 1.16 GB of
-        # states: the bound is a copy of them, not a share
-        donate, kernels, share = (2, 3), 0, 1.0
+        donate = (2, 3)
     exe = jax.jit(getattr(net, which)(),
                   donate_argnums=donate).lower(*args).compile()
+    # prefill's phi(q) is made in the kernel's VMEM, a row at a time
     assert exe.memory_analysis().temp_size_in_bytes \
-        < share * 4 * math.prod(s_shape)
-    assert exe.as_text().count(
-        'custom_call_target="tpu_custom_call"') == kernels
+        < 0.25 * 4 * math.prod(s_shape)
+    assert exe.as_text().count('custom_call_target="tpu_custom_call"') == 1
 
 
 # the serve cells' engines: vocabulary, slots, a prefill bucket (the

@@ -175,6 +175,80 @@ def test_a_step_with_no_live_slot_changes_no_state(seq):
     assert (np.asarray(z1)[:, :2] == np.asarray(z)[:, :2]).all()
 
 
+# the prefill kernel in the interpreter, at a whole 128-lane head, small
+# chunks and two KV heads of two queries each: (bucket, length, chunk,
+# entering state)
+_PREFILL = {
+    "whole_chunks": (64, 64, 16, False),
+    "partial_last_chunk": (64, 50, 16, False),
+    "shorter_than_a_chunk": (64, 10, 16, False),
+    "wholly_padded_chunks": (96, 30, 16, False),
+    "entering_state": (64, 57, 32, True),
+}
+
+
+def _prefill_operands(T, entering, seed):
+    rng = np.random.RandomState(seed)
+    d = 128
+    q = jnp.asarray(rng.randn(T, H, d) * 0.3, jnp.bfloat16)
+    k = jnp.asarray(rng.randn(T, KVH, d) * 0.3, jnp.bfloat16)
+    v = jnp.asarray(rng.randn(T, KVH, d), jnp.bfloat16)
+    log_g = jnp.asarray(-np.abs(rng.randn(T, KVH)) * 0.05, jnp.float32)
+    s_shape, z_shape = (KVH, d, R.feature_dim(d)), (KVH, R.feature_rows(d), d)
+    if entering:
+        state = (jnp.asarray(rng.randn(*s_shape) * 0.1, jnp.float32),
+                 jnp.asarray(np.abs(rng.randn(*z_shape)) + 1.0, jnp.float32))
+    else:
+        state = (jnp.zeros(s_shape), jnp.zeros(z_shape))
+    return q, k, v, log_g, state
+
+
+@pytest.mark.parametrize("case", sorted(_PREFILL))
+def test_the_prefill_kernel_is_the_jnp_form(case):
+    """``mxtpu_retention_prefill`` in the Pallas interpreter against the
+    jnp form on the same bfloat16 operands: outputs (zero at and past
+    ``length``) and the leaving state. The kernel rounds the update's
+    operands and the chunk's attention weights to bfloat16 on their way
+    into the matrix unit, where the jnp form on the CPU keeps them in
+    float32 (on the chip both round: ``tests_tpu``), so ``o`` and ``S``
+    agree to a hundredth of their scale (measured: 1.2e-3..4.2e-3); the
+    normaliser's update is float32 in both."""
+    T, length, chunk, entering = _PREFILL[case]
+    args = _prefill_operands(T, entering, T + length)
+    o, (S, z) = R._pallas_chunked(*args, length, chunk, interpret=True)
+    want_o, (want_S, want_z) = R._jnp_chunked(*args, length, chunk)
+    assert o.shape == want_o.shape and S.dtype == z.dtype == jnp.float32
+    assert not np.asarray(o)[length:].any()
+    for got, want, tol in ((o, want_o, 1e-2), (S, want_S, 1e-2),
+                           (z, want_z, 1e-5)):
+        got, want = np.asarray(got), np.asarray(want)
+        scale = np.abs(want).max()
+        np.testing.assert_allclose(got, want, rtol=0, atol=tol * scale)
+
+
+@pytest.mark.parametrize("dtype,path", [("float32", "jnp"),
+                                        ("bfloat16", "kernel")])
+def test_the_chunked_form_takes_its_path_from_the_operands(
+        monkeypatch, dtype, path):
+    """Where the kernel could run (a backend that is not the CPU, heads
+    of 128 lanes, chunks of them), bfloat16 operands take it and float32
+    operands keep the jnp form, in float32 at ``highest``."""
+    monkeypatch.setattr(R, "_use_pallas", lambda d: True)
+    taken = []
+
+    def kernel(*args):
+        taken.append(args[0].dtype)
+        return R._jnp_chunked(*args)
+
+    monkeypatch.setattr(R, "_pallas_chunked", kernel)
+    q, k, v, log_g, state = _prefill_operands(40, False, 3)
+    q, k, v = (a.astype(dtype) for a in (q, k, v))
+    o, _ = R.power_retention_chunked(q, k, v, log_g, state, 33, 128)
+    assert taken == ([jnp.dtype(dtype)] if path == "kernel" else [])
+    want, _ = R._jnp_chunked(q, k, v, log_g, state, 33, 128)
+    assert (np.asarray(o) == np.asarray(want)).all()
+
+
 # ---------------------------------------------------------------------------
 # the decoder's retention layers against the plain reference
 # ---------------------------------------------------------------------------
