@@ -3,7 +3,9 @@
 Part 1 drives a :class:`~mxnet_tpu.serving.GenerationEngine` directly:
 a paged KV cache, per-prompt-bucket sealed prefill executables, and a
 single-dispatch chunk-of-T decode loop with on-device sampling. It
-prints per-token latency and the engine's SLO counters — note
+prints per-token latency, where that time went (``stats()["pace"]``:
+decode chunks, other prompts' prefills, the scheduler's own turn) and
+the engine's SLO counters — note
 ``tokens/dispatch`` (several tokens ride each XLA dispatch) and
 ``recompiles_after_warmup == 0`` under ragged traffic.
 
@@ -64,9 +66,15 @@ def main():
         print(f"  SLO: {st['tokens_generated']} tokens in "
               f"{st['dispatches']} dispatches "
               f"({st['tokens_per_dispatch']:.1f} tok/dispatch), "
-              f"itl p50 {st['itl_p50_ms']:.2f} ms / "
-              f"p99 {st['itl_p99_ms']:.2f} ms, "
               f"recompiles_after_warmup={st['recompiles_after_warmup']}")
+        # where the time between a request's tokens went, per token
+        pace = st["pace"]
+        ms = 1e3 / max(1, pace["intervals"])
+        host = pace["decode_s"] - pace["device_s"] - pace["stall_s"]
+        print(f"  pace {ms * pace['decode_s']:.2f} ms/tok = chunks "
+              f"{ms * pace['device_s']:.2f} + other prompts' prefills "
+              f"{ms * pace['stall_s']:.2f} + scheduler's turn "
+              f"{ms * host:.2f}")
         print(f"  cache: {st['cache']['blocks_used']} blocks still held "
               f"(freed on retirement), {st['cache']['forks']} forks")
     finally:

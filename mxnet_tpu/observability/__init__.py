@@ -678,9 +678,9 @@ DECODE_CHUNKS_TOTAL = _REGISTRY.counter(
     "by model")
 DECODE_ITL_SECONDS = _REGISTRY.histogram(
     "mxtpu_decode_inter_token_seconds",
-    "amortized inter-token latency: decode-chunk wall time / tokens the "
-    "slot emitted in that chunk (tokens of one chunk arrive together), "
-    "by model — p50/p99 are stats()['itl_p50_ms'/'itl_p99_ms']",
+    "a finished request's pace, observed once a request: (last token's "
+    "stamp - first token's) / tokens after the first, by model — what "
+    "the client sees; stats()['pace'] holds where it went",
     buckets=(0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
              0.025, 0.05, 0.1, 0.25))
 DECODE_PREFILL_SECONDS = _REGISTRY.histogram(

@@ -15,8 +15,11 @@ Timestamps are epoch microseconds (``time.time_ns()``): the clock the
 ``jax.profiler`` stamps its host events with, up to one constant per
 profiler session, so a ring event can be laid beside a device operation
 of the same session's ``.xplane.pb``. Callers that hand ``record()`` a
-``perf_counter`` time are mapped through one ``(perf_counter,
-time_ns)`` pair taken when the tracer is built.
+``perf_counter`` time are mapped through a ``(perf_counter, time_ns)``
+pair read at that call, so a stepped wall clock moves them with the
+:class:`Span` events beside them. ``tid`` is the thread's native id
+(``threading.get_native_id()``), unique among the process's live
+threads.
 
 A :class:`Span` is the program's one span: on entry it opens a
 ``jax.profiler.TraceAnnotation("mx:" + name)`` (a no-op costing half a
@@ -117,8 +120,6 @@ class Tracer:
     def __init__(self, capacity=None):
         self._events = collections.deque(
             maxlen=capacity or _default_capacity())
-        # one pair maps a caller's perf_counter seconds onto epoch us
-        self._pc0, self._ns0 = time.perf_counter(), time.time_ns()
         self.step = 0  # advanced by Trainer.step via mark_step()
         self._local = threading.local()
         # span ids: process-unique, monotonic, survive clear() — parent
@@ -156,7 +157,7 @@ class Tracer:
             "ts": ts_us,
             "dur": dur_us,
             "pid": os.getpid(),
-            "tid": threading.get_ident() & 0xFFFF,
+            "tid": threading.get_native_id(),
             "args": args,
         }
         self._events.append(ev)
@@ -168,13 +169,13 @@ class Tracer:
         (``ts=None`` means now). Every event carries a unique ``id``
         (pass ``span_id`` to stamp one minted earlier, e.g. before
         handing it to children as their parent)."""
-        if ts is None:
-            ts = time.perf_counter()
+        ts_us = time.time_ns() / 1e3
+        if ts is not None:
+            ts_us += (ts - time.perf_counter()) * 1e6
         return self._append(
             name, cat, ph,
             int(span_id) if span_id is not None else self.new_span_id(),
-            (ts - self._pc0) * 1e6 + self._ns0 / 1e3, dur * 1e6,
-            dict(args or ()))
+            ts_us, dur * 1e6, dict(args or ()))
 
     def instant(self, name, cat="default", **args):
         return self.record(name, cat=cat, dur=0.0, args=args, ph="i")

@@ -172,9 +172,9 @@ def sample_tokens(logits, key, temperature, top_k, top_p, greedy,
 class _GenRequest:
     __slots__ = ("prompt", "max_new", "temperature", "top_k", "top_p",
                  "greedy", "seed", "eos", "deadline", "rid", "t_submit",
-                 "t_admit", "tokens", "t_first", "t_last", "event", "result",
-                 "error", "version", "claimed", "cancelled",
-                 "_state_lock")
+                 "t_admit", "tokens", "t_first", "t_last", "pace0",
+                 "device_s", "stall_s", "event", "result", "error",
+                 "version", "claimed", "cancelled", "_state_lock")
 
     def __init__(self, prompt, max_new, temperature, top_k, top_p,
                  greedy, seed, eos, deadline):
@@ -194,6 +194,12 @@ class _GenRequest:
         self.tokens = []
         self.t_first = None
         self.t_last = None
+        # where its pace went (``GenerationEngine._account``): the
+        # engine's two sums as its prefill ended, then its chunks' wall
+        # and the other requests' prefills until its last token
+        self.pace0 = None
+        self.device_s = 0.0
+        self.stall_s = 0.0
         self.event = threading.Event()
         self.result = None
         self.error = None
@@ -452,7 +458,6 @@ class GenerationEngine:
         self._idle = threading.Event()
         self._idle.set()
         # engine-local SLO state (real numbers with telemetry off)
-        self._itl = collections.deque(maxlen=8192)
         self._tokens = 0
         self._chunks = 0
         self._filtered_chunks = 0  # chunks with a live slot that draws
@@ -472,7 +477,16 @@ class GenerationEngine:
         self._compiles = 0
         self._pool_temp_share = 0.0
         self._pool_temp_worst = None
-        self._decode_wall = 0.0
+        # where a live request's pace goes, summed on the clock of its
+        # token stamps: every chunk's wall from staging to fetch, every
+        # prefill's from entering ``_prefill`` to leaving it; both as
+        # they stood at the last chunk's delivery; and over the requests
+        # finished so far [decode_s, device_s, stall_s, intervals]
+        # (``_account``)
+        self._chunk_wait_s = 0.0
+        self._prefill_s = 0.0
+        self._delivered = (0.0, 0.0)
+        self._pace = (0.0, 0.0, 0.0, 0)
         self._sealed = False
         # slot state (scheduler-thread-private after start): the chunk's
         # packed operand, kept on the host as the scheduler's truth. Its
@@ -707,6 +721,7 @@ class GenerationEngine:
         self._failed += 1
         if _obs.ENABLED:
             _obs.record_serve_request(self._name, code)
+        self._account(req)
         self._trace_request(req, code)
         req.finish(error=err, version=self._version)
 
@@ -717,7 +732,9 @@ class GenerationEngine:
         not to the profiler. ``req`` is submit -> its last token's
         stamp (now, for one that failed), ``req.queue`` submit ->
         admitted, ``req.prefill`` -> first token, ``req.decode`` -> last
-        token; all four under one ``rid``."""
+        token; all four under one ``rid``. ``req.decode`` carries the
+        split of its pace (``_account``): ``tokens`` after the first,
+        ``device_us`` and ``stall_us``."""
         if not _obs.watching():
             return
         ring = _obs.tracer()
@@ -733,8 +750,36 @@ class GenerationEngine:
             phases += [("req.prefill", req.t_admit, req.t_first),
                        ("req.decode", req.t_first, req.t_last)]
         for name, t0, t1 in phases:
-            ring.record(name, cat="request", ts=t0, dur=t1 - t0,
-                        args={"rid": rid, "parent": rid})
+            args = {"rid": rid, "parent": rid}
+            if name == "req.decode":
+                args.update(tokens=len(req.tokens) - 1,
+                            device_us=req.device_s * 1e6,
+                            stall_us=req.stall_s * 1e6)
+            ring.record(name, cat="request", ts=t0, dur=t1 - t0, args=args)
+
+    def _account(self, req):
+        """Splits the pace of a request that is leaving, ``t_last -
+        t_first``: ``device_s``, the wall of the chunks it was live in
+        (a live slot is in every chunk, so the difference of the sum
+        is exact), ``stall_s``, the other requests' prefills between
+        its first and last token; the rest is the scheduler's host
+        turn. The sums are read as they stood at the last chunk's
+        delivery, its last token's stamp: a request failed after it
+        takes none of what ran since."""
+        n = len(req.tokens) - 1
+        if req.pace0 is None or n < 1:
+            return
+        chunks, prefills = self._delivered
+        req.device_s = chunks - req.pace0[0]
+        req.stall_s = prefills - req.pace0[1]
+        decode_s = req.t_last - req.t_first
+        decode, device, stall, intervals = self._pace
+        # one new tuple: ``stats()`` on another thread reads all four
+        # of one moment
+        self._pace = (decode + decode_s, device + req.device_s,
+                      stall + req.stall_s, intervals + n)
+        if _obs.ENABLED:
+            _obs.DECODE_ITL_SECONDS.observe(decode_s / n, model=self._name)
 
     def _admit(self):
         """Join queued requests to idle slots (iteration-level
@@ -795,11 +840,17 @@ class GenerationEngine:
                            ServingError(f"prefill failed: {e}"), "error")
 
     def _prefill(self, req, seq, slot):
+        t_in = time.perf_counter()
         plen = len(req.prompt)
         tb = self._bucket_for(plen)
-        with _obs.span("gen.prefill", cat="generation", rid=req.rid,
-                       bucket=tb, prompt_len=plen, slot=slot):
-            self._prefill_traced(req, seq, slot, plen, tb)
+        try:
+            with _obs.span("gen.prefill", cat="generation", rid=req.rid,
+                           bucket=tb, prompt_len=plen, slot=slot):
+                self._prefill_traced(req, seq, slot, plen, tb)
+        finally:
+            # failed or not, every request decoding waited through it
+            self._prefill_s += time.perf_counter() - t_in
+            req.pace0 = (self._chunk_wait_s, self._prefill_s)
 
     def _prefill_row(self, seq, req=None):
         """A prefill's packed operand, one row: the request's scalars,
@@ -952,9 +1003,10 @@ class GenerationEngine:
         """Hands each of the ``live`` slots its tokens of a chunk that
         took ``dt`` seconds and retires what finished; ``(tokens
         emitted, requests retired)``."""
-        self._decode_wall += dt
+        self._chunk_wait_s += dt
         self._chunks += 1
-        now = time.perf_counter()
+        now = time.perf_counter()  # the stamp of every token it delivers
+        self._delivered = (self._chunk_wait_s, self._prefill_s)
         emitted_total = retired = 0
         for s in live.tolist():
             req = self._slot_req[s]
@@ -963,16 +1015,7 @@ class GenerationEngine:
             n = len(new)
             if n:
                 req.tokens.extend(new)
-                # tokens of one chunk arrive together: the honest
-                # inter-token latency is the amortized chunk wall time
-                per_tok = dt / n
-                if req.t_first is None:
-                    req.t_first = now
                 req.t_last = now
-                self._itl.extend([per_tok] * n)
-                if _obs.ENABLED:
-                    _obs.DECODE_ITL_SECONDS.observe(per_tok,
-                                                    model=self._name)
                 emitted_total += n
             if not self._active[s]:
                 seq = self._slot_seqs[s]
@@ -1006,6 +1049,7 @@ class GenerationEngine:
             _obs.record_serve_request(self._name, "ok")
             _obs.SERVE_LATENCY_SECONDS.observe(
                 time.perf_counter() - req.t_submit, model=self._name)
+        self._account(req)
         self._trace_request(req, "ok")
         req.finish(result=_np.asarray(req.tokens, _np.int32),
                    version=self._version)
@@ -1050,7 +1094,6 @@ class GenerationEngine:
         """Engine-local snapshot (plain floats; telemetry-independent).
         ``retraces_after_warmup`` is structurally 0: every executable is
         AOT-sealed and slot liveness is an operand, never a shape."""
-        itl = _np.asarray(self._itl, _np.float64) if self._itl else None
         dispatches = self._chunks + self._prefills
         return {
             "model": self._name,
@@ -1072,12 +1115,12 @@ class GenerationEngine:
             "host_transfers": {"uploads": self._uploads,
                                "fetches": self._fetches},
             "tokens_per_dispatch": self._tokens / max(1, dispatches),
-            "tokens_per_s": (self._tokens / self._decode_wall
-                             if self._decode_wall else 0.0),
-            "itl_p50_ms": (float(_np.percentile(itl, 50)) * 1e3
-                           if itl is not None else None),
-            "itl_p99_ms": (float(_np.percentile(itl, 99)) * 1e3
-                           if itl is not None else None),
+            # the finished requests' pace, t_last - t_first summed, and
+            # how much of it was chunks they were live in and other
+            # requests' prefills (the rest: the scheduler's host turn);
+            # over ``intervals``, their tokens after the first
+            "pace": dict(zip(("decode_s", "device_s", "stall_s",
+                              "intervals"), self._pace)),
             "queue_depth": self.queue_depth(),
             "active_slots": self.active_slots(),
             "compiles": self._compiles,

@@ -605,11 +605,9 @@ def test_local_replica_serves_decoder_spec():
 def traced(eng, tmp_path_factory):
     """One profiler session over the module's engine serving a dozen
     requests with telemetry off: the ring's events of that session, the
-    requests' answers and the cache's counters. It is the module's one
-    long-lived engine on purpose: the ring keeps 16 bits of a thread's
-    ident, of which glibc's stack addresses vary only four, so a second
-    engine idling on a thread of its own would, in up to one process of
-    sixteen, put its ``gen.admit`` and ``gen.idle`` among these."""
+    requests' answers and the cache's counters. The ring names a thread
+    by its native id, so another engine idling on a thread of its own
+    puts none of its ``gen.admit`` and ``gen.idle`` among these."""
     import jax
 
     obs.set_enabled(False)
@@ -637,7 +635,7 @@ def traced(eng, tmp_path_factory):
             jax.profiler.stop_trace()
         cap["stats"] = eng.cache.stats()
         # what another thread of the process recorded is not this engine's
-        tid = eng._thread.ident & 0xFFFF
+        tid = eng._thread.native_id
         cap["ring"] = [ev for ev in obs.tracer().events()
                        if ev["cat"] != "generation" or ev["tid"] == tid]
     finally:
@@ -723,6 +721,38 @@ def test_request_phases_share_a_rid_and_sum_to_the_request(traced):
         pf = prefills[rid]
         assert pf["args"]["prompt_len"] == ev["args"]["prompt_len"]
         assert parts["req.prefill"]["ts"] <= pf["ts"] + 1000.0
+
+
+def test_a_requests_decode_carries_the_split_of_its_pace(traced):
+    """``req.decode`` carries its tokens after the first, the wall of
+    the chunks it was live in and that of the other requests' prefills
+    between its first and its last token: no less than the scheduler's
+    spans of those chunks' device waits and of those prefills inside
+    its interval, and together no more than the interval."""
+    reqs = _cat(traced, "request")
+    tokens = {ev["args"]["rid"]: ev["args"]["tokens"] for ev in reqs
+              if ev["name"] == "req"}
+    gen = _cat(traced, "generation")
+    decodes = [ev for ev in reqs if ev["name"] == "req.decode"]
+    assert len(decodes) == 12
+    stalled = 0
+    for ev in decodes:
+        a, rid = ev["args"], ev["args"]["rid"]
+        lo, hi = ev["ts"], ev["ts"] + ev["dur"]
+
+        def inside(name):
+            return [g["dur"] for g in gen if g["name"] == name
+                    and g["args"].get("rid") != rid
+                    and lo <= g["ts"] and g["ts"] + g["dur"] <= hi]
+
+        assert a["tokens"] == tokens[rid] - 1 >= 1
+        assert 0 < a["device_us"] and 0 <= a["stall_us"]
+        assert a["device_us"] + a["stall_us"] <= ev["dur"] + 1.0
+        waits, prefills = inside("gen.chunk.device"), inside("gen.prefill")
+        assert waits and sum(waits) <= a["device_us"] + len(waits)
+        assert sum(prefills) <= a["stall_us"] + len(prefills)
+        stalled += bool(prefills)
+    assert stalled  # a dozen requests through four slots: some waited
 
 
 def test_every_chunk_carries_the_pools_blocks_in_use(traced):
@@ -988,5 +1018,141 @@ def test_a_request_shed_for_want_of_blocks_leaves_a_null_row(kind):
         _assert_matches_dense(net, a, fa.result(0))
         assert eng.cache.blocks_used() == 0
         assert eng.stats()["failed"] == 1
+    finally:
+        eng.close()
+
+
+# ---------------------------------------------------------------------------
+# where a request's pace goes: the chunks it was live in, the other
+# requests' prefills between its tokens, and the scheduler's host turn
+# ---------------------------------------------------------------------------
+
+def _host_s(req):
+    """What ``_account`` leaves to the scheduler's turn."""
+    return (req.t_last - req.t_first) - req.device_s - req.stall_s
+
+
+def _burst(eng, seed):
+    """A dozen ragged requests, one of a single token, through the
+    module's four slots; their requests once all have finished."""
+    rng = np.random.RandomState(seed)
+    futs = [eng.submit(rng.randint(1, VOCAB, rng.randint(2, 15))
+                       .astype(np.int32),
+                       max_new_tokens=1 if i == 5 else 3 + 4 * (i % 4))
+            for i in range(12)]
+    for f in futs:
+        f.result(120.0)
+    return [f._req for f in futs]
+
+
+def test_a_requests_pace_is_its_chunks_its_stalls_and_the_host(eng):
+    for req in _burst(eng, 21):
+        n = len(req.tokens) - 1
+        if n == 0:  # one token, no pace to split
+            assert req.device_s == req.stall_s == 0.0
+            continue
+        decode_s = req.t_last - req.t_first
+        assert 0 < req.device_s < decode_s
+        assert 0 <= req.stall_s < decode_s
+        # the parts never overlap: the host's turn is what is left
+        assert _host_s(req) >= -1e-6
+        assert req.device_s + req.stall_s + _host_s(req) == pytest.approx(
+            decode_s, abs=1e-6)
+
+
+def test_stats_pace_sums_the_finished_requests(eng):
+    before = dict(eng.stats()["pace"])
+    reqs = _burst(eng, 22)
+    after = eng.stats()["pace"]
+    assert set(after) == {"decode_s", "device_s", "stall_s", "intervals"}
+    assert after["intervals"] - before["intervals"] \
+        == sum(len(r.tokens) - 1 for r in reqs)
+    for key, part in (("decode_s", lambda r: r.t_last - r.t_first),
+                      ("device_s", lambda r: r.device_s),
+                      ("stall_s", lambda r: r.stall_s)):
+        assert after[key] - before[key] == pytest.approx(
+            sum(part(r) for r in reqs), abs=1e-9)
+    assert after["stall_s"] > before["stall_s"]  # some waited on prefills
+    # a chunk's wall over its tokens is no request's pace: not reported
+    assert not [k for k in eng.stats()
+                if k == "tokens_per_s" or k.startswith("itl_")]
+
+
+def test_a_request_decoding_alone_never_stalls(net):
+    eng = _family_engine(net, "pace-alone", autostart=False)
+    try:
+        fut = eng.submit(np.array([3, 1, 4, 1, 5], np.int32),
+                         max_new_tokens=13, greedy=True)
+        eng._admit()
+        while eng._active.any():
+            eng._step_chunk()
+            eng._admit()
+        req = fut._req
+        assert len(fut.result(0)) == 13
+        assert req.stall_s == 0.0
+        assert 0 < req.device_s <= req.t_last - req.t_first
+        assert eng.stats()["pace"]["intervals"] == 12
+    finally:
+        eng.close()
+
+
+def test_a_prefill_between_its_tokens_is_a_requests_stall(net):
+    """Driven turn by turn with telemetry on: the second request's
+    prefill runs while the first is decoding, and the first's stall is
+    at least that prefill's ``gen.prefill`` span; the second, which
+    decoded beside no newcomer, never stalled."""
+    obs.set_enabled(True)
+    eng = _family_engine(net, "pace-stall", autostart=False)
+    try:
+        first = eng.submit(np.array([2, 7, 1, 8], np.int32),
+                           max_new_tokens=14, greedy=True)
+        eng._admit()
+        eng._step_chunk()
+        second = eng.submit(np.array([9, 9, 3], np.int32),
+                            max_new_tokens=6, greedy=True)
+        eng._admit()
+        while eng._active.any():
+            eng._step_chunk()
+        a, b = first._req, second._req
+        assert first.done() and second.done()
+        (span,) = [ev for ev in obs.tracer().events()
+                   if ev["name"] == "gen.prefill"
+                   and ev["args"]["rid"] == b.rid]
+        assert a.stall_s * 1e6 >= span["dur"] > 0
+        assert b.stall_s == 0.0
+        assert _host_s(a) >= -1e-6 and _host_s(b) >= -1e-6
+    finally:
+        eng.close()
+
+
+def test_a_request_shed_mid_decode_takes_no_later_prefill(net):
+    """Three usable blocks: the first request decodes to five tokens,
+    a second's prefill takes the last free block, and the first's next
+    growth is shed (typed OOM). Its pace ends at its last token, before
+    that prefill: it stalled on nothing and its parts still add up."""
+    from mxnet_tpu.serving import KVCacheOOM
+
+    eng = _family_engine(net, "pace-shed", autostart=False, cache_blocks=4)
+    try:
+        first = eng.submit(np.array([3, 1, 4, 1], np.int32),
+                           max_new_tokens=8, greedy=True)
+        eng._admit()
+        eng._step_chunk()
+        second = eng.submit(np.array([2, 7], np.int32), max_new_tokens=3,
+                            greedy=True)
+        eng._admit()
+        assert eng.cache.blocks_used() == 3
+        eng._step_chunk()
+        with pytest.raises(KVCacheOOM):
+            first.result(0)
+        a = first._req
+        assert len(a.tokens) == 5
+        assert a.stall_s == 0.0 and a.device_s > 0
+        assert _host_s(a) >= -1e-6
+        while eng._active.any():
+            eng._step_chunk()
+        assert len(second.result(0)) == 3
+        st = eng.stats()["pace"]
+        assert st["intervals"] == 4 + 2  # the shed request's count too
     finally:
         eng.close()

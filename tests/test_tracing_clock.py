@@ -8,6 +8,7 @@ seconds); what it captured is asserted test by test.
 
 import glob
 import os
+import threading
 import time
 
 import jax
@@ -121,6 +122,46 @@ def test_a_perf_counter_caller_lands_on_the_same_clock(session):
     (beside,) = _ring(session, "clock.beside")
     assert abs(hand["ts"] - (beside["ts"] + beside["dur"])) < 1000.0
     assert abs(hand["ts"] - time.time_ns() / 1e3) < 600e6  # epoch us
+
+
+def test_a_perf_counter_caller_follows_a_stepped_wall_clock(monkeypatch):
+    """A long-lived process whose wall clock steps (an NTP correction)
+    after the tracer was built: an event handed a perf_counter time
+    lands where ``time_ns`` now says, as a :class:`Span` beside it
+    would, and not where the clock stood when the tracer was built."""
+    tr = obs.Tracer(capacity=8)
+    real = time.time_ns
+    step_ns = 3600 * 10**9
+    monkeypatch.setattr(time, "time_ns", lambda: real() + step_ns)
+    tr.record("clock.stepped", cat="test", ts=time.perf_counter())
+    (ev,) = tr.events()
+    assert abs(ev["ts"] - time.time_ns() / 1e3) < 1e5  # within 0.1 s
+    assert ev["ts"] - real() / 1e3 > step_ns / 1e3 - 1e5
+
+
+def test_each_thread_is_named_by_its_native_id():
+    """Two threads alive at once record under distinct ids, each the
+    native id of the thread that recorded (no 16-bit fold to collide
+    on), as does the caller's own thread."""
+    tr = obs.Tracer(capacity=8)
+    both = threading.Barrier(2)
+    ids = {}
+
+    def record(tag):
+        both.wait()  # alive together: the OS cannot reuse an id
+        ids[tag] = threading.get_native_id()
+        tr.record("clock." + tag, cat="test")
+        both.wait()
+
+    threads = [threading.Thread(target=record, args=(t,)) for t in "ab"]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(10.0)
+    tr.record("clock.main", cat="test")
+    ids["main"] = threading.get_native_id()
+    got = {ev["name"][len("clock."):]: ev["tid"] for ev in tr.events()}
+    assert got == ids and len(set(ids.values())) == 3
 
 
 def test_telemetry_alone_keeps_the_ring_on_the_epoch_clock():
