@@ -704,7 +704,7 @@ def _paged_group_blocks(block_size, width, itemsize, max_blocks):
 
 
 def _paged_decode_kernel(layer_ref, tables_ref, lens_ref, *refs, scale, bs,
-                         gb, latent=False):
+                         gb, latent=False, append=False):
     """One invocation walks the LIVE blocks of every sequence, ``gb`` of
     them a compute step. The pools stay in HBM; block ``j`` of sequence
     ``b`` is copied from ``(layer, tables[b, j])`` into rows ``(j % gb)
@@ -724,6 +724,17 @@ def _paged_decode_kernel(layer_ref, tables_ref, lens_ref, *refs, scale, bs,
     groups, exactly the prefill kernel's recurrence with the heads as
     its rows.
 
+    ``append``: each pool comes with the step's new rows ``(B, 1,
+    width)`` and goes out again, aliased: a sequence's new row is its
+    token ``ctx - 1``, which lies in its last block. Once that block
+    has come into VMEM with its group, the row is put in its place
+    there (before the group is attended to: the sequence sees its own
+    new token), and the block goes back to ``(layer, tables[b, (ctx -
+    1) // bs])`` by a DMA that runs beside the group's products. The
+    pool takes whole blocks only (its bf16 rows are paired in HBM: one
+    row is not a slice a DMA may take); the block's other rows go back
+    as they came. A sequence with ``ctx == 0`` writes nothing.
+
     ``latent``: ONE pool whose row is a token's latent, shared by every
     query head (:func:`latent_decode_attention`). The queries are their
     own diagonal (``q_ref`` is ``(B, H, width)``, no pattern), one copy
@@ -731,15 +742,16 @@ def _paged_decode_kernel(layer_ref, tables_ref, lens_ref, *refs, scale, bs,
     all of a row's lanes, the weighted sum over the same rows: the
     caller keeps the lanes that are values), and ``o_ref`` takes all
     heads' rows whole."""
-    if latent:
-        q_ref, k_hbm, o_ref, k_buf, sems, a_scr, m_scr, l_scr, acc_scr = refs
-        seg_ref, v_buf, pools = None, k_buf, ((k_hbm, k_buf),)
-        B, group = q_ref.shape[0], 1
-    else:
-        (q_ref, seg_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, a_scr,
-         m_scr, l_scr, acc_scr) = refs
-        pools = ((k_hbm, k_buf), (v_hbm, v_buf))
-        B, group, _ = q_ref.shape
+    n = 1 if latent else 2
+    q_ref, refs = refs[0], refs[1:]
+    seg_ref, refs = (None, refs) if latent else (refs[0], refs[1:])
+    news, refs = (refs[:n], refs[n:]) if append else ((None,) * n, refs)
+    hbm, o_ref, refs = refs[:n], refs[n], refs[n + 1:]
+    outs, refs = (refs[:n], refs[n:]) if append else (hbm, refs)
+    bufs, (sems, a_scr, m_scr, l_scr, acc_scr) = refs[:n], refs[n:]
+    pools = tuple(zip(hbm, bufs, news, outs))
+    k_buf, v_buf = bufs[0], bufs[-1]
+    B, group = q_ref.shape[0], (1 if latent else q_ref.shape[1])
     mb = tables_ref.shape[1]
     rows = gb * bs
     layer = layer_ref[0]
@@ -758,7 +770,7 @@ def _paged_decode_kernel(layer_ref, tables_ref, lens_ref, *refs, scale, bs,
             def _(i=i, blk=blk):
                 src = tables_ref[b, blk]
                 dst = pl.ds(i * bs, bs)
-                for s, (pool, buf) in enumerate(pools):
+                for s, (pool, buf, _, _) in enumerate(pools):
                     copy = pltpu.make_async_copy(
                         pool.at[layer, src], buf.at[slot, dst],
                         sems.at[s, slot])
@@ -766,6 +778,34 @@ def _paged_decode_kernel(layer_ref, tables_ref, lens_ref, *refs, scale, bs,
                         copy.wait()
                     else:
                         copy.start()
+
+    def put_back(b, j, slot, wait):
+        """Put sequence ``b``'s new row in its block in buffer
+        ``slot`` (``j`` is its last group) and start that block's copy
+        back to the pool; or wait for the copy."""
+        last = ctx_of(b) - 1
+        blk = last // bs
+
+        def copies(i):
+            held = pl.ds(i * bs, bs)
+            return [(buf, new, held, pltpu.make_async_copy(
+                buf.at[slot, held], out.at[layer, tables_ref[b, blk]],
+                sems.at[s, 2])) for s, (_, buf, new, out) in enumerate(pools)]
+
+        if wait:  # a wait needs the copy's size and semaphore only
+            for *_, copy in copies(0):
+                copy.wait()
+            return
+        for i in range(gb):
+
+            @pl.when(blk - j * gb == i)
+            def _(i=i):
+                for buf, new, held, copy in copies(i):
+                    block = buf[slot, held, :]
+                    at = lax.broadcasted_iota(jnp.int32, block.shape, 0)
+                    buf[slot, held, :] = jnp.where(at == last % bs, new[b],
+                                                   block)
+                    copy.start()
 
     def next_live(b):
         """The first sequence after ``b`` with a context, or ``B``."""
@@ -776,7 +816,7 @@ def _paged_decode_kernel(layer_ref, tables_ref, lens_ref, *refs, scale, bs,
 
     # rows of a buffer past a context keep what an earlier group left
     # there and weigh 0: they only have to be finite
-    for _, buf in pools:
+    for buf in bufs:
         buf[...] = jnp.zeros_like(buf)
     o_ref[...] = jnp.zeros_like(o_ref)
 
@@ -814,6 +854,8 @@ def _paged_decode_kernel(layer_ref, tables_ref, lens_ref, *refs, scale, bs,
                 fetch(nb, nj, 1 - slot, wait=False)
 
             fetch(b, j, slot, wait=True)
+            if append:
+                pl.when(last)(lambda: put_back(b, j, slot, wait=False))
             s = jax.lax.dot_general(
                 a_scr[...], k_buf[slot], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale  # (H, rows)
@@ -829,6 +871,8 @@ def _paged_decode_kernel(layer_ref, tables_ref, lens_ref, *refs, scale, bs,
                 p.astype(v_buf.dtype), v_buf[slot], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             m_scr[...] = m_new
+            if append:  # before a later group's copy reuses the buffer
+                pl.when(last)(lambda: put_back(b, j, slot, wait=True))
             return 1 - slot
 
         slot = lax.fori_loop(0, groups, attend, slot)
@@ -850,23 +894,72 @@ def _paged_decode_kernel(layer_ref, tables_ref, lens_ref, *refs, scale, bs,
 
 
 def _pallas_paged_decode(q, k_pool, v_pool, tables, lens, scale,
-                         layer=0, interpret=False):
+                         layer=0, rows=None, interpret=False):
     """``k_pool``/``v_pool`` are the WHOLE pool ``(layers, num_blocks,
     block_size, KVH*D)``, handed to the kernel where they lie in HBM;
     ``layer`` rides the scalar-prefetch lane into the source of the
     kernel's copies, so they address the layer's blocks inside the pool
-    and no slice of it is ever made."""
+    and no slice of it is ever made. ``rows``: the step's new K and V
+    rows, each ``(B, KVH*D)``, which the kernel appends; it then
+    returns ``(out, k_pool, v_pool)``, the pools updated in place."""
     _, _, bs, width = k_pool.shape
     gb = _paged_group_blocks(bs, width, k_pool.dtype.itemsize,
                              tables.shape[1])
     return _paged_decode_call(jnp.asarray(layer, jnp.int32).reshape(1),
-                              tables, lens, q, k_pool, v_pool, scale=scale,
-                              gb=gb, interpret=interpret)
+                              tables, lens, q, k_pool, v_pool, rows,
+                              scale=scale, gb=gb, interpret=interpret)
+
+
+def _decode_call(kernel, scalars, operands, pools, rows, out, *, heads, gb,
+                 vmem, interpret, name):
+    """The ``pallas_call`` of both decode kernels: the ``scalars``
+    prefetched, ``operands`` and the step's new ``rows`` (one ``(B,
+    width)`` array a pool, or ``None``) whole in VMEM, the ``pools``
+    where they lie in HBM, ``out`` whole in VMEM, and with rows the
+    pools again, aliased to their inputs; the scratch of a sequence's
+    ``heads`` query rows. Returns ``out`` and the pools it wrote (none
+    without rows). ``vmem`` is the caller's estimate without the
+    rows."""
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    _, _, bs, width = pools[0].shape
+    news = () if rows is None else tuple(
+        r.reshape(r.shape[0], 1, width).astype(p.dtype)
+        for r, p in zip(rows, pools))
+    ins = (*operands, *news)
+    written = pools if news else ()
+    res = pl.pallas_call(
+        functools.partial(kernel, bs=bs, gb=gb, append=bool(news)),
+        out_shape=(out,) + tuple(jax.ShapeDtypeStruct(p.shape, p.dtype)
+                                 for p in written),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(1,),
+            in_specs=[whole] * len(ins) + [in_hbm] * len(pools),
+            out_specs=[whole] + [in_hbm] * len(written),
+            scratch_shapes=[pltpu.VMEM((2, gb * bs, width), p.dtype)
+                            for p in pools] + [
+                pltpu.SemaphoreType.DMA((len(pools), 3)),
+                pltpu.VMEM((heads, width), out.dtype),
+                pltpu.VMEM((heads, 1), jnp.float32),
+                pltpu.VMEM((heads, 1), jnp.float32),
+                pltpu.VMEM((heads, width), jnp.float32)],
+        ),
+        input_output_aliases={len(scalars) + len(ins) + s: 1 + s
+                              for s in range(len(written))},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=int(vmem + (8 << 20) + 4 * sum(
+                r.size * r.dtype.itemsize for r in news))),
+        interpret=interpret,
+        name=name,
+    )(*scalars, *ins, *pools)
+    return res[0], res[1:]
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "gb", "interpret"))
-def _paged_decode_call(layer, tables, lens, q, k_pool, v_pool, *, scale, gb,
-                       interpret):
+def _paged_decode_call(layer, tables, lens, q, k_pool, v_pool, rows, *,
+                       scale, gb, interpret):
     """The kernel's call, a function of its own under ``jit`` with the
     layer as an operand: a decoder's layers then share one trace and
     one lowering of the kernel (traced layer by layer, twelve of them
@@ -888,45 +981,47 @@ def _paged_decode_call(layer, tables, lens, q, k_pool, v_pool, *, scale, gb,
         head == lane_head * group + np.arange(group)[:, None, None],
         q.dtype)                                         # (group, hp, width)
     qr = q.reshape(B, KVH, group, D).swapaxes(1, 2).reshape(B, group, width)
-    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
     # what grows with the shapes: K/V in flight, and q, o and the
     # pattern whole (each twice, as the pipeline holds them); the
     # scratch and the values of a step ride in the margin
     vmem = (4 * gb * bs * width * itemsize
             + (4 * qr.size + 2 * seg.size) * q.dtype.itemsize)
-    out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, scale=scale, bs=bs, gb=gb),
-        out_shape=jax.ShapeDtypeStruct(qr.shape, q.dtype),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(1,),
-            in_specs=[whole, whole, pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=whole,
-            scratch_shapes=[
-                pltpu.VMEM((2, gb * bs, width), k_pool.dtype),
-                pltpu.VMEM((2, gb * bs, width), v_pool.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
-                pltpu.VMEM((hp, width), q.dtype),
-                pltpu.VMEM((hp, 1), jnp.float32),
-                pltpu.VMEM((hp, 1), jnp.float32),
-                pltpu.VMEM((hp, width), jnp.float32),
-            ],
-        ),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=int(vmem + (8 << 20))),
-        interpret=interpret,
-        name="mxtpu_paged_decode",
-    )(layer, tables, lens, qr, seg, k_pool, v_pool)
-    return out.reshape(B, group, KVH, D).swapaxes(1, 2).reshape(B, H, D)
+    out, pools = _decode_call(
+        functools.partial(_paged_decode_kernel, scale=scale),
+        (layer, tables, lens), (qr, seg), (k_pool, v_pool), rows,
+        jax.ShapeDtypeStruct(qr.shape, q.dtype), heads=hp, gb=gb, vmem=vmem,
+        interpret=interpret, name="mxtpu_paged_decode")
+    out = out.reshape(B, group, KVH, D).swapaxes(1, 2).reshape(B, H, D)
+    return (out, *pools) if pools else out
 
 
-def _jnp_paged_decode(q, k_pool, v_pool, tables, lens, scale, layer=0):
-    """CPU path + oracle: materialize each slot's context via the same
-    ``(layer, table)`` gather the kernel's copies perform (one
-    indexing step into the whole pool, no layer's slice in between),
-    then masked softmax."""
+def _jnp_append(pool, layer, tables, lens, row):
+    """The decode kernels' append as the scatter it replaces (the CPU
+    path and the oracle): each sequence's ``row`` at its token ``ctx -
+    1``, through its table; a sequence with ``ctx == 0`` writes
+    nothing (its block index falls off the pool and is dropped)."""
+    _, nb, bs, _ = pool.shape
+    last = jnp.minimum(lens, tables.shape[1] * bs) - 1
+    blk = jnp.take_along_axis(tables, jnp.maximum(last, 0)[:, None] // bs,
+                              axis=1)[:, 0]
+    blk = jnp.where(last >= 0, blk, nb)
+    return pool.at[layer, blk, last % bs].set(
+        row.reshape(blk.shape[0], -1).astype(pool.dtype), mode="drop")
+
+
+def _jnp_paged_decode(q, k_pool, v_pool, tables, lens, scale, layer=0,
+                      rows=None):
+    """CPU path + oracle: ``rows`` (as :func:`_pallas_paged_decode`
+    takes them) scattered in first (:func:`_jnp_append`), then each
+    slot's context materialised via the same ``(layer, table)`` gather
+    the kernel's copies perform (one indexing step into the whole pool,
+    no layer's slice in between), then masked softmax."""
+    if rows is not None:
+        k_pool, v_pool = (_jnp_append(p, layer, tables, lens, r)
+                          for p, r in zip((k_pool, v_pool), rows))
+        out = _jnp_paged_decode(q, k_pool, v_pool, tables, lens, scale,
+                                layer)
+        return out, k_pool, v_pool
     B, H, D = q.shape
     _, _, bs, width = k_pool.shape
     KVH = width // D
@@ -954,7 +1049,7 @@ def _jnp_paged_decode(q, k_pool, v_pool, tables, lens, scale, layer=0):
 
 @register("paged_decode_attention")
 def paged_decode_attention(query, k_pool, v_pool, block_tables,
-                           context_lens, scale=None, layer=None):
+                           context_lens, scale=None, layer=None, new=None):
     """Decode-specialized attention: ``query`` is one new token per
     sequence, ``(B, H, D)``. With ``layer`` (a static int) K/V are the
     WHOLE paged pool as :class:`~mxnet_tpu.serving.PagedKVCache` keeps
@@ -969,6 +1064,17 @@ def paged_decode_attention(query, k_pool, v_pool, block_tables,
     ``context_lens`` ``(B,)`` int32 is how many positions are valid
     (rows past it — padding and the null block — are masked).
 
+    ``new``: the step's K and V rows ``(k, v)``, each ``(B, KVH, D)``
+    or ``(B, KVH*D)``, appended to the pool before they are attended
+    to: each sequence's row becomes its token ``context_lens - 1``,
+    written at ``(layer, block_tables[seq, (ctx - 1) // block_size],
+    (ctx - 1) % block_size)`` (the block must be the sequence's own:
+    copy-on-write comes first); a sequence with ``context_lens == 0``
+    writes nothing, so the null block is never written. The call then
+    returns ``(out, k_pool, v_pool)``, the pools updated in place (a
+    caller that donates them, or carries them through a loop, keeps
+    one copy).
+
     TPU path: one kernel invocation a call. The tables and lengths are
     scalar-prefetched, the pool stays in HBM, and the kernel walks each
     sequence's ``ceil(ctx / block_size)`` live blocks only: a group of
@@ -976,13 +1082,19 @@ def paged_decode_attention(query, k_pool, v_pool, block_tables,
     768 lanes) is copied from ``(layer, tables[seq, j])`` by explicit
     double-buffered DMAs, the next group (of this sequence, or the
     first of the next one with a context) in flight while this one is
-    attended to; an empty slot costs a scalar test. All heads of a
+    attended to; an empty slot costs a scalar test. With ``new`` a
+    sequence's row is put in its last block where that block lies in
+    VMEM, before it is attended to, and the block is copied back beside
+    the products (one 24-KB block a live sequence a pool, where the
+    scatter it replaces rewrote 128 rows into the whole pool whatever
+    the live sequences). All heads of a
     group of blocks are two products: the queries spread to a block
     diagonal ``(H, KVH*D)`` against K's whole rows, and the weights
     against V's; GQA is native (a kv head's ``H/KVH`` query heads are
     rows of the same diagonal block, so each K/V block is fetched
     once). CPU/debug path: the same math via a plain gather (the test
-    oracle).
+    oracle; the rows scattered in first, as the decode step did before
+    the kernel wrote them).
 
     Sequences with ``context_lens == 0`` (empty batch slots) return
     zeros. Grows O(1) per generated token — no T×S score matrix, no
@@ -991,6 +1103,7 @@ def paged_decode_attention(query, k_pool, v_pool, block_tables,
     if scale is None:
         scale = 1.0 / (query.shape[-1] ** 0.5)
     D = query.shape[-1]
+    shape = k_pool.shape
     if layer is None:
         nb, bs = k_pool.shape[:2]
         k_pool = k_pool.reshape(1, nb, bs, -1)
@@ -1008,8 +1121,13 @@ def paged_decode_attention(query, k_pool, v_pool, block_tables,
     tables = block_tables.astype(jnp.int32)
     lens = context_lens.astype(jnp.int32)
     decode = _pallas_paged_decode if _use_pallas(D) else _jnp_paged_decode
-    return decode(query, k_pool, v_pool, tables, lens, float(scale),
-                  layer=int(layer))
+    if new is None:
+        return decode(query, k_pool, v_pool, tables, lens, float(scale),
+                      layer=int(layer))
+    rows = tuple(r.reshape(r.shape[0], -1) for r in new)
+    out, k_pool, v_pool = decode(query, k_pool, v_pool, tables, lens,
+                                 float(scale), layer=int(layer), rows=rows)
+    return out, k_pool.reshape(shape), v_pool.reshape(shape)
 
 
 # ---------------------------------------------------------------------------
@@ -1019,53 +1137,41 @@ def paged_decode_attention(query, k_pool, v_pool, block_tables,
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def _pallas_latent_decode(layer, tables, lens, q, pool, *, scale,
+def _pallas_latent_decode(layer, tables, lens, q, pool, row=None, *, scale,
                           interpret=False):
     """:func:`_paged_decode_kernel` over one pool ``(layers, num_blocks,
     block_size, width)`` with one "KV head" of ``width`` lanes: ``q``
     is ``(B, H, width)``, each head against a token's whole row, and
     the result ``(B, H, width)`` is the weights' sum over the same
     rows. A group of blocks is one ``(gb * bs, width)`` tile in VMEM,
-    fetched once for both products."""
+    fetched once for both products. ``row`` ``(B, width)``: the step's
+    new rows, which the kernel appends; it then returns ``(out,
+    pool)``, the pool updated in place."""
     B, H, width = q.shape
     _, _, bs, _ = pool.shape
     gb = _paged_group_blocks(bs, width, pool.dtype.itemsize, tables.shape[1])
     hp = -(-H // 16) * 16
     qp = jnp.pad(q, ((0, 0), (0, hp - H), (0, 0)))
-    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
     vmem = 2 * gb * bs * width * pool.dtype.itemsize \
         + 4 * qp.size * q.dtype.itemsize
-    out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, scale=scale, bs=bs, gb=gb,
-                          latent=True),
-        out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(1,),
-            in_specs=[whole, pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=whole,
-            scratch_shapes=[
-                pltpu.VMEM((2, gb * bs, width), pool.dtype),
-                pltpu.SemaphoreType.DMA((1, 2)),
-                pltpu.VMEM((hp, width), q.dtype),
-                pltpu.VMEM((hp, 1), jnp.float32),
-                pltpu.VMEM((hp, 1), jnp.float32),
-                pltpu.VMEM((hp, width), jnp.float32),
-            ],
-        ),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=int(vmem + (8 << 20))),
-        interpret=interpret,
-        name="mxtpu_latent_decode",
-    )(layer, tables, lens, qp, pool)
-    return out[:, :H]
+    out, pools = _decode_call(
+        functools.partial(_paged_decode_kernel, scale=scale, latent=True),
+        (layer, tables, lens), (qp,), (pool,),
+        None if row is None else (row,),
+        jax.ShapeDtypeStruct(qp.shape, q.dtype), heads=hp, gb=gb, vmem=vmem,
+        interpret=interpret, name="mxtpu_latent_decode")
+    return (out[:, :H], *pools) if pools else out[:, :H]
 
 
-def _jnp_latent_decode(layer, tables, lens, q, pool, *, scale):
-    """CPU path + oracle: each slot's rows through the ``(layer,
+def _jnp_latent_decode(layer, tables, lens, q, pool, row=None, *, scale):
+    """CPU path + oracle: ``row`` scattered in first
+    (:func:`_jnp_append`), then each slot's rows through the ``(layer,
     table)`` gather, a masked softmax in float32, the weights' sum over
     the rows themselves."""
+    if row is not None:
+        pool = _jnp_append(pool, layer[0], tables, lens, row)
+        return _jnp_latent_decode(layer, tables, lens, q, pool,
+                                  scale=scale), pool
     B, mb = tables.shape
     rows = pool[layer, tables].reshape(B, mb * pool.shape[2], -1)
     rows = rows.astype(jnp.float32)
@@ -1080,7 +1186,7 @@ def _jnp_latent_decode(layer, tables, lens, q, pool, *, scale):
 
 
 def latent_decode_attention(q_lat, q_rope, pool, block_tables, context_lens,
-                            scale, layer=0):
+                            scale, layer=0, new=None):
     """Decode attention in the absorbed form of latent attention (MLA):
     a token keeps ONE row for all heads, ``[c_kv | k_rope]`` (``rank +
     rope`` numbers: the compressed key-value latent after its norm and
@@ -1096,14 +1202,20 @@ def latent_decode_attention(q_lat, q_rope, pool, block_tables, context_lens,
     softmax-weighted sum of the rows' latent parts, which the caller
     expands a head through ``W_uv``. ``layer`` may be traced (a scanned
     stack of layers): it rides the scalar-prefetch lane into the source
-    of the kernel's copies, so the pool is read where it lies.
+    of the kernel's copies, so the pool is read where it lies. ``new``
+    ``(B, lanes)``: the step's rows, appended as
+    :func:`paged_decode_attention` appends them (each sequence's at its
+    token ``context_lens - 1``, none from an empty slot); the call then
+    returns ``(out, pool)``.
 
     TPU path: :func:`paged_decode_attention`'s kernel body with one
     pool (``mxtpu_latent_decode``): live blocks only, 8 blocks a
     double-buffered copy, one ``(128, lanes)`` tile a group that
     serves the scores (all its lanes against the 32 heads' rows) and the
     weighted sum (the same tile; the rotary lanes of the result are
-    dropped here). CPU/debug path: a plain gather (the oracle).
+    dropped here), the new row put in its block in VMEM and the block
+    copied back. CPU/debug path: a scatter and a plain gather (the
+    oracle).
     Sequences with ``context_lens == 0`` return zeros."""
     rank = q_lat.shape[-1]
     pad = pool.shape[-1] - rank - q_rope.shape[-1]
@@ -1119,11 +1231,14 @@ def latent_decode_attention(q_lat, q_rope, pool, block_tables, context_lens,
         axis=-1).astype(pool.dtype)
     # (any row width: the kernel's choice is the backend's alone)
     decode = _pallas_latent_decode if _use_pallas(0) else _jnp_latent_decode
-    out = decode(jnp.asarray(layer, jnp.int32).reshape(1),
+    res = decode(jnp.asarray(layer, jnp.int32).reshape(1),
                  block_tables.astype(jnp.int32),
-                 context_lens.astype(jnp.int32), q, pool,
+                 context_lens.astype(jnp.int32), q, pool, new,
                  scale=float(scale))
-    return out[..., :rank].astype(q_lat.dtype)
+    if new is None:
+        return res[..., :rank].astype(q_lat.dtype)
+    out, pool = res
+    return out[..., :rank].astype(q_lat.dtype), pool
 
 
 @register("flash_attention", aliases=("_contrib_flash_attention",))

@@ -69,7 +69,8 @@ tests pin against each other:
   carried chunk to chunk from the one its slot holds and left there at
   the prompt's real length;
 - :meth:`decode_step_fn`: one token per sequence, attention through
-  :func:`~mxnet_tpu.ops.flash_attention.paged_decode_attention`,
+  :func:`~mxnet_tpu.ops.flash_attention.paged_decode_attention`
+  (which appends the token's K/V to the pool itself),
   retention through :func:`~mxnet_tpu.ops.retention.power_retention_step`,
   latent attention absorbed through
   :func:`~mxnet_tpu.ops.flash_attention.latent_decode_attention`.
@@ -795,20 +796,21 @@ class TransformerDecoderLM:
         int32 ``[routed_pairs, experts_hit, load_max]``, summed over its
         expert layers, of the active slots' tokens.
 
-        Attention layers append each active slot's K/V to the pool and
-        attend through the block table; inactive slots write to the
-        null block and read an empty context. The pool is updated and
-        read in place: each layer scatters its rows at ``[li, blk,
-        off]`` and hands the kernel the WHOLE pool with the layer
-        index, never ``k_pool[li]``. Retention layers step each active
-        slot's state in place and read it in the same pass; the states
-        of inactive slots are neither read nor written. Latent layers
-        write each active slot's row into the one pool and attend in
-        the absorbed form, over the rows as they lie there."""
+        Attention layers hand the decode kernel each slot's new K/V
+        rows with the WHOLE pool and the layer index (never
+        ``k_pool[li]``): the kernel appends an active slot's rows at
+        its position ``pos`` through its block table, attends over the
+        context that ends there, and returns the pools updated in
+        place; an inactive slot has an empty context, writes nothing
+        and reads nothing. Retention layers step each active slot's
+        state in place and read it in the same pass; the states of
+        inactive slots are neither read nor written. Latent layers hand
+        their kernel each slot's row for the one pool the same way and
+        attend in the absorbed form, over the rows as they lie
+        there."""
         from ..ops.flash_attention import (latent_decode_attention,
                                            paged_decode_attention)
         from ..ops.retention import power_retention_step
-        from .kvcache import slot_coords
 
         def step(params, token, pos, *operands):
             import jax.numpy as jnp
@@ -821,21 +823,16 @@ class TransformerDecoderLM:
                           if k in arrays), None)
             if paged:
                 tables = index[paged]
-                blk, off = slot_coords(tables, pos_c,
-                                       arrays[paged][0].shape[2], active)
                 # context includes the token being written THIS step
                 ctx = jnp.where(active, pos_c + 1, 0).astype(jnp.int32)
                 scale = 1.0 / (self.head_dim ** 0.5)
 
             def attend(lyr, h, carry, j):
-                k_pool, v_pool = carry["attention"]
                 q, k, v = self._qkv(lyr, h, pos_c)   # (B, H/KVH, hd)
-                rows = (k.shape[0], -1)  # (B, KVH * hd), the pool's row
-                k_pool = k_pool.at[j, blk, off].set(k.reshape(rows))
-                v_pool = v_pool.at[j, blk, off].set(v.reshape(rows))
-                o = paged_decode_attention(q, k_pool, v_pool, tables, ctx,
-                                           scale=scale, layer=j)
-                return o, {**carry, "attention": (k_pool, v_pool)}
+                o, *pools = paged_decode_attention(
+                    q, *carry["attention"], tables, ctx, scale=scale,
+                    layer=j, new=(k, v))
+                return o, {**carry, "attention": tuple(pools)}
 
             def retain(lyr, h, carry, j):
                 q, k, v = self._qkv(lyr, h, pos_c)
@@ -848,17 +845,15 @@ class TransformerDecoderLM:
                 q_nope, q_rope, c_kv, k_rope = self._latent_parts(
                     lyr, h, pos_c)
                 (pool,) = carry["latent"]
-                pool = pool.at[j, blk, off].set(
-                    self._latent_row(c_kv, k_rope, pool))
                 # absorbed: W_kvb's key half carries the queries into
                 # the latent's space, its value half expands the result
                 w_kvb = lyr["w_kvb"].reshape(
                     self.kv_rank, self.num_heads, self.nope_dim + self.v_dim)
                 q_lat = jnp.einsum("bhn,chn->bhc", q_nope,
                                    w_kvb[..., :self.nope_dim])
-                o_lat = latent_decode_attention(
+                o_lat, pool = latent_decode_attention(
                     q_lat, q_rope, pool, tables, ctx, self._latent_scale,
-                    layer=j)
+                    layer=j, new=self._latent_row(c_kv, k_rope, pool))
                 o = jnp.einsum("bhc,chv->bhv", o_lat,
                                w_kvb[..., self.nope_dim:])
                 return o, {**carry, "latent": (pool,)}

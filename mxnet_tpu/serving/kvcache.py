@@ -626,10 +626,14 @@ def split_index(kinds, rows):
 
 def slot_coords(tables, pos, block_size, active=None):
     """``(block_id, offset)`` pool coordinates for writing each batch
-    slot's token at position ``pos``. ``tables`` is ``(B, max_blocks)``
-    int32, ``pos`` is ``(B,)`` int32. Inactive slots are routed to the
-    null block (id 0) so the compiled step is branch-free in liveness.
-    """
+    slot's token at position ``pos`` with :func:`paged_write`. ``tables``
+    is ``(B, max_blocks)`` int32, ``pos`` is ``(B,)`` int32. Inactive
+    slots are routed to the null block (id 0) so a scatter is
+    branch-free in liveness. The decode step no longer scatters: the
+    decode kernels append each live slot's row at its context's last
+    token themselves and an inactive slot writes nothing
+    (:func:`~mxnet_tpu.ops.flash_attention.paged_decode_attention`), so
+    the null block now takes only prefill's padding."""
     import jax.numpy as jnp
 
     idx = jnp.clip(pos // block_size, 0, tables.shape[1] - 1)
@@ -643,9 +647,10 @@ def slot_coords(tables, pos, block_size, active=None):
 def paged_write(pool_layer, blk, off, values):
     """Scatter one token's K (or V) per batch slot into a single
     layer's pool ``(num_blocks, block_size, ...)``; ``values`` is
-    ``(B, ...)`` with the pool's trailing axes. (The decode step writes
-    the whole pool the same way, one axis up: ``pool.at[layer, blk,
-    off]``.)"""
+    ``(B, ...)`` with the pool's trailing axes: the scatter the decode
+    kernels' append replaced (their CPU path still writes the whole pool
+    so, one axis up: ``pool.at[layer, blk, off]``, an inactive slot's
+    row dropped)."""
     return pool_layer.at[blk, off].set(values)
 
 

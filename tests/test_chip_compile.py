@@ -19,6 +19,7 @@ off around them (such an entry cannot be read back without a chip).
 import functools
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -105,9 +106,25 @@ def test_flash_bwd_split_compiles_for_v5e(on_chip, case):
         names=["mxtpu_flash_bwd_dq", "mxtpu_flash_bwd_dkv"])
 
 
+def _pool_makers(hlo, pool):
+    """The opcodes of the instructions whose result is an array of the
+    pool's shape (``pool`` as ``"bf16[2,3073,16,768]"``): the parameter,
+    the loop's and the calls' plumbing, and whatever else writes a
+    pool."""
+    return {re.search(r" ([a-z][a-z\-]*)\(", line).group(1)
+            for line in hlo.splitlines()
+            if re.match(rf"\s*(ROOT )?%\S+ = {re.escape(pool)}\{{", line)}
+
+
+# what may hand a pool on without writing one of its own
+_POOL_PLUMBING = {"parameter", "get-tuple-element", "tuple", "while",
+                  "bitcast"}
+
+
 # B, H, KVH, D, block_size, layers, num_blocks, max_blocks per sequence:
 # the kernel takes the whole pool where it lies (a token's heads side
 # by side in its row) and copies the live blocks of a static layer
+@pytest.mark.parametrize("append", [False, True], ids=["read", "append"])
 @pytest.mark.parametrize("B,H,KVH,D,bs,L,nb,mb", [
     (32, 12, 12, 64, 16, 2, 4096, 64),    # GPT-2-small heads, 1024 ctx
     (32, 32, 8, 128, 16, 2, 4096, 128),   # GQA 32Q/8KV at head_dim 128
@@ -115,15 +132,31 @@ def test_flash_bwd_split_compiles_for_v5e(on_chip, case):
     (128, 12, 12, 64, 16, 12, 8193, 64),  # the pool an operator reserves
 ], ids=["mha_h12_d64", "gqa_h32_kv8_d128", "serve_chat_pool",
         "serve_chat_pool8193"])
-def test_paged_decode_compiles_for_v5e(on_chip, B, H, KVH, D, bs, L, nb, mb):
+def test_paged_decode_compiles_for_v5e(on_chip, B, H, KVH, D, bs, L, nb, mb,
+                                      append):
+    """With ``append`` the kernel also takes the step's new K/V rows
+    and hands the pools back aliased: donated, they are written where
+    they lie (no temporary, nothing but the kernel makes a pool)."""
     q = on_chip((B, H, D), jnp.bfloat16)
     pool = on_chip((L, nb, bs, KVH * D), jnp.bfloat16)
     tables = on_chip((B, mb), jnp.int32)
     lens = on_chip((B,), jnp.int32)
-    _compiles_to_kernel(
-        lambda q, k, v, t, l: fa._pallas_paged_decode(
-            q, k, v, t, l, D ** -0.5, layer=L - 1),
-        q, pool, pool, tables, lens, names=["mxtpu_paged_decode"])
+    if not append:
+        _compiles_to_kernel(
+            lambda q, k, v, t, l: fa._pallas_paged_decode(
+                q, k, v, t, l, D ** -0.5, layer=L - 1),
+            q, pool, pool, tables, lens, names=["mxtpu_paged_decode"])
+        return
+    rows = on_chip((B, KVH * D), jnp.bfloat16)
+    exe = jax.jit(lambda q, k, v, t, l, kr, vr: fa._pallas_paged_decode(
+        q, k, v, t, l, D ** -0.5, layer=L - 1, rows=(kr, vr)),
+        donate_argnums=(1, 2)).lower(
+            q, pool, pool, tables, lens, rows, rows).compile()
+    hlo = exe.as_text()
+    assert exe.memory_analysis().temp_size_in_bytes < 2 * B * KVH * D * 64
+    assert _pool_makers(hlo, f"bf16[{L},{nb},{bs},{KVH * D}]") \
+        <= _POOL_PLUMBING | {"custom-call"}
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
 
 
 def _gpt2_net(on_chip, layers, vocab):
@@ -166,6 +199,10 @@ def test_decoder_works_on_the_pool_in_place_on_v5e(on_chip, monkeypatch,
     assert exe.memory_analysis().temp_size_in_bytes < 0.25 * pool_bytes
     assert exe.as_text().count(
         'custom_call_target="tpu_custom_call"') == kernels
+    if which == "decode_step_fn":
+        # the kernels write the new rows: no scatter makes a pool
+        assert _pool_makers(exe.as_text(), "bf16[2,3073,16,768]") \
+            <= _POOL_PLUMBING | {"custom-call"}
 
 
 # B slots, H query heads over KVH KV heads of D, layers: the store is
@@ -363,17 +400,29 @@ def test_engine_sorts_the_vocabulary_only_under_a_conditional_on_v5e(
 # products, at JoyAI-LLM-Flash's published widths
 # ---------------------------------------------------------------------------
 
-def test_latent_decode_compiles_for_v5e(on_chip):
+@pytest.mark.parametrize("append", [False, True], ids=["read", "append"])
+def test_latent_decode_compiles_for_v5e(on_chip, append):
     """The serve cell's shapes: 64 slots, 32 query heads against one
     row of 576 numbers in 640 lanes, blocks of 16 tokens, 288 a table,
-    the whole pool of 5 layers x 18,433 blocks, the layer an operand."""
-    _compiles_to_kernel(
-        lambda layer, t, l, q, pool: fa._pallas_latent_decode(
-            layer, t, l, q, pool, scale=192 ** -0.5)[..., :512],
-        on_chip((1,), jnp.int32), on_chip((64, 288), jnp.int32),
-        on_chip((64,), jnp.int32), on_chip((64, 32, 640), jnp.bfloat16),
-        on_chip((5, 18433, 16, 640), jnp.bfloat16),
-        names=["mxtpu_latent_decode"])
+    the whole pool of 5 layers x 18,433 blocks, the layer an operand;
+    with ``append`` the step's rows too, the pool donated and written
+    where it lies."""
+    shapes = (on_chip((1,), jnp.int32), on_chip((64, 288), jnp.int32),
+              on_chip((64,), jnp.int32), on_chip((64, 32, 640), jnp.bfloat16),
+              on_chip((5, 18433, 16, 640), jnp.bfloat16))
+    if not append:
+        _compiles_to_kernel(
+            lambda layer, t, l, q, pool: fa._pallas_latent_decode(
+                layer, t, l, q, pool, scale=192 ** -0.5)[..., :512],
+            *shapes, names=["mxtpu_latent_decode"])
+        return
+    exe = jax.jit(lambda layer, t, l, q, pool, row: fa._pallas_latent_decode(
+        layer, t, l, q, pool, row, scale=192 ** -0.5),
+        donate_argnums=(4,)).lower(
+            *shapes, on_chip((64, 640), jnp.bfloat16)).compile()
+    assert exe.memory_analysis().temp_size_in_bytes < 64 * 640 * 2 * 64
+    assert _pool_makers(exe.as_text(), "bf16[5,18433,16,640]") \
+        <= _POOL_PLUMBING | {"custom-call"}
 
 
 @pytest.mark.parametrize("tokens", [64, 4096], ids=["decode", "prefill"])
@@ -459,3 +508,6 @@ def test_latent_engine_works_on_the_pool_in_place_on_v5e(on_chip,
     assert hlo.count('custom_call_target="tpu_custom_call"') == kernels
     assert [line for line in hlo.splitlines() if "18433,16,640]" in line
             and (" copy(" in line or " transpose(" in line)] == []
+    if which == "chunk_fn":  # the kernels write the new rows
+        assert _pool_makers(hlo, "bf16[2,18433,16,640]") \
+            <= _POOL_PLUMBING | {"custom-call"}

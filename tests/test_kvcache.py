@@ -239,21 +239,164 @@ def test_paged_prefill_write_masks_pad_positions():
     assert pool[[1, 3, 5]].sum() == 0.0
 
 
-def test_null_block_absorbs_inactive_writes():
-    """An inactive slot's write lands in block 0 and paged_gather of a
-    real table never reads it back."""
+def _decode_writer(writer, q, k_pool, v_pool, tables, lens, rows, layer=0):
+    """One decode call that appends ``rows``: through the public call
+    (the gather path on this backend) or the kernel in the Pallas
+    interpreter. Returns ``(out, k_pool, v_pool)``."""
+    from mxnet_tpu.ops import flash_attention as fa
+
+    scale = q.shape[-1] ** -0.5
+    if writer == "gather":
+        return fa.paged_decode_attention(q, k_pool, v_pool, tables, lens,
+                                         scale=scale, layer=layer, new=rows)
+    return fa._pallas_paged_decode(
+        q, k_pool, v_pool, jnp.asarray(tables, jnp.int32),
+        jnp.asarray(lens, jnp.int32), scale, layer=layer, rows=rows,
+        interpret=True)
+
+
+@pytest.mark.parametrize("writer", ["scatter", "gather", "kernel"])
+def test_null_block_absorbs_inactive_writes(writer):
+    """An inactive slot's write: the scatter (``paged_write`` at
+    ``slot_coords``) lands it in block 0, and ``paged_gather`` of a
+    real table never reads it back; the decode call's append (its
+    gather path, and the kernel) writes nothing for it, so the null
+    block stays as it was too."""
     bs, kvh, d = 2, 1, 2
     pool = jnp.zeros((4, bs, kvh, d), jnp.float32)
     tables = np.array([[1], [2]], np.int32)
-    blk, off = slot_coords(tables, np.array([0, 0], np.int32), bs,
-                           active=np.array([True, False]))
     vals = np.full((2, kvh, d), 7.0, np.float32)
-    pool = np.asarray(paged_write(pool, blk, off, vals))
+    if writer == "scatter":
+        blk, off = slot_coords(tables, np.array([0, 0], np.int32), bs,
+                               active=np.array([True, False]))
+        pool = np.asarray(paged_write(pool, blk, off, vals))
+        sink = kvh * d * 7.0                   # absorbed by the sink
+    else:
+        # position 0 written, so the live slot's context is 1 and the
+        # inactive one's 0
+        q = jnp.ones((2, kvh, d), jnp.float32)
+        pool = pool.reshape(1, 4, bs, kvh * d)
+        out, pool, _ = _decode_writer(writer, q, pool, pool, tables,
+                                      np.array([1, 0], np.int32),
+                                      (vals, vals))
+        pool = np.asarray(pool).reshape(4, bs, kvh, d)
+        assert np.array_equal(np.asarray(out)[0], vals[0])  # its own row
+        assert not np.asarray(out)[1].any()
+        sink = 0.0                             # never written
     assert pool[1, 0].sum() == kvh * d * 7.0   # the live slot's write
     assert pool[2].sum() == 0.0                # inactive slot's block clean
-    assert pool[0, 0].sum() == kvh * d * 7.0   # absorbed by the sink
+    assert pool[0, 0].sum() == sink
     got = np.asarray(paged_gather(pool, tables))
     assert got[1].sum() == 0.0  # the sink never leaks into a real read
+
+
+def _append_case(lens, bs, mb, nb, width, dtype, seed):
+    """A pool of random rows, a table a sequence of distinct shuffled
+    blocks (the null block past its use), and a new row a sequence."""
+    rng = np.random.RandomState(seed)
+    pool = np.asarray(rng.randn(2, nb, bs, width), dtype)
+    tables = np.zeros((len(lens), mb), np.int32)
+    free = list(1 + rng.permutation(nb - 1))
+    for b, n in enumerate(lens):
+        tables[b, :-(-n // bs)] = [free.pop() for _ in range(-(-n // bs))]
+    rows = np.asarray(rng.randn(len(lens), width), dtype)
+    return pool, tables, rows, rng
+
+
+def _scattered(pool, layer, tables, lens, rows, bs):
+    """What the decode step's scatter wrote: each live sequence's row at
+    its last token, through its table; nothing for an empty one."""
+    want = np.array(pool)
+    for b, n in enumerate(lens):
+        if n:
+            want[layer, tables[b, (n - 1) // bs], (n - 1) % bs] = rows[b]
+    return want
+
+
+# live and dead slots in turn; a row at offset 0 of a fresh block (17,
+# 33), at a block's last offset (16, 32, 64), the first token (1), in
+# either block of a later group (the group is 2 blocks here: 33, 47, 64)
+_APPEND_LENS = [17, 0, 16, 1, 0, 32, 33, 47, 64]
+
+
+@pytest.mark.parametrize("writer", ["gather", "kernel"])
+@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_decode_append_equals_the_scatter(writer, dtype, monkeypatch):
+    """One decode step through ``paged_decode_attention`` with the new
+    rows: both pools equal the XLA scatter's bit for bit (live rows at
+    their coordinates, every other row untouched, the null block and
+    the dead slots' blocks included), and the output equals the
+    oracle's over the scattered pool, the new row in its context."""
+    from mxnet_tpu.ops import flash_attention as fa
+
+    H, KVH, D, bs, mb = 4, 2, 16, 16, 4
+    width = KVH * D
+    monkeypatch.setattr(fa, "_PAGED_BUFFER_BYTES",
+                        2 * 4 * bs * width * jnp.dtype(dtype).itemsize)
+    assert fa._paged_group_blocks(bs, width, jnp.dtype(dtype).itemsize,
+                                  mb) == 2
+    lens = _APPEND_LENS
+    nb = sum(-(-n // bs) for n in lens) + 3
+    k_pool, tables, k_rows, rng = _append_case(lens, bs, mb, nb, width,
+                                               dtype, 1)
+    v_pool, _, v_rows, _ = _append_case(lens, bs, mb, nb, width, dtype, 2)
+    q = jnp.asarray(rng.randn(len(lens), H, D), dtype)
+    out, k_new, v_new = _decode_writer(
+        writer, q, jnp.asarray(k_pool), jnp.asarray(v_pool), tables,
+        np.asarray(lens, np.int32), (jnp.asarray(k_rows),
+                                     jnp.asarray(v_rows)), layer=1)
+    k_want = _scattered(k_pool, 1, tables, lens, k_rows, bs)
+    v_want = _scattered(v_pool, 1, tables, lens, v_rows, bs)
+    assert np.array_equal(np.asarray(k_new), k_want)
+    assert np.array_equal(np.asarray(v_new), v_want)
+    assert not np.array_equal(k_want, k_pool)  # the rows did land
+    want = fa._jnp_paged_decode(q, jnp.asarray(k_want), jnp.asarray(v_want),
+                                jnp.asarray(tables),
+                                jnp.asarray(lens, jnp.int32), D ** -0.5,
+                                layer=1)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)
+    assert not np.asarray(out, np.float32)[np.asarray(lens) == 0].any()
+
+
+@pytest.mark.parametrize("writer", ["gather", "kernel"])
+def test_latent_append_equals_the_scatter(writer, monkeypatch):
+    """The same for the latent pool (one pool, rows of 640 lanes, the
+    kernel's body with ``latent``): the pool equals the scatter's bit
+    for bit and the output the oracle's over it."""
+    from mxnet_tpu.ops import flash_attention as fa
+
+    bs, mb, width, heads = 16, 4, 640, 8
+    monkeypatch.setattr(fa, "_use_pallas", lambda d: writer == "kernel")
+    monkeypatch.setattr(fa, "_PAGED_BUFFER_BYTES", 2 * 4 * bs * width * 2)
+    assert fa._paged_group_blocks(bs, width, 2, mb) == 2
+    if writer == "kernel":  # the interpreter, steered here
+        real = fa._pallas_latent_decode
+        monkeypatch.setattr(fa, "_pallas_latent_decode",
+                            lambda *a, **k: real(*a, **k, interpret=True))
+    lens = _APPEND_LENS
+    nb = sum(-(-n // bs) for n in lens) + 3
+    pool, tables, rows, rng = _append_case(lens, bs, mb, nb, width,
+                                           jnp.bfloat16, 3)
+    pool[..., 576:] = 0
+    rows[:, 576:] = 0
+    q = jnp.asarray(rng.randn(len(lens), heads, 576) * 0.2, jnp.bfloat16)
+    out, got = fa.latent_decode_attention(
+        q[..., :512], q[..., 512:], jnp.asarray(pool), jnp.asarray(tables),
+        jnp.asarray(lens, jnp.int32), 0.07, layer=1, new=jnp.asarray(rows))
+    want_pool = _scattered(pool, 1, tables, lens, rows, bs)
+    assert np.array_equal(np.asarray(got), want_pool)
+    want = fa._jnp_latent_decode(
+        jnp.asarray([1], jnp.int32), jnp.asarray(tables),
+        jnp.asarray(lens, jnp.int32),
+        jnp.concatenate([q, jnp.zeros((len(lens), heads, 64), q.dtype)], -1),
+        jnp.asarray(want_pool), scale=0.07)[..., :512]
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32), atol=2e-2)
+    assert not np.asarray(out, np.float32)[np.asarray(lens) == 0].any()
 
 
 # ---------------------------------------------------------------------------
@@ -313,14 +456,19 @@ _DECODE_CASES = {
 }
 
 
+@pytest.mark.parametrize("append", [False, True], ids=["read", "append"])
 @pytest.mark.parametrize("case", list(_DECODE_CASES))
-def test_pallas_paged_decode_matches_gather_oracle(case, monkeypatch):
+def test_pallas_paged_decode_matches_gather_oracle(case, append,
+                                                   monkeypatch):
     """The kernel the TPU path takes, run by the Pallas interpreter
     (steered here, not by a program option), against
     ``_jnp_paged_decode`` on ragged contexts: an empty slot, one token,
     a block boundary, a full table and a partial last block; contexts
     on either side of a group's edge; nothing live, one slot live,
-    live and empty slots in turn; tables that end in the null block."""
+    live and empty slots in turn; tables that end in the null block.
+    With ``append`` each sequence's new rows go in at its last token,
+    in whichever group and block of the group that falls, and the
+    pools come back equal to the oracle's scatter bit for bit."""
     from mxnet_tpu.ops import flash_attention as fa
 
     H, KVH, D, bs, mb, dtype, tol, lens, null_tail, gb = _DECODE_CASES[case]
@@ -353,9 +501,16 @@ def test_pallas_paged_decode_matches_gather_oracle(case, monkeypatch):
     # its row: one layer is a leading 1
     k_pool = k_pool.reshape(1, nb, bs, KVH * D)
     v_pool = v_pool.reshape(1, nb, bs, KVH * D)
-    ref = fa._jnp_paged_decode(q, k_pool, v_pool, tables, lens, scale)
+    rows = (tuple(jnp.asarray(rng.randn(B, KVH * D), dtype)
+                  for _ in range(2)) if append else None)
+    ref = fa._jnp_paged_decode(q, k_pool, v_pool, tables, lens, scale,
+                               rows=rows)
     out = fa._pallas_paged_decode(q, k_pool, v_pool, tables, lens, scale,
-                                  interpret=True)
+                                  rows=rows, interpret=True)
+    if append:
+        for got, want in zip(out[1:], ref[1:]):
+            assert np.array_equal(np.asarray(got), np.asarray(want))
+        ref, out = ref[0], out[0]
     assert out.shape == (B, H, D) and out.dtype == q.dtype
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
@@ -415,14 +570,18 @@ def test_whole_pool_prefill_write_equals_layer_by_layer(bucket, length):
         == (bucket <= length)
 
 
+@pytest.mark.parametrize("append", [False, True], ids=["read", "append"])
 @pytest.mark.parametrize("path", ["gather", "kernel"])
 @pytest.mark.parametrize("H,KVH", [(4, 4), (8, 2)],
                          ids=["group1", "group4"])
-def test_whole_pool_decode_equals_one_layer_call(H, KVH, path):
+def test_whole_pool_decode_equals_one_layer_call(H, KVH, path, append):
     """``paged_decode_attention`` on the whole pool with ``layer=li``
     is the one-layer call on that layer's pool, for every layer, with
     and without GQA, through the gather path and through the kernel
-    (Pallas interpreter); an empty slot returns zeros."""
+    (Pallas interpreter); an empty slot returns zeros. With ``append``
+    (the decode step's writer since the kernel writes its rows) the
+    whole pool comes back with layer ``li`` as the one-layer call left
+    its pool and every other layer untouched."""
     from mxnet_tpu.ops import flash_attention as fa
 
     layers, B, D, bs, mb = 3, 4, 16, 8, 3
@@ -434,18 +593,30 @@ def test_whole_pool_decode_equals_one_layer_call(H, KVH, path):
     tables = jnp.asarray(1 + rng.permutation(B * mb).reshape(B, mb),
                          jnp.int32)
     lens = jnp.asarray([0, 1, bs, mb * bs - 3], jnp.int32)
+    rows = (tuple(jnp.asarray(rng.randn(B, KVH, D), jnp.float32)
+                  for _ in range(2)) if append else None)
     for li in range(layers):
         one_layer = fa.paged_decode_attention(
             q, k_pool[li].reshape(nb, bs, KVH, D),
-            v_pool[li].reshape(nb, bs, KVH, D), tables, lens)
+            v_pool[li].reshape(nb, bs, KVH, D), tables, lens, new=rows)
         if path == "gather":
             got = fa.paged_decode_attention(q, k_pool, v_pool, tables,
-                                            lens, layer=li)
+                                            lens, layer=li, new=rows)
+        else:
+            got = fa._pallas_paged_decode(
+                q, k_pool, v_pool, tables, lens, D ** -0.5, layer=li,
+                rows=rows and tuple(r.reshape(B, -1) for r in rows),
+                interpret=True)
+        if append:
+            for whole, pool, one in zip(got[1:], (k_pool, v_pool),
+                                        one_layer[1:]):
+                want = np.array(pool)
+                want[li] = np.asarray(one).reshape(nb, bs, KVH * D)
+                assert np.array_equal(np.asarray(whole), want)
+            got, one_layer = got[0], one_layer[0]
+        if path == "gather":
             assert np.array_equal(np.asarray(got), np.asarray(one_layer))
         else:
-            got = fa._pallas_paged_decode(q, k_pool, v_pool, tables, lens,
-                                          D ** -0.5, layer=li,
-                                          interpret=True)
             np.testing.assert_allclose(np.asarray(got),
                                        np.asarray(one_layer),
                                        rtol=1e-5, atol=1e-5)
@@ -497,8 +668,10 @@ def _pool_shaped_outputs(jaxpr, pool_shape):
 def test_served_functions_never_slice_or_copy_the_pool(which):
     """Structure of what the engine compiles: no equation of the decode
     step or of prefill produces one layer's slice of the pool, and only
-    the scatters (and, in the decode step, nothing else) produce a
-    value of the pool's size. The gather path reads ``pool[layer,
+    the scatters produce a value of the pool's size: prefill's, and in
+    the decode step the gather path's append (on the chip the kernel
+    writes the rows and its aliased call is the only such value:
+    ``tests/test_chip_compile.py``). The gather path reads ``pool[layer,
     tables]`` in one step, so this holds on a CPU as it does for the
     kernel."""
     from mxnet_tpu.serving import TransformerDecoderLM

@@ -7,6 +7,10 @@ and the every-expert sum on the device, then timed: ms a call and GB/s
 of what the algorithm needs (a live token's 1,152 bytes; the three
 matrices of every distinct expert hit), and one grouped product alone by
 the kernel ``mxtpu_experts_gmm`` and by the compiler's ``ragged_dot``.
+The latent kernel also as the decode step calls it, appending each live
+slot's new row: the pool against the oracle's scatter bit for bit, and
+ms a call beside the call that only reads and beside the XLA scatter a
+layer the append replaced.
 
     chiprun -- python -m pytest tests_tpu/test_latent_decode.py -q -s -p no:xdist
 
@@ -58,6 +62,19 @@ def _call_ms(fn, *args, n=40):
     return (time.perf_counter() - t0) / n * 1e3
 
 
+def _donated_ms(fn, pool, *rest, n=40):
+    """``fn(pool, *rest)`` takes the pool donated and returns it first:
+    each call takes the pool the last one returned."""
+    import jax
+
+    res = jax.block_until_ready(fn(pool, *rest))
+    t0 = time.perf_counter()
+    for _ in range(n):
+        res = fn(res[0], *rest)
+    jax.block_until_ready(res)
+    return (time.perf_counter() - t0) / n * 1e3
+
+
 @pytest.mark.parametrize("load", list(LOADS))
 def test_latent_kernel_agrees_with_the_gather_oracle_and_its_time(load):
     import jax
@@ -90,9 +107,48 @@ def test_latent_kernel_agrees_with_the_gather_oracle_and_its_time(load):
 
     ms = _call_ms(jax.jit(step), q_lat, q_rope, pool, tables, lens) / LAYERS
     need = int(np.asarray(lens).sum()) * (RANK + ROPE) * 2
+
+    # the decode step's call: each live slot's new row appended
+    row = jax.random.normal(jax.random.PRNGKey(9), (B, LANES), pool.dtype)
+    row = row.at[:, RANK + ROPE:].set(0)
+    if load != "full":
+        at = jnp.asarray([layer], jnp.int32)
+        want_out, want = jax.jit(lambda *a: fa._jnp_latent_decode(
+            at, *a, scale=scale))(tables, lens, qp, pool, row)
+        got_out, got = jax.jit(lambda *a: fa.latent_decode_attention(
+            *a, scale, layer=layer, new=row))(q_lat, q_rope, pool, tables,
+                                              lens)
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+        np.testing.assert_allclose(
+            np.asarray(got_out, np.float32),
+            np.asarray(want_out, np.float32)[..., :RANK], rtol=2e-2,
+            atol=2e-2)
+        del got, want
+
+    def append_step(pool, q_lat, q_rope, tables, lens, row):
+        out = q_lat
+        for li in range(LAYERS):
+            out, pool = fa.latent_decode_attention(
+                out, q_rope, pool, tables, lens, scale, layer=li, new=row)
+        return pool, out
+
+    def scatter_step(pool, row, blk, off):
+        for li in range(LAYERS):
+            pool = pool.at[li, blk, off].set(row)
+        return (pool,)
+
+    from mxnet_tpu.serving.kvcache import slot_coords
+
+    blk, off = slot_coords(tables, lens - 1, BS, lens > 0)
+    scatter_ms = _donated_ms(jax.jit(scatter_step, donate_argnums=(0,)),
+                             jnp.copy(pool), row, blk, off) / LAYERS
+    append_ms = _donated_ms(jax.jit(append_step, donate_argnums=(0,)),
+                            pool, q_lat, q_rope, tables, lens, row) / LAYERS
     print(json.dumps({
         "load": load, "tokens_live": int(np.asarray(lens).sum()),
-        "latent_decode_call_ms": ms, "needed_bytes": need,
+        "latent_decode_call_ms": ms, "latent_decode_append_call_ms":
+        append_ms, "latent_scatter_ms_a_layer": scatter_ms,
+        "needed_bytes": need,
         "GB_per_s": need / ms / 1e6,
         "share_of_819_GB_per_s": need / ms / 1e6 / 819}))
     assert need / ms / 1e6 < 1.05 * 819

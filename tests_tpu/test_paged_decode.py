@@ -5,7 +5,12 @@ blocks (``gpt2_small.serve_chat``) and about 1,144
 (``gpt2_small.serve_chat_pool8193``), ragged contexts, most slots
 empty. Against the gather oracle on the device, then timed: ms a call,
 GB/s of live K/V, and from the two loads the kernel's fixed cost and
-its cost a live block, which one traced cell cannot tell apart.
+its cost a live block, which one traced cell cannot tell apart. Then
+the same call appending each live slot's new K/V rows (the decode
+step's call): the pools against the oracle's scatter bit for bit, the
+output against the oracle's over them, and ms a call beside the call
+that only reads and beside the two XLA scatters a layer the append
+replaced (``k_pool.at[layer, blk, off].set``, and V's).
 
     chiprun -- python -m pytest tests_tpu/test_paged_decode.py -q -s -p no:xdist
 
@@ -72,6 +77,19 @@ def _call_ms(fn, *args, n=40):
     return (time.perf_counter() - t0) / n * 1e3
 
 
+def _donated_ms(fn, pools, *rest, n=40):
+    """``fn(*pools, *rest)`` takes the pools donated and returns them
+    first: each call takes the pools the last one returned."""
+    import jax
+
+    res = jax.block_until_ready(fn(*pools, *rest))
+    t0 = time.perf_counter()
+    for _ in range(n):
+        res = fn(*res[:len(pools)], *rest)
+    jax.block_until_ready(res)
+    return (time.perf_counter() - t0) / n * 1e3
+
+
 @pytest.fixture(scope="module")
 def readings():
     return {}
@@ -80,6 +98,7 @@ def readings():
 @pytest.mark.parametrize("load", list(LOADS))
 def test_kernel_agrees_with_the_gather_oracle_and_its_time(load, readings):
     import jax
+    import jax.numpy as jnp
 
     from mxnet_tpu.ops import flash_attention as fa
 
@@ -107,9 +126,52 @@ def test_kernel_agrees_with_the_gather_oracle_and_its_time(load, readings):
     ms = _call_ms(jax.jit(step), q, k_pool, v_pool, tables, lens) / LAYERS
     live_bytes = 2 * int(np.asarray(lens).sum()) * H * D * 2
     readings[load] = (blocks_live, ms)
+
+    # the decode step's call: each live slot's new rows appended
+    kr, vr = (jax.random.normal(jax.random.PRNGKey(s), (B, H * D),
+                                k_pool.dtype) for s in (7, 8))
+    if load != "full":  # there sequences share blocks: timed only
+        want, k_want, v_want = jax.jit(lambda *a: fa._jnp_paged_decode(
+            *a[:5], scale, layer=layer, rows=a[5:]))(
+            q, k_pool, v_pool, tables, lens, kr, vr)
+        k_want, v_want = np.asarray(k_want), np.asarray(v_want)
+        got, k_got, v_got = jax.jit(lambda *a: fa._pallas_paged_decode(
+            *a[:5], scale, layer=layer, rows=a[5:]))(
+            q, k_pool, v_pool, tables, lens, kr, vr)
+        assert np.array_equal(np.asarray(k_got), k_want)
+        assert np.array_equal(np.asarray(v_got), v_want)
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=2e-2, atol=2e-2)
+        del k_got, v_got, k_want, v_want
+
+    def append_step(k_pool, v_pool, q, tables, lens, kr, vr):
+        out = q
+        for li in range(LAYERS):
+            out, k_pool, v_pool = fa._pallas_paged_decode(
+                out, k_pool, v_pool, tables, lens, scale, layer=li,
+                rows=(kr, vr))
+        return k_pool, v_pool, out
+
+    def scatter_step(k_pool, v_pool, kr, vr, blk, off):
+        for li in range(LAYERS):
+            k_pool = k_pool.at[li, blk, off].set(kr)
+            v_pool = v_pool.at[li, blk, off].set(vr)
+        return k_pool, v_pool
+
+    from mxnet_tpu.serving.kvcache import slot_coords
+
+    blk, off = slot_coords(tables, lens - 1, BS, lens > 0)
+    pools = (k_pool, v_pool)
+    scatter_ms = _donated_ms(jax.jit(scatter_step, donate_argnums=(0, 1)),
+                             tuple(map(jnp.copy, pools)), kr, vr, blk,
+                             off) / LAYERS
+    append_ms = _donated_ms(jax.jit(append_step, donate_argnums=(0, 1)),
+                            pools, q, tables, lens, kr, vr) / LAYERS
     print(json.dumps({
         "load": load, "slots_live": slots_live, "blocks_live": blocks_live,
-        "paged_decode_call_ms": ms, "live_kv_bytes": live_bytes,
+        "paged_decode_call_ms": ms, "paged_decode_append_call_ms": append_ms,
+        "kv_scatter_ms_a_layer": scatter_ms, "live_kv_bytes": live_bytes,
         "GB_per_s": live_bytes / ms / 1e6,
         "share_of_819_GB_per_s": live_bytes / ms / 1e6 / 819}))
     assert live_bytes / ms / 1e6 < 1.05 * 819
