@@ -163,15 +163,26 @@ class Tracer:
         self._events.append(ev)
         return ev
 
+    @staticmethod
+    def clock() -> float:
+        """The epoch time of ``perf_counter() == 0``, in us: what turns
+        a perf_counter stamp into an event's ``ts``. Events that share
+        one reading (``record(..., clock=)``) keep their stamps' order
+        and gaps exactly; each reading of its own may land a thread
+        switch between its two clock reads."""
+        return time.time_ns() / 1e3 - time.perf_counter() * 1e6
+
     def record(self, name, cat="default", ts=None, dur=0.0, args=None,
-               ph="X", span_id=None):
+               ph="X", span_id=None, clock=None):
         """Append one event. ``ts``/``dur`` are perf_counter seconds
-        (``ts=None`` means now). Every event carries a unique ``id``
-        (pass ``span_id`` to stamp one minted earlier, e.g. before
-        handing it to children as their parent)."""
-        ts_us = time.time_ns() / 1e3
-        if ts is not None:
-            ts_us += (ts - time.perf_counter()) * 1e6
+        (``ts=None`` means now), placed on the epoch by ``clock`` (a
+        :meth:`clock` reading; read anew when not given). Every event
+        carries a unique ``id`` (pass ``span_id`` to stamp one minted
+        earlier, e.g. before handing it to children as their parent)."""
+        if ts is None:
+            ts_us = time.time_ns() / 1e3
+        else:
+            ts_us = (self.clock() if clock is None else clock) + ts * 1e6
         return self._append(
             name, cat, ph,
             int(span_id) if span_id is not None else self.new_span_id(),

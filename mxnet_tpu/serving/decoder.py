@@ -12,8 +12,13 @@ the device runs comes out of :meth:`prefill_fn` / :meth:`decode_step_fn`
 What the configuration chooses, each on its own:
 
 - ``norm``: ``"layernorm"`` (gain and bias) or ``"rmsnorm"`` (gain);
-- ``positions``: ``"learned"`` (a table added to the embedding) or
-  ``"rope"`` (rotary, half-split form, applied to queries and keys);
+- ``positions``: ``"learned"`` (a table added to the embedding),
+  ``"rope"`` (rotary, half-split form, applied to queries and keys) or
+  ``"none"`` (attention sees no position: what order there is comes
+  from the layers around it);
+- ``tie_head``: the logits are the final hidden state times the
+  embedding's transpose (the embedding is the one copy on the device),
+  where a net has a head of its own by default;
 - ``mlp``: ``"gelu"`` (two matrices with biases), ``"swiglu"``
   (``silu(x W_gate) * (x W_up)`` through ``W_down``, no biases) or
   ``"experts"`` (a sigmoid router's top-k of ``experts`` gated MLPs of
@@ -37,43 +42,65 @@ What the configuration chooses, each on its own:
   and the oracle compute the expanded form (keys and values a head from
   the latent), decode the absorbed form over the cached rows
   (:func:`~mxnet_tpu.ops.flash_attention.latent_decode_attention`).
+  ``"mamba"``: Mamba-1's selective state space
+  (:mod:`mxnet_tpu.ops.mamba`): ``[x | z] = h W_in`` (``expand x d``
+  channels each), a causal depthwise convolution of ``d_conv`` taps
+  with a bias and its SiLU over ``x``, ``[dt_r | B | C] = x W_x``
+  (``dt_rank``, ``d_state``, ``d_state``) each through an RMSNorm with
+  a gain, ``dt = softplus(dt_r W_dt + b_dt)``, the recurrence with
+  ``A = -exp(A_log)`` and the skip ``D``, and ``(y * silu(z)) W_out``
+  (``wo``); no biases on the projections. It keeps a fixed state a
+  sequence: the selective state and the convolution's last inputs.
 
-A net whose layers are all retention layers keeps their weights stacked
-on a leading axis and runs them under one ``lax.scan`` (``scan_layers``,
-which follows from ``layer_kinds``): one layer's program whatever the
-depth, which is what keeps the compile of a wide model's prefill
-buckets short. Every other net runs its layers one by one: the
-paged-decode kernel takes its layer as a constant, and an expert
-layer's grouped products take their stacked experts as a whole operand
-(under a scan each step would first copy the layer's experts out of the
-stack: 2.4 GB a layer at the published widths).
+Consecutive layers of a kind that keeps a fixed state (retention,
+mamba) with the same MLP are one run: their weights are stacked on a
+leading axis and the run goes under one ``lax.scan``, one layer's
+program whatever the run's length, which is what keeps the compile of a
+wide model's prefill buckets short. A net that is one such run
+(``scan_layers``) keeps its layers' leaves as one dict; every other
+net keeps a list, a stacked run or a single layer an entry (``layers``
+in the weights follows from ``layer_kinds`` and ``mlp``). The other
+kinds run one by one: the paged-decode kernel takes its layer as a
+constant, and an expert layer's grouped products take their stacked
+experts as a whole operand (under a scan each step would first copy the
+layer's experts out of the stack: 2.4 GB a layer at the published
+widths).
 
 The defaults are the GPT-2 shape the stack started with (learned
 positions, pre-LayerNorm, GELU MLP, grouped-query attention allowed,
 ``kv_heads | num_heads``, a head of its own). :data:`PRESETS` names
-two more at a size for the CPU tests: ``brumby_tiny``, the switches of
+three more at a size for the CPU tests: ``brumby_tiny``, the switches of
 manifestai/Brumby-14B-Base (Qwen3-14B's shapes with every layer a
-retention layer), and ``joyai_tiny``, those of
+retention layer), ``joyai_tiny``, those of
 jdopensource/JoyAI-LLM-Flash (latent layers, the first with a dense
-gated MLP, then expert layers with a shared expert). The published
-widths are the benchmark's ``chipbench/configs/*.json``.
+gated MLP, then expert layers with a shared expert), and
+``jamba_tiny``, those of ai21labs/AI21-Jamba2-3B (Mamba layers around
+an attention layer of one K/V head without positions, gated MLPs, a
+tied head). The published widths are the benchmark's
+``chipbench/configs/*.json``.
 
 The SAME math is exposed three ways, which is what the correctness
 tests pin against each other:
 
 - :meth:`forward_fn`: dense full-context causal forward (the oracle;
-  a retention layer in its attention form, a latent layer expanded);
+  a retention layer in its attention form, a latent layer expanded, a
+  Mamba layer's recurrence a position at a time);
 - :meth:`prefill_fn`: dense over the prompt; every attention layer's
   K/V and every latent layer's rows scattered into the paged pool
   through the request's block table, every retention layer's state
   carried chunk to chunk from the one its slot holds and left there at
-  the prompt's real length;
+  the prompt's real length, every Mamba layer's convolution and
+  selective scan started from its slot's window and state and both
+  left there at the prompt's real length;
 - :meth:`decode_step_fn`: one token per sequence, attention through
   :func:`~mxnet_tpu.ops.flash_attention.paged_decode_attention`
   (which appends the token's K/V to the pool itself),
   retention through :func:`~mxnet_tpu.ops.retention.power_retention_step`,
   latent attention absorbed through
-  :func:`~mxnet_tpu.ops.flash_attention.latent_decode_attention`.
+  :func:`~mxnet_tpu.ops.flash_attention.latent_decode_attention`, a
+  Mamba layer through :func:`~mxnet_tpu.ops.mamba.conv_step` and
+  :func:`~mxnet_tpu.ops.mamba.scan_step` (each slot's window and state
+  stepped in place).
 
 Process replicas rebuild it from the ``{"decoder": {...}}`` spec with
 the same seed, so every replica serves identical weights.
@@ -83,18 +110,25 @@ from __future__ import annotations
 
 import numpy as np
 
-from .kvcache import CACHE_ARRAYS
+from .kvcache import CACHE_ARRAYS, STATE_SHAPES
 
 _EPS = 1e-5
-KINDS = tuple(CACHE_ARRAYS)  # "attention", "retention", "latent"
+KINDS = tuple(CACHE_ARRAYS)  # "attention", "retention", "latent", "mamba"
 MLPS = ("gelu", "swiglu", "experts")
 
 # tokens a chunk of a retention layer's prefill: inside a chunk the
 # attention form, between chunks the state (the program's, not a model's)
 RETENTION_CHUNK = 256
+# positions a chunk of a Mamba layer's prefill scan: inside a chunk the
+# closed form over cumulative decays, between chunks the state
+MAMBA_CHUNK = 16
 # query rows a latent layer's prefill scores at a time, each block
 # against the keys up to its own end: no T x T scores at a long bucket
 LATENT_QUERY_BLOCK = 256
+# an attention layer's prefill over a longer bucket than this scores
+# the same blocks of query rows: 20 heads of 4,096 squared in float32
+# are 1.3 GB
+DENSE_PREFILL_MAX = 512
 
 PRESETS = {
     "brumby_tiny": dict(
@@ -109,6 +143,12 @@ PRESETS = {
         q_rank=48, kv_rank=32, nope_dim=16, rope_dim=8, v_dim=16,
         rope_interleave=True, experts=8, experts_per_token=2, expert_ff=24,
         shared_experts=1, route_scale=2.5),
+    "jamba_tiny": dict(
+        vocab_size=128, num_layers=4, d_model=64, num_heads=4, kv_heads=1,
+        head_dim=16, d_ff=128, max_seq=256, norm="rmsnorm", norm_eps=1e-6,
+        positions="none", mlp="swiglu",
+        layer_kinds=["mamba", "mamba", "attention", "mamba"], expand=2,
+        d_state=16, d_conv=4, dt_rank=8, tie_head=True),
 }
 
 # a published config.json's names for the latent and expert sizes
@@ -119,6 +159,8 @@ _CONFIG_NAMES = {
     "num_experts_per_tok": "experts_per_token",
     "moe_intermediate_size": "expert_ff", "n_shared_experts":
     "shared_experts", "routed_scaling_factor": "route_scale",
+    "mamba_expand": "expand", "mamba_d_state": "d_state",
+    "mamba_d_conv": "d_conv", "mamba_dt_rank": "dt_rank",
 }
 
 
@@ -184,11 +226,12 @@ class TransformerDecoderLM:
     {'latent': {'layers': 2, 'width': 40}}
 
     The latent sizes (``q_rank``, ``kv_rank``, ``nope_dim``,
-    ``rope_dim``, ``v_dim``, ``rope_interleave``) and the expert sizes
+    ``rope_dim``, ``v_dim``, ``rope_interleave``), the expert sizes
     (``experts``, ``experts_per_token``, ``expert_ff``,
-    ``shared_experts``, ``route_scale``) may also be given under a
-    published config's names (``q_lora_rank``, ``n_routed_experts``,
-    ...: :data:`_CONFIG_NAMES`).
+    ``shared_experts``, ``route_scale``) and the Mamba sizes
+    (``expand``, ``d_state``, ``d_conv``, ``dt_rank``) may also be given
+    under a published config's names (``q_lora_rank``,
+    ``n_routed_experts``, ``mamba_d_state``, ...: :data:`_CONFIG_NAMES`).
     """
 
     def __init__(self, vocab_size=64, num_layers=2, d_model=32,
@@ -200,12 +243,14 @@ class TransformerDecoderLM:
                  nope_dim=None, rope_dim=None, v_dim=None,
                  rope_interleave=True, experts=0, experts_per_token=0,
                  expert_ff=None, shared_experts=0, route_scale=1.0,
-                 **config_names):
+                 expand=2, d_state=16, d_conv=4, dt_rank=None,
+                 tie_head=False, **config_names):
         sizes = dict(q_rank=q_rank, kv_rank=kv_rank, nope_dim=nope_dim,
                      rope_dim=rope_dim, v_dim=v_dim, experts=experts,
                      experts_per_token=experts_per_token,
                      expert_ff=expert_ff, shared_experts=shared_experts,
-                     route_scale=route_scale)
+                     route_scale=route_scale, expand=expand,
+                     d_state=d_state, d_conv=d_conv, dt_rank=dt_rank)
         for name, value in config_names.items():
             if name not in _CONFIG_NAMES:
                 raise TypeError(f"unexpected argument {name!r}")
@@ -227,10 +272,11 @@ class TransformerDecoderLM:
         self.head_dim = int(head_dim or self.d_model // self.num_heads)
         for name, value, known in (
                 ("norm", norm, ("layernorm", "rmsnorm")),
-                ("positions", positions, ("learned", "rope"))):
+                ("positions", positions, ("learned", "rope", "none"))):
             if value not in known:
                 raise ValueError(f"{name} is one of {known}; got {value!r}")
         self.norm, self.positions = norm, positions
+        self.tie_head = bool(tie_head)
         # one name for every layer, or a name a layer
         self.mlp = mlp if isinstance(mlp, str) else [str(m) for m in mlp]
         self.layer_mlps = [mlp] * self.num_layers if isinstance(mlp, str) \
@@ -282,10 +328,27 @@ class TransformerDecoderLM:
             raise ValueError(
                 "an expert layer needs experts >= experts_per_token > 0; "
                 f"got {self.experts} and {self.experts_per_token}")
-        # layers of one kind under one scan; the paged-decode kernel
-        # takes its layer as a constant and the grouped products their
-        # layer's experts whole, so retention layers only
-        self.scan_layers = set(self.layer_kinds) == {"retention"}
+        self.expand = int(sizes["expand"])
+        self.d_inner = self.expand * self.d_model
+        self.d_state = int(sizes["d_state"])
+        self.d_conv = int(sizes["d_conv"])
+        self.dt_rank = int(sizes["dt_rank"] or -(-self.d_model // 16))
+        if "mamba" in self._kind_count and self.norm != "rmsnorm":
+            raise ValueError("Mamba layers take RMSNorm")
+        # runs of consecutive layers of a kind that keeps a fixed state
+        # and of one MLP, each under one scan: (kind, mlp, first layer,
+        # layers, stacked). The paged-decode kernel takes its layer as a
+        # constant and the grouped products their layer's experts whole,
+        # so the other kinds are a layer each
+        runs = []
+        for li, (kind, mlp) in enumerate(zip(self.layer_kinds,
+                                             self.layer_mlps)):
+            if runs and runs[-1][4] and runs[-1][:2] == [kind, mlp]:
+                runs[-1][3] += 1
+            else:
+                runs.append([kind, mlp, li, 1, kind in STATE_SHAPES])
+        self._runs = [tuple(r) for r in runs]
+        self.scan_layers = len(self._runs) == 1 and self._runs[0][4]
         self._params = self._init_params()
 
     @classmethod
@@ -331,6 +394,8 @@ class TransformerDecoderLM:
                     kv_a_norm=ones(self.kv_rank),
                     w_kvb=w(self.kv_rank, h * (self.nope_dim + self.v_dim)),
                     wo=w(h * self.v_dim, d))
+            elif kind == "mamba":
+                lyr.update(self._mamba_leaves(rng, w))
             else:
                 lyr.update(wq=w(d, h * hd), wk=w(d, kvh * hd),
                            wv=w(d, kvh * hd), wo=w(h * hd, d))
@@ -362,15 +427,40 @@ class TransformerDecoderLM:
                     lyr.update(ws_gate=w(d, sff), ws_up=w(d, sff),
                                ws_down=w(sff, d))
             layers.append(lyr)
-        if self.scan_layers:
-            layers = {k: jnp.stack([lyr[k] for lyr in layers])
-                      for k in layers[0]}
-        out = {"embed": w(self.vocab_size, d), "layers": layers}
+        runs = [{k: jnp.stack([lyr[k] for lyr in layers[at:at + n]])
+                 for k in layers[at]} if stacked else layers[at]
+                for _, _, at, n, stacked in self._runs]
+        out = {"embed": w(self.vocab_size, d),
+               "layers": runs[0] if self.scan_layers else runs}
         if self.positions == "learned":
             out["pos"] = w(self.max_seq, d)
         out.update(norm_leaves("lnf"))
-        out["head"] = w(d, self.vocab_size)
+        if not self.tie_head:
+            out["head"] = w(d, self.vocab_size)
         return out
+
+    def _mamba_leaves(self, rng, w):
+        """One Mamba layer's weights: the matrices at the net's scale,
+        and Mamba's own initialisation of what sets its memory
+        (``A_log = log(1..d_state)`` a channel, ``D = 1``, ``b_dt`` the
+        inverse softplus of a step drawn log-uniformly in [0.001, 0.1]):
+        a random model then remembers hundreds of tokens."""
+        import jax.numpy as jnp
+
+        d, di, n, r = self.d_model, self.d_inner, self.d_state, self.dt_rank
+        step = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), di))
+
+        def const(a):
+            return jnp.asarray(a, dtype=self.dtype)
+
+        return dict(
+            w_in=w(d, 2 * di), conv_w=w(self.d_conv, di), conv_b=w(di),
+            w_x=w(di, r + 2 * n), dt_norm=const(np.ones(r)),
+            b_norm=const(np.ones(n)), c_norm=const(np.ones(n)),
+            w_dt=w(r, di), b_dt=const(np.log(np.expm1(step))),
+            a_log=const(np.log(np.arange(1, n + 1, dtype=np.float64))[:, None]
+                        * np.ones(di)),
+            d_skip=const(np.ones(di)), wo=w(di, d))
 
     def params(self):
         """The weight pytree (a plain dict — device-resident arrays)."""
@@ -393,12 +483,20 @@ class TransformerDecoderLM:
         ``attention`` layers keep K/V rows that grow with the sequence
         (the paged pools), ``retention`` layers a fixed state a KV
         head, ``latent`` layers one row of ``kv_rank + rope_dim`` a
-        token (one paged pool). :class:`~.kvcache.SequenceCache` is
-        built from this."""
-        return {kind: {"layers": n, "width": self.kv_rank + self.rope_dim}
-                if kind == "latent" else
-                {"layers": n, "kv_heads": self.kv_heads,
-                 "head_dim": self.head_dim}
+        token (one paged pool), ``mamba`` layers a selective state of
+        ``d_state x d_inner`` and ``d_conv - 1`` inputs of the
+        convolution. :class:`~.kvcache.SequenceCache` is built from
+        this."""
+        def geometry(kind, n):
+            if kind == "latent":
+                return {"layers": n, "width": self.kv_rank + self.rope_dim}
+            if kind == "mamba":
+                return {"layers": n, "d_inner": self.d_inner,
+                        "d_state": self.d_state, "d_conv": self.d_conv}
+            return {"layers": n, "kv_heads": self.kv_heads,
+                    "head_dim": self.head_dim}
+
+        return {kind: geometry(kind, n)
                 for kind, n in self._kind_count.items()}
 
     def spec(self) -> dict:
@@ -421,7 +519,9 @@ class TransformerDecoderLM:
             "experts_per_token": self.experts_per_token,
             "expert_ff": self.expert_ff,
             "shared_experts": self.shared_experts,
-            "route_scale": self.route_scale,
+            "route_scale": self.route_scale, "expand": self.expand,
+            "d_state": self.d_state, "d_conv": self.d_conv,
+            "dt_rank": self.dt_rank, "tie_head": self.tie_head,
         }}
 
     # -- shared layer math -------------------------------------------------
@@ -504,6 +604,51 @@ class TransformerDecoderLM:
     @property
     def _latent_scale(self):
         return 1.0 / ((self.nope_dim + self.rope_dim) ** 0.5)
+
+    def _mamba_in(self, lyr, h):
+        """The in-projection of one Mamba layer: ``(x, z)``, each
+        ``(..., d_inner)``."""
+        xz = h @ lyr["w_in"]
+        return xz[..., :self.d_inner], xz[..., self.d_inner:]
+
+    def _mamba_project(self, lyr, xc):
+        """``(dt, B, C)`` of the convolved channels ``xc`` ``(...,
+        d_inner)``, float32: ``[dt_r | B | C] = xc W_x``, each through
+        its RMSNorm, and ``dt = softplus(dt_r W_dt + b_dt)``."""
+        import jax
+        import jax.numpy as jnp
+
+        r, n, eps = self.dt_rank, self.d_state, self.norm_eps
+        proj = xc.astype(lyr["w_x"].dtype) @ lyr["w_x"]
+        dt_r = _rms(proj[..., :r], lyr["dt_norm"], eps)
+        b = _rms(proj[..., r:r + n], lyr["b_norm"], eps)
+        c = _rms(proj[..., r + n:], lyr["c_norm"], eps)
+        dt = jax.nn.softplus((dt_r @ lyr["w_dt"]).astype(jnp.float32)
+                             + lyr["b_dt"].astype(jnp.float32))
+        return dt, b.astype(jnp.float32), c.astype(jnp.float32)
+
+    @staticmethod
+    def _mamba_a(lyr):
+        import jax.numpy as jnp
+
+        return -jnp.exp(lyr["a_log"].astype(jnp.float32))
+
+    @staticmethod
+    def _mamba_out(y, z):
+        """The scan's output gated by ``silu(z)``, in the activations'
+        width, for ``W_out``."""
+        import jax
+        import jax.numpy as jnp
+
+        return (y * jax.nn.silu(z.astype(jnp.float32))).astype(z.dtype)
+
+    def _logits(self, params, h):
+        """The head: its own matrix, or the embedding's transpose."""
+        import jax.numpy as jnp
+
+        if self.tie_head:
+            return jnp.einsum("...d,vd->...v", h, params["embed"])
+        return h @ params["head"]
 
     def _mlp(self, lyr, x, kind, live=None):
         """Layer ``lyr``'s MLP of ``kind`` over ``x`` ``(..., d)``, and
@@ -626,28 +771,31 @@ class TransformerDecoderLM:
             return x + y, carry, load if more is None else load + more
 
         layers = params["layers"]
+        runs = [layers] if isinstance(layers, dict) else layers
         load = jnp.zeros(3, jnp.int32) if self.expert_layers else None
-        if isinstance(layers, dict):
-            kind, mlp = self.layer_kinds[0], self.layer_mlps[0]
+        for (kind, mlp, first, n, stacked), run in zip(self._runs, runs):
+            j = self._kind_index[first]
+            if not stacked:
+                x, carry, load = layer(run, x, carry, load, kind, mlp, j)
+                continue
 
-            def body(c, xs):
+            def body(c, xs, kind=kind, mlp=mlp):
                 lyr, j = xs
                 return layer(lyr, *c, kind, mlp, j), None
 
             (x, carry, load), _ = jax.lax.scan(
                 body, (x, carry, load),
-                (layers, jnp.arange(self.num_layers, dtype=jnp.int32)))
-        else:
-            for li, lyr in enumerate(layers):
-                x, carry, load = layer(
-                    lyr, x, carry, load, self.layer_kinds[li],
-                    self.layer_mlps[li], self._kind_index[li])
+                (run, j + jnp.arange(n, dtype=jnp.int32)))
         return self._norm(x, params, "lnf"), carry, load
 
     def _trunk_dense(self, params, tokens):
         """Dense causal trunk over ``tokens`` (B, T), every layer in
-        its attention form."""
+        its attention form (a Mamba layer's recurrence a position at a
+        time)."""
+        import jax
         import jax.numpy as jnp
+
+        from ..ops import mamba as ssm
 
         b, t = tokens.shape
         pos = jnp.arange(t, dtype=jnp.int32)
@@ -668,19 +816,35 @@ class TransformerDecoderLM:
             return self._dense_attend(q, k, v, mask,
                                       self._latent_scale), carry
 
+        def mamba(lyr, h, carry, j):
+            x, z = self._mamba_in(lyr, h)
+            window = jnp.zeros((self.d_conv - 1, self.d_inner), jnp.float32)
+
+            def one(x):  # a sequence, the recurrence a position at a time
+                pre, _ = ssm.causal_conv(x, window, lyr["conv_w"],
+                                         lyr["conv_b"], t)
+                xc = jax.nn.silu(pre)
+                dt, b, c = self._mamba_project(lyr, xc)
+                return ssm.selective_scan(
+                    xc, dt, self._mamba_a(lyr), b, c, lyr["d_skip"],
+                    jnp.zeros((self.d_state, self.d_inner), jnp.float32),
+                    t, chunk=1)[0]
+
+            return self._mamba_out(jax.vmap(one)(x), z), carry
+
         x = self._embed(params, tokens, pos[None])
         return self._through_layers(
             params, x, None, {"attention": attend, "retention": retain,
-                              "latent": latent})[0]
+                              "latent": latent, "mamba": mamba})[0]
 
     def _split_cache(self, operands):
         """The faces' cache operands, in their fixed order: each kind's
         arrays in :data:`KINDS`' order, as many as
         :data:`~.kvcache.CACHE_ARRAYS` says (the attention layers'
         ``k_pool, v_pool``, the retention layers' ``state, norm``, the
-        latent layers' one pool); after them an index a kind (the block
-        tables, the slots' states). Returns ``({kind: arrays}, {kind:
-        index})``."""
+        latent layers' one pool, the Mamba layers' ``state, window``);
+        after them an index a kind (the block tables, the slots'
+        states). Returns ``({kind: arrays}, {kind: index})``."""
         kinds = [k for k in KINDS if k in self._kind_count]
         counts = [CACHE_ARRAYS[k] for k in kinds]
         if len(operands) != sum(counts) + len(kinds):
@@ -703,8 +867,7 @@ class TransformerDecoderLM:
         logits[B, T, V]`` — what every decode step must reproduce."""
 
         def forward(params, tokens):
-            h = self._trunk_dense(params, tokens)
-            return h @ params["head"]
+            return self._logits(params, self._trunk_dense(params, tokens))
 
         return forward
 
@@ -715,9 +878,10 @@ class TransformerDecoderLM:
         attention layers (``index`` the request's block table ``[1,
         mb]``), ``state, norm`` for one of retention layers (``index``
         the request's state ``[1]``), both pairs and both indices for a
-        mixed one, one ``pool`` for a net of latent layers (``index``
-        the block table). Logits are at the LAST REAL position
-        (``length - 1``).
+        mixed one (attention and Mamba layers alike: ``state, window``
+        after the pools), one ``pool`` for a net of latent layers
+        (``index`` the block table). Logits are at the LAST REAL
+        position (``length - 1``).
 
         Attention layers: dense causal attention, then every layer's
         K/V scattered into the pool in place — one scatter for K and
@@ -728,11 +892,19 @@ class TransformerDecoderLM:
         ``length`` tokens, written back to the slot. Latent layers: the
         expanded form a block of query rows at a time, then every
         layer's rows ``[latent | rotary key]`` scattered into the one
-        pool as K and V are; padding routes to no expert."""
+        pool as K and V are; padding routes to no expert. Mamba layers
+        (``index`` the request's state ``[1]``, the arrays ``state,
+        window``): the convolution from the window the slot holds and
+        the chunked scan from its state, both written back as they
+        stand after ``length`` tokens. A bucket longer than
+        :data:`DENSE_PREFILL_MAX` scores an attention layer a block of
+        query rows at a time."""
+        from ..ops import mamba as ssm
         from ..ops.retention import power_retention_chunked
         from .kvcache import paged_prefill_write_all
 
         def prefill(params, tokens, *operands):
+            import jax
             import jax.numpy as jnp
 
             *operands, length = operands
@@ -747,7 +919,14 @@ class TransformerDecoderLM:
                 # (Tb, KVH * hd): a token's row as the pool keeps it
                 ks.append(k[0].reshape(t, -1))
                 vs.append(v[0].reshape(t, -1))
-                return self._dense_attend(q, k, v, mask), carry
+                if t <= DENSE_PREFILL_MAX:
+                    return self._dense_attend(q, k, v, mask), carry
+                group = self.num_heads // self.kv_heads
+                o = self._blocked_attend(
+                    q[0], jnp.repeat(k[0], group, axis=1),
+                    jnp.repeat(v[0], group, axis=1),
+                    1.0 / (self.head_dim ** 0.5))
+                return o[None], carry
 
             def retain(lyr, h, carry, j):
                 q, k, v = self._qkv(lyr, h, pos)
@@ -767,11 +946,26 @@ class TransformerDecoderLM:
                     *self._latent_expand(lyr, *parts), self._latent_scale)
                 return o[None], carry
 
+            def mamba(lyr, h, carry, j):
+                state, window = carry["mamba"]
+                at = index["mamba"][0]
+                x, z = self._mamba_in(lyr, h[0])
+                pre, w1 = ssm.causal_conv(x, window[j, at], lyr["conv_w"],
+                                          lyr["conv_b"], length[0])
+                xc = jax.nn.silu(pre)
+                dt, b, c = self._mamba_project(lyr, xc)
+                y, s1 = ssm.selective_scan(
+                    xc, dt, self._mamba_a(lyr), b, c, lyr["d_skip"],
+                    state[j, at], length[0], MAMBA_CHUNK)
+                return self._mamba_out(y, z)[None], {**carry, "mamba": (
+                    state.at[j, at].set(s1), window.at[j, at].set(w1))}
+
             x = self._embed(params, tokens, pos[None])
             h, arrays, _ = self._through_layers(
                 params, x, arrays,
                 {"attention": attend, "retention": retain,
-                 "latent": latent}, live=(pos < length[0])[None])
+                 "latent": latent, "mamba": mamba},
+                live=(pos < length[0])[None])
             if ks:  # the paged kind's rows: K and V, or the latent's
                 paged = "attention" if vs else "latent"
                 table = index[paged][0]
@@ -782,7 +976,7 @@ class TransformerDecoderLM:
             last = jnp.clip(length - 1, 0, t - 1)
             h_last = jnp.take_along_axis(
                 h, last[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-            return (h_last @ params["head"],) + self._join_cache(arrays)
+            return (self._logits(params, h_last),) + self._join_cache(arrays)
 
         return prefill
 
@@ -807,7 +1001,11 @@ class TransformerDecoderLM:
         inactive slots are neither read nor written. Latent layers hand
         their kernel each slot's row for the one pool the same way and
         attend in the absorbed form, over the rows as they lie
-        there."""
+        there. Mamba layers step each active slot's window and state in
+        place, in two calls with the projection to ``dt``, ``B`` and
+        ``C`` over the whole batch between them; the others are neither
+        read nor written."""
+        from ..ops import mamba as ssm
         from ..ops.flash_attention import (latent_decode_attention,
                                            paged_decode_attention)
         from ..ops.retention import power_retention_step
@@ -858,10 +1056,24 @@ class TransformerDecoderLM:
                                w_kvb[..., self.nope_dim:])
                 return o, {**carry, "latent": (pool,)}
 
+            def mamba(lyr, h, carry, j):
+                state, window = carry["mamba"]
+                slots = index["mamba"]
+                x, z = self._mamba_in(lyr, h)
+                xc, window = ssm.conv_step(x, window, lyr["conv_w"],
+                                           lyr["conv_b"], slots, active, j)
+                dt, b, c = self._mamba_project(lyr, xc)
+                y, state = ssm.scan_step(xc, dt, self._mamba_a(lyr), b, c,
+                                         lyr["d_skip"], state, slots, active,
+                                         j)
+                return self._mamba_out(y, z), {**carry,
+                                               "mamba": (state, window)}
+
             h, arrays, load = self._through_layers(
                 params, x, arrays, {"attention": attend, "retention": retain,
-                                    "latent": latent}, live=active)
-            out = (h @ params["head"],) + self._join_cache(arrays)
+                                    "latent": latent, "mamba": mamba},
+                live=active)
+            out = (self._logits(params, h),) + self._join_cache(arrays)
             return out + (load,) if with_load else out
 
         return step

@@ -732,16 +732,18 @@ class GenerationEngine:
         not to the profiler. ``req`` is submit -> its last token's
         stamp (now, for one that failed), ``req.queue`` submit ->
         admitted, ``req.prefill`` -> first token, ``req.decode`` -> last
-        token; all four under one ``rid``. ``req.decode`` carries the
-        split of its pace (``_account``): ``tokens`` after the first,
-        ``device_us`` and ``stall_us``."""
+        token; all four under one ``rid`` and placed on the epoch by one
+        clock reading, so that the phases tile the request exactly.
+        ``req.decode`` carries the split of its pace (``_account``):
+        ``tokens`` after the first, ``device_us`` and ``stall_us``."""
         if not _obs.watching():
             return
         ring = _obs.tracer()
+        clock = ring.clock()
         end = req.t_last if outcome == "ok" else time.perf_counter()
         rid = req.rid
         ring.record("req", cat="request", ts=req.t_submit,
-                    dur=end - req.t_submit, span_id=rid,
+                    dur=end - req.t_submit, span_id=rid, clock=clock,
                     args={"rid": rid, "outcome": outcome,
                           "prompt_len": len(req.prompt),
                           "tokens": len(req.tokens)})
@@ -755,7 +757,8 @@ class GenerationEngine:
                 args.update(tokens=len(req.tokens) - 1,
                             device_us=req.device_s * 1e6,
                             stall_us=req.stall_s * 1e6)
-            ring.record(name, cat="request", ts=t0, dur=t1 - t0, args=args)
+            ring.record(name, cat="request", ts=t0, dur=t1 - t0, args=args,
+                        clock=clock)
 
     def _account(self, req):
         """Splits the pace of a request that is leaving, ``t_last -

@@ -43,17 +43,22 @@ copy has come back). Everything device-side (gather/scatter through the
 table) lives in the pure helpers at the bottom so the decode model and
 the tests target the same code.
 
-Three kinds of layer keep something for a live sequence
-(:data:`CACHE_ARRAYS`). ``attention`` layers keep K and V rows: two
-pools of ``kv_heads * head_dim`` lanes. ``latent`` layers (latent
-attention) keep ONE row a token for all heads, the compressed key-value
-latent and the shared rotary key: one pool of that width, in whole lane
-tiles; the same :class:`PagedKVCache`, which is a tuple of pools of a
-stated row width over one allocator. ``retention`` layers (power
-retention) keep a fixed recurrent state a sequence: :class:`StateStore`,
-one state a decode slot, no table, nothing that grows.
-:class:`SequenceCache` is the one manager the generation engine talks
-to; the net's ``cache_spec()`` says which parts it has.
+Four kinds of layer keep something for a live sequence
+(:data:`CACHE_ARRAYS`), of two sorts. Rows that grow with the sequence:
+``attention`` layers keep K and V rows, two pools of ``kv_heads *
+head_dim`` lanes; ``latent`` layers (latent attention) keep ONE row a
+token for all heads, the compressed key-value latent and the shared
+rotary key: one pool of that width, in whole lane tiles; the same
+:class:`PagedKVCache`, which is a tuple of pools of a stated row width
+over one allocator. A state of fixed size (:data:`STATE_SHAPES`):
+``retention`` layers (power retention) keep a recurrent state and its
+normaliser, ``mamba`` layers (Mamba-1's selective state space) a
+selective state and the convolution's last inputs; either is a
+:class:`StateStore`, one state a decode slot, no table, nothing that
+grows. :class:`SequenceCache` is the one manager the generation engine
+talks to; the net's ``cache_spec()`` says which parts it has, at most
+one of each sort (a net of attention and Mamba layers holds a paged pool
+and a state store at once).
 
 Knobs: ``MXTPU_KVCACHE_BLOCKS`` (pool size), ``MXTPU_KVCACHE_BLOCK_SIZE``
 (tokens per block). Gauges: ``mxtpu_kvcache_blocks_used`` /
@@ -73,9 +78,28 @@ from .errors import KVCacheOOM
 
 # device arrays a layer kind keeps for its sequences, in the order the
 # net's faces take the kinds: K and V pools; a state and its
-# normaliser; one pool of latent rows
-CACHE_ARRAYS = {"attention": 2, "retention": 2, "latent": 1}
+# normaliser; one pool of latent rows; a selective state and a window
+CACHE_ARRAYS = {"attention": 2, "retention": 2, "latent": 1, "mamba": 2}
 _LANES = 128  # the TPU tiles an array's minor axis in lanes of 128
+
+
+def _retention_shapes(spec, slots):
+    from ..ops.retention import state_shapes
+
+    return state_shapes(spec["layers"], slots, spec["kv_heads"],
+                        spec["head_dim"])
+
+
+def _mamba_shapes(spec, slots):
+    from ..ops.mamba import state_shapes
+
+    return state_shapes(spec["layers"], slots, spec["d_inner"],
+                        spec["d_state"], spec["d_conv"])
+
+
+# the kinds that keep a fixed state a sequence: the shapes of their
+# store's arrays from the kind's ``cache_spec()`` entry and the slots
+STATE_SHAPES = {"retention": _retention_shapes, "mamba": _mamba_shapes}
 
 
 def kvcache_blocks() -> int:
@@ -313,16 +337,16 @@ class PagedKVCache:
 
 
 class StateStore:
-    """A fixed recurrent state a live sequence, for layers that keep one
-    (power retention: ``S`` and its normaliser ``z``, float32). Two
-    device arrays hold every layer's states, ``(layers, slots + 1,
-    kv_heads, head_dim, P)`` and ``(layers, slots + 1, kv_heads, R,
-    head_dim)`` (:func:`mxnet_tpu.ops.retention.state_shapes`); the
-    last slot is the NULL slot, where steps of slots that are not live
-    are routed. A state never grows and has no table: a sequence holds
-    one slot id from :meth:`allocate` to :meth:`release`. ``allocate``
-    zeroes the slot on the device (in place, the arrays donated), since
-    prefill starts from the state its slot holds.
+    """A fixed state a live sequence, for layers that keep one: power
+    retention's ``S`` and its normaliser ``z``, Mamba's selective state
+    and convolution window (:data:`STATE_SHAPES`), float32. ``shapes``
+    are the device arrays that hold every layer's states, ``(layers,
+    slots + 1, ...)`` each; the last slot is the NULL slot, where steps
+    of slots that are not live are routed. A state never grows and has
+    no table: a sequence holds one slot id from :meth:`allocate` to
+    :meth:`release`. ``allocate`` zeroes the slot in every array on the
+    device (in place, the arrays donated), since prefill starts from the
+    state its slot holds.
 
     The surface is the pool's, with a block read as one sequence's
     state: ``num_blocks`` (slots and the null slot), ``blocks_used()``,
@@ -330,48 +354,40 @@ class StateStore:
 
     _GUARDED_BY = {"_free": "_lock"}
 
-    def __init__(self, layers, kv_heads, head_dim, *, slots, name="model",
-                 gauges=True):
+    def __init__(self, shapes, *, name="model", gauges=True):
         import jax
         import jax.numpy as jnp
-
-        from ..ops.retention import state_shapes
 
         # beside a paged pool the ``mxtpu_kvcache_*`` gauges are the pool's
         self._own_gauges = bool(gauges)
 
-        self.layers = int(layers)
-        self.kv_heads = int(kv_heads)
-        self.head_dim = int(head_dim)
-        self.slots = int(slots)
-        self.num_blocks = self.slots + 1
+        self.layers = int(shapes[0][0])
+        self.num_blocks = int(shapes[0][1])
+        self.slots = self.num_blocks - 1
         self.null = self.slots
         self.name = str(name)
-        s_shape, z_shape = state_shapes(self.layers, self.slots,
-                                        self.kv_heads, self.head_dim)
-        self.state = jnp.zeros(s_shape, jnp.float32)
-        self.norm = jnp.zeros(z_shape, jnp.float32)
-        self.bytes_per_state = (self.state.nbytes + self.norm.nbytes) \
+        self._arrays = tuple(jnp.zeros(shape, jnp.float32)
+                             for shape in shapes)
+        self.bytes_per_state = sum(a.nbytes for a in self._arrays) \
             // self.num_blocks
         self._lock = threading.Lock()
         self._free = list(range(self.slots - 1, -1, -1))  # pop() -> 0
 
-        def zero(state, norm, slot):
-            return (state.at[:, slot].set(0.0), norm.at[:, slot].set(0.0))
+        def zero(arrays, slot):
+            return tuple(a.at[:, slot].set(0.0) for a in arrays)
 
         # compiled here, on the null slot: never inside a served window
-        self._zero = jax.jit(zero, donate_argnums=(0, 1))
+        self._zero = jax.jit(zero, donate_argnums=(0,))
         self._clear(self.null)
 
     def _clear(self, slot):
-        self.state, self.norm = self._zero(
-            self.state, self.norm, np.int32(slot))
+        self._arrays = self._zero(self._arrays, np.int32(slot))
 
     def arrays(self):
-        return self.state, self.norm
+        return self._arrays
 
-    def adopt(self, state, norm):
-        self.state, self.norm = state, norm
+    def adopt(self, *arrays):
+        self._arrays = tuple(arrays)
 
     def allocate(self) -> int:
         """A zeroed slot for a fresh sequence; typed OOM when every
@@ -418,7 +434,7 @@ class StateStore:
 class Sequence:
     """What one live sequence holds of a :class:`SequenceCache`: a
     block table where the net has attention or latent layers, a state's
-    slot where it has retention layers."""
+    slot where it has retention or Mamba layers."""
 
     __slots__ = ("table", "state")
 
@@ -432,8 +448,9 @@ class SequenceCache:
     (a layer kind -> its geometry). ``attention`` layers get a
     :class:`PagedKVCache` of K and V pools that grows block by block;
     ``latent`` layers the same allocator over one pool of their row's
-    ``width`` (in whole lane tiles: 576 -> 640); ``retention`` layers a
-    :class:`StateStore` of one fixed state a decode slot. A sequence is
+    ``width`` (in whole lane tiles: 576 -> 640); ``retention`` or
+    ``mamba`` layers a :class:`StateStore` of one fixed state a decode
+    slot, shaped as :data:`STATE_SHAPES` says. A sequence is
     admitted when every part can hold it, grows only where a part
     grows, and is released from all of them at once.
 
@@ -453,11 +470,14 @@ class SequenceCache:
     def __init__(self, spec, *, slots, max_seq=None, num_blocks=None,
                  block_size=None, dtype="float32", name="model"):
         unknown = set(spec) - set(CACHE_ARRAYS)
-        if unknown or not spec or {"attention", "latent"} <= set(spec):
+        stated = [k for k in spec if k in STATE_SHAPES]
+        if unknown or not spec or {"attention", "latent"} <= set(spec) \
+                or len(stated) > 1:
             raise ValueError(
                 f"a cache spec names layer kinds of {tuple(CACHE_ARRAYS)}, "
                 "'attention' or 'latent' but not both (a sequence has one "
-                f"block table); got {sorted(spec)}")
+                f"block table), and at most one of {tuple(STATE_SHAPES)} "
+                f"(and one state slot); got {sorted(spec)}")
         self.name = str(name)
         self.pool = self.states = None
         paged = dict(max_seq=max_seq, num_blocks=num_blocks,
@@ -471,15 +491,14 @@ class SequenceCache:
             self.pool = PagedKVCache(
                 c["layers"], width=-(-int(c["width"]) // _LANES) * _LANES,
                 arrays=CACHE_ARRAYS["latent"], **paged)
-        if "retention" in spec:
-            r = spec["retention"]
-            self.states = StateStore(r["layers"], r["kv_heads"],
-                                     r["head_dim"], slots=slots, name=name,
-                                     gauges=self.pool is None)
+        for kind in stated:
+            self.states = StateStore(
+                STATE_SHAPES[kind](spec[kind], slots), name=name,
+                gauges=self.pool is None)
         self._main = self.pool if self.pool is not None else self.states
         # the kinds in the faces' order, and the part that serves each
         self.kinds = tuple(kind for kind in CACHE_ARRAYS if kind in spec)
-        self._parts = [{"retention": self.states}.get(kind, self.pool)
+        self._parts = [self.states if kind in STATE_SHAPES else self.pool
                        for kind in self.kinds]
         self.index_width = sum(
             1 if part is self.states else part.max_blocks_per_seq
@@ -609,13 +628,13 @@ def split_index(kinds, rows):
     """Packed index rows ``(B, W)`` (:meth:`SequenceCache.write_row`) as
     the index operands the net's faces take, for a net whose layers are
     of ``kinds``: in :data:`CACHE_ARRAYS`'s order a ``(B,)`` column of
-    state slots for retention layers, the ``(B, max_blocks)`` table for
-    attention or latent layers. ``rows`` is a numpy array or a traced
-    one."""
+    state slots for retention or Mamba layers, the ``(B, max_blocks)``
+    table for attention or latent layers. ``rows`` is a numpy array or a
+    traced one."""
     out, at = (), 0
-    table = rows.shape[1] - ("retention" in kinds)
+    table = rows.shape[1] - sum(kind in STATE_SHAPES for kind in kinds)
     for kind in CACHE_ARRAYS:
-        if kind == "retention" and kind in kinds:
+        if kind in STATE_SHAPES and kind in kinds:
             out += (rows[:, at],)
             at += 1
         elif kind in kinds:

@@ -511,3 +511,111 @@ def test_latent_engine_works_on_the_pool_in_place_on_v5e(on_chip,
     if which == "chunk_fn":  # the kernels write the new rows
         assert _pool_makers(hlo, "bf16[2,18433,16,640]") \
             <= _POOL_PLUMBING | {"custom-call"}
+
+
+# ---------------------------------------------------------------------------
+# Mamba layers beside an attention layer, at AI21-Jamba2-3B's published
+# widths: d_inner 5,120, d_state 16, 4 taps; 20 query heads over one K/V
+# head of 128
+@pytest.mark.parametrize("call", ["conv", "scan"])
+def test_mamba_decode_compiles_for_v5e(on_chip, call):
+    """The decode kernel's two calls over the cell's stores (26 layers,
+    256 slots and the null slot, a layer that may be traced): each is
+    one Mosaic call named ``mxtpu_mamba_decode``, and with the store
+    donated its temporaries (the step's rows as the kernel takes them)
+    are under a hundredth of ONE layer's store, let alone a copy of the
+    store (the device keeps both stores as they are laid out: a window's
+    inputs in whole tiles, the state's rows ``d_state``)."""
+    from mxnet_tpu.ops import mamba as M
+
+    L, B, Di, N, K = 26, 256, 5120, 16, 4
+    s_shape, w_shape = M.state_shapes(L, B, Di, N, K)
+    index = (on_chip((B,), jnp.int32), on_chip((B,), bool),
+             on_chip((), jnp.int32))
+    if call == "conv":
+        fn, donate = M._pallas_conv, (1,)
+        args = (on_chip((B, Di), jnp.bfloat16), on_chip(w_shape, jnp.float32),
+                on_chip((K, Di), jnp.bfloat16), on_chip((Di,), jnp.bfloat16))
+    else:
+        fn, donate = M._pallas_scan, (6,)
+        args = (on_chip((B, Di), jnp.float32), on_chip((B, Di), jnp.float32),
+                on_chip((N, Di), jnp.float32), on_chip((B, N), jnp.float32),
+                on_chip((B, N), jnp.float32), on_chip((Di,), jnp.bfloat16),
+                on_chip(s_shape, jnp.float32))
+    exe = jax.jit(fn, donate_argnums=donate).lower(*args, *index).compile()
+    calls = [line for line in exe.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 1
+    assert calls[0].lstrip().startswith("%mxtpu_mamba_decode")
+    assert exe.memory_analysis().temp_size_in_bytes \
+        < 0.01 * 4 * math.prod(s_shape[1:])
+
+
+def _jamba_net(on_chip, mamba_layers, vocab):
+    """A run of ``mamba_layers`` Mamba layers and an attention layer at
+    AI21-Jamba2-3B's published widths, the parameters as shapes on the
+    chip, held as the benchmark's glue holds them."""
+    import json
+
+    from chipbench.models import jamba_lm as glue
+    from chipbench.references import jamba_lm as ref
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "chipbench", "configs",
+            "jamba2_3b.json")) as f:
+        cfg = {**json.load(f), "num_hidden_layers": mamba_layers + 1,
+               "attn_layer_offset": mamba_layers,
+               "attn_layer_period": mamba_layers + 1, "vocab_size": vocab}
+    shapes = jax.eval_shape(lambda: ref.init_params(cfg, 1, "bfloat16"))
+    params = jax.tree_util.tree_map(lambda a: on_chip(a.shape, a.dtype),
+                                    shapes)
+    return glue.build_net(cfg, params, "bfloat16")
+
+
+@pytest.mark.parametrize("which", ["chunk_fn", "prefill_fn"])
+def test_jamba_engine_works_on_the_stores_in_place_on_v5e(on_chip,
+                                                         monkeypatch, which):
+    """The two programs ``GenerationEngine`` compiles for a Jamba net
+    (four Mamba layers under the scan and one attention layer, a small
+    vocabulary) over the serve cell's 256 slots, a K/V pool of 131,073
+    blocks and the two state stores: with all of them donated the
+    temporaries stay a small share of the state store and nothing of its
+    size is copied; the chunk's kernels are the Mamba kernel's two calls
+    and the paged-decode kernel, and no XLA gather or scatter touches a
+    store in the decode step (the kernels read and write the live slots
+    where they lie)."""
+    from mxnet_tpu.ops import mamba as M
+    from mxnet_tpu.serving import generation
+
+    monkeypatch.setattr(fa, "_use_pallas", lambda d: True)  # no TPU here
+    monkeypatch.setattr(M, "_use_pallas", lambda d: True)
+    layers, slots, mb = 4, 256, 512
+    net = _jamba_net(on_chip, layers, vocab=512)
+    pool = on_chip((1, 131073, 16, 128), jnp.bfloat16)
+    s_shape, w_shape = M.state_shapes(layers, slots, 5120, 16, 4)
+    cache = (pool, pool, on_chip(s_shape, jnp.float32),
+             on_chip(w_shape, jnp.float32))
+    width = mb + 1  # a block table and a state's slot a row
+    chunk_fn, prefill_fn = generation.generation_programs(net, 8)
+    if which == "chunk_fn":
+        args = (net.params(), cache,
+                on_chip((slots, generation._SLOT_COLS + width), jnp.int32),
+                on_chip((2,), jnp.uint32))
+        exe = jax.jit(chunk_fn, donate_argnums=(1,)).lower(*args).compile()
+        share, kernels = 0.05, 3
+    else:
+        args = (net.params(), on_chip((1, 512), jnp.int32), cache,
+                on_chip((1, generation._PREFILL_COLS + width), jnp.int32))
+        exe = jax.jit(prefill_fn, donate_argnums=(2,)).lower(*args).compile()
+        share, kernels = 0.25, 0
+    assert exe.memory_analysis().temp_size_in_bytes \
+        < share * 4 * math.prod(s_shape)
+    hlo = exe.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == kernels
+    for shape in (s_shape, w_shape):
+        dims = ",".join(map(str, shape))
+        assert [line for line in hlo.splitlines() if f"[{dims}]" in line
+                and (" copy(" in line or " transpose(" in line)] == []
+        if which == "chunk_fn":
+            assert _pool_makers(hlo, f"f32[{dims}]") \
+                <= _POOL_PLUMBING | {"custom-call"}

@@ -291,7 +291,7 @@ def test_prefill_then_decode_logits_are_the_reference(tiny, bucket):
     rng = np.random.RandomState(bucket)
     plen, n_out = 13, 9
     prompt = rng.randint(0, 128, plen)
-    store = StateStore(2, 2, 16, slots=2)
+    store = StateStore(R.state_shapes(2, 2, 2, 16))
     slot = store.allocate()
     other = store.allocate()  # a second live row, on another prompt
     prefill = jax.jit(net.prefill_fn())
@@ -414,15 +414,14 @@ def test_layers_are_stacked_where_all_are_retention_layers():
 # ---------------------------------------------------------------------------
 
 def test_the_state_store_zeroes_a_slot_it_hands_out():
-    store = StateStore(2, 2, 16, slots=2, name="s")
+    store = StateStore(R.state_shapes(2, 2, 2, 16), name="s")
     assert store.num_blocks == 3 and store.blocks_used() == 0
     a = store.allocate()
-    store.adopt(store.state.at[:, a].set(7.0), store.norm.at[:, a].set(7.0))
+    store.adopt(*(x.at[:, a].set(7.0) for x in store.arrays()))
     store.release(a)
     b, c = store.allocate(), store.allocate()
     assert a in (b, c)
-    assert not np.asarray(store.state[:, a]).any()
-    assert not np.asarray(store.norm[:, a]).any()
+    assert not any(np.asarray(x[:, a]).any() for x in store.arrays())
     with pytest.raises(KVCacheOOM):
         store.allocate()
     st = store.stats()
