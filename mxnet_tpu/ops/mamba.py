@@ -42,9 +42,23 @@ against the benchmark's sequential reference by ``tests/test_mamba.py``:
   is two calls. On a TPU each is the Pallas kernel
   ``mxtpu_mamba_decode``; on the CPU the same mathematics in
   ``jax.numpy``, which is also the kernel's oracle.
+
+**The decode kernel.** Each call is one invocation that walks the live
+rows only, their count and order (live rows first) taken by scalar
+prefetch. The stores and the rows stay in HBM: a group of live slots
+(as many as :data:`_GROUP_BYTES` holds of what a slot brings in) is
+copied in by explicit DMA, its windows or states from where they lie
+in the store and its rows from the batch, while the group before it is
+computed and the one before that goes back, the store entries to where
+they came from (the store is aliased to the output). A dead row costs
+one zero output row, copied from VMEM, and nothing is read of it or of
+its slot.
 """
 
 from __future__ import annotations
+
+import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -142,18 +156,12 @@ def selective_scan(x, dt, A, B, C, D, state, length, chunk):
 # decode: one token a slot against the stores
 # ---------------------------------------------------------------------------
 
-def _order(slots, active, null):
-    """The live rows first (in slot order) and each grid step's state:
-    a step past the last live one keeps the last live step's state, so
-    nothing of a row that is not live is fetched or written back."""
+def _order(slots, active):
+    """The rows in the order the kernel walks them, the live ones first
+    (in row order), each one's state slot, and how many are live."""
     order = jnp.argsort(jnp.logical_not(active), stable=True).astype(jnp.int32)
     n_live = jnp.sum(active).astype(jnp.int32)
-    slots = slots.astype(jnp.int32)
-    last = jnp.where(n_live > 0, slots[order[jnp.maximum(n_live - 1, 0)]],
-                     null)
-    step = jnp.arange(slots.shape[0], dtype=jnp.int32)
-    at = jnp.where(step < n_live, slots[order], last)
-    return order, at, n_live
+    return order, slots.astype(jnp.int32)[order], n_live
 
 
 def _jnp_conv(x, window, w, b, slots, active, layer):
@@ -188,94 +196,211 @@ def _jnp_scan(xc, dt, A, B, C, D, state, slots, active, layer):
     return jnp.where(active[:, None], y, 0.0), state.at[layer, at].set(s)
 
 
-def _conv_kernel(meta_ref, at_ref, row_ref, x_ref, w_ref, b_ref, win_ref,
-                 y_ref, win_out):
-    """One grid step a live slot (``at_ref`` its window, ``row_ref`` its
-    row of the batch): the step's input joins the window, the
-    convolution and its SiLU are the output, and the window moves on by
-    one. Every ``d_inner`` vector here is a ``(d_inner / 128, 128)``
-    tile, as the store lays out a window's inputs. A step past the last
-    live slot writes a zero row and nothing else."""
-    del at_ref, row_ref  # the index maps' own
-    live = pl.program_id(0) < meta_ref[1]
+# VMEM one DMA group's store entries and rows take coming in (about as
+# much again going out, and both twice over, double-buffered). More
+# slots a group keep more copies in flight: on a v5e at the Jamba
+# cell's widths (d_inner 5,120) and 108 live rows of 256, the two calls
+# took 0.1443 ms at 256 KiB, 0.1339 at 1 MiB and 0.1270 at 4 MiB
+_GROUP_BYTES = 4 << 20
+
+
+def _group_slots(entry_bytes, rows):
+    """Live slots in one DMA group: as many as the group's budget holds
+    of what one slot brings in, at least one and at most the batch."""
+    return int(max(1, min(rows, _GROUP_BYTES // entry_bytes)))
+
+
+def _vmem_bytes(shape):
+    """Bytes of a float32 array in VMEM: its lanes whole."""
+    *lead, lanes = shape
+    return 4 * math.prod(lead) * (-(-lanes // 128) * 128)
+
+
+def _move(copy, wait):
+    if wait:
+        copy.wait()
+    else:
+        copy.start()
+
+
+def _walk_kernel(meta_ref, order_ref, at_ref, *refs, step, n_const, n_in,
+                 group):
+    """One invocation walks the live rows (the first ``meta[1]`` of
+    ``order``), ``group`` of them a DMA group, and nothing else. The
+    store (``(layers, slots + 1, ...)``) and the rows stay in HBM. Row
+    ``order[i]``'s inputs and its slot's entry ``(layer, at[i])`` are
+    copied into position ``i % group`` of an input buffer, ``step``
+    computes the slot's new entry and output row into the output
+    buffers, and those go back by DMA, the entry where it came from.
+    Double-buffered both ways: while one group is computed the next one
+    is coming in and the one before it going out. Each dead row gets a
+    zero output row, copied from VMEM while the live ones are walked;
+    nothing of a dead row or its slot is read."""
+    consts, refs = refs[:n_const], refs[n_const:]
+    rows_in, (store, y_hbm, store_out), refs = \
+        refs[:n_in], refs[n_in:n_in + 3], refs[n_in + 3:]
+    bufs_in, (entry_buf, y_buf, zero, sems) = refs[:n_in + 1], refs[n_in + 1:]
+    out_sem, zero_sem = n_in + 1, n_in + 3     # semaphore rows, one a stream
+    layer, n_live = meta_ref[0], meta_ref[1]
+    groups = pl.cdiv(n_live, group)
+
+    def coming(i, g, slot):
+        row = order_ref[i]
+        return [(store.at[layer, at_ref[i]], bufs_in[0].at[slot, g])] + [
+            (src.at[row], buf.at[slot, g])
+            for src, buf in zip(rows_in, bufs_in[1:])]
+
+    def going(i, g, slot):
+        return [(entry_buf.at[slot, g], store_out.at[layer, at_ref[i]]),
+                (y_buf.at[slot, g], y_hbm.at[order_ref[i]])]
+
+    def each_slot(j, fn):
+        """``fn(i, g)`` for the live rows of group ``j``."""
+        def one(g, carry):
+            fn(j * group + g, g)
+            return carry
+        jax.lax.fori_loop(0, jnp.minimum(group, n_live - j * group), one, 0)
+
+    def copies(pairs, sem, j, slot, wait):
+        def fn(i, g):
+            for s, (src, dst) in enumerate(pairs(i, g, slot)):
+                _move(pltpu.make_async_copy(src, dst, sems.at[sem + s, slot]),
+                      wait)
+        each_slot(j, fn)
+
+    def dead(wait):
+        def one(i, carry):
+            _move(pltpu.make_async_copy(zero, y_hbm.at[order_ref[i]],
+                                        sems.at[zero_sem, 0]), wait)
+            return carry
+        jax.lax.fori_loop(n_live, order_ref.shape[0], one, 0)
+
+    @pl.when(groups > 0)
+    def _prologue():
+        copies(coming, 0, 0, 0, wait=False)
+
+    zero[...] = jnp.zeros_like(zero)
+    dead(wait=False)
+
+    def walk(j, carry):
+        slot = j % 2
+
+        @pl.when(j + 1 < groups)
+        def _prefetch():
+            copies(coming, 0, j + 1, 1 - slot, wait=False)
+
+        copies(coming, 0, j, slot, wait=True)
+
+        @pl.when(j >= 2)  # group j - 2 has left the output buffers
+        def _drained():
+            copies(going, out_sem, j - 2, slot, wait=True)
+
+        each_slot(j, lambda i, g: step(
+            consts, [buf.at[slot, g] for buf in bufs_in],
+            entry_buf.at[slot, g], y_buf.at[slot, g]))
+        copies(going, out_sem, j, slot, wait=False)
+        return carry
+
+    jax.lax.fori_loop(0, groups, walk, 0)
+    for back in (2, 1):
+
+        @pl.when(groups >= back)
+        def _epilogue(back=back):
+            j = groups - back
+            copies(going, out_sem, j, j % 2, wait=True)
+
+    dead(wait=True)
+
+
+def _walk(step, consts, rows_in, store, row, slots, active, layer,
+          interpret):
+    """The ``pallas_call`` of both calls: ``consts`` whole in VMEM,
+    ``rows_in`` ``(B, ...)`` and the WHOLE ``store`` where they lie in
+    HBM, the store aliased to the output; ``row`` the shape of an
+    output row. The layer, the live count and the walk's order ride the
+    scalar-prefetch lane. Returns the output rows and the store."""
+    Bt = slots.shape[0]
+    order, at, n_live = _order(slots, active)
+    meta = jnp.stack([jnp.asarray(layer, jnp.int32), n_live])
+    entry = store.shape[2:]
+    entries_in = [entry] + [r.shape[1:] for r in rows_in]
+    group = _group_slots(sum(_vmem_bytes(e) for e in entries_in), Bt)
+    buffers = [(2, group) + e for e in entries_in + [entry, row]]
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    n_in = len(rows_in)
+    # the buffers and the zero row, the constants whole, and the
+    # margin of the other kernels for a slot's values
+    vmem = sum(_vmem_bytes(b) for b in buffers + [row]) \
+        + sum(_vmem_bytes(c.shape) for c in consts) + (8 << 20)
+    y, store = pl.pallas_call(
+        functools.partial(_walk_kernel, step=step, n_const=len(consts),
+                          n_in=n_in, group=group),
+        out_shape=(jax.ShapeDtypeStruct((Bt,) + row, _F32),
+                   jax.ShapeDtypeStruct(store.shape, store.dtype)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(1,),
+            in_specs=[whole] * len(consts) + [in_hbm] * (n_in + 1),
+            out_specs=[in_hbm, in_hbm],
+            scratch_shapes=[pltpu.VMEM(b, _F32) for b in buffers + [row]]
+            + [pltpu.SemaphoreType.DMA((n_in + 4, 2))]),
+        # operands count the three prefetched scalars first
+        input_output_aliases={3 + len(consts) + n_in: 1},
+        interpret=interpret,
+        name="mxtpu_mamba_decode",
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=int(vmem)),
+    )(meta, order, at, *consts, *rows_in, store)
+    return y, store
+
+
+def _conv_slot(consts, ins, win_out, y_ref):
+    """A live slot's window call: the step's input joins the window,
+    the convolution and its SiLU are the output, and the window moves on
+    by one. Every ``d_inner`` vector here is a ``(d_inner / 128, 128)``
+    tile, as the store lays out a window's inputs."""
+    w_ref, b_ref = consts
+    win, x_ref = ins
     K = w_ref.shape[0]
-
-    @pl.when(jnp.logical_not(live))
-    def _dead():
-        y_ref[...] = jnp.zeros_like(y_ref)
-
-    @pl.when(live)
-    def _step():
-        x = x_ref[0]                                      # (Di/128, 128)
-        acc = b_ref[...] + w_ref[K - 1] * x
-        for k in range(K - 1):
-            acc = acc + w_ref[k] * win_ref[0, 0, k]
-        y_ref[0] = acc * jax.nn.sigmoid(acc)
-        for k in range(K - 2):
-            win_out[0, 0, k] = win_ref[0, 0, k + 1]
-        win_out[0, 0, K - 2] = x
+    x = x_ref[...]                                        # (Di/128, 128)
+    acc = b_ref[...] + w_ref[K - 1] * x
+    for k in range(K - 1):
+        acc = acc + w_ref[k] * win[k]
+    y_ref[...] = acc * jax.nn.sigmoid(acc)
+    for k in range(K - 2):
+        win_out[k] = win[k + 1]
+    win_out[K - 2] = x
 
 
-def _scan_kernel(meta_ref, at_ref, row_ref, xd_ref, bc_ref, a_ref, d_ref,
-                 s_ref, y_ref, s_out):
-    """One grid step a live slot: its ``(N, d_inner)`` state decays by
+def _scan_slot(consts, ins, s_out, y_ref):
+    """A live slot's state call: its ``(N, d_inner)`` state decays by
     ``exp(dt A)``, takes ``dt x`` along its lanes times ``B`` down its
     rows, is stored, and is read by ``C`` before it leaves the
-    registers. ``xd_ref`` holds the slot's ``xc`` and ``dt`` rows,
-    ``bc_ref`` its ``B`` and ``C`` as columns."""
-    del at_ref, row_ref
-    live = pl.program_id(0) < meta_ref[1]
-
-    @pl.when(jnp.logical_not(live))
-    def _dead():
-        y_ref[...] = jnp.zeros_like(y_ref)
-
-    @pl.when(live)
-    def _step():
-        x = xd_ref[0, 0:1, :]                             # (1, Di)
-        dt = xd_ref[0, 1:2, :]
-        b = bc_ref[0, :, 0:1]                             # (N, 1)
-        c = bc_ref[0, :, 1:2]
-        s = jnp.exp(dt * a_ref[...]) * s_ref[0, 0] + (dt * x) * b
-        s_out[0, 0] = s
-        y_ref[0] = jnp.sum(c * s, axis=0, keepdims=True) + d_ref[...] * x
-
-
-def _compiler(vmem):
-    return pltpu.CompilerParams(dimension_semantics=("arbitrary",),
-                                vmem_limit_bytes=int(vmem))
+    registers. ``xd`` holds the slot's ``xc`` and ``dt`` rows, ``bc``
+    its ``B`` and ``C`` as columns."""
+    a_ref, d_ref = consts
+    s_ref, xd, bc = ins
+    x = xd[0:1, :]                                        # (1, Di)
+    dt = xd[1:2, :]
+    b = bc[:, 0:1]                                        # (N, 1)
+    c = bc[:, 1:2]
+    s = jnp.exp(dt * a_ref[...]) * s_ref[...] + (dt * x) * b
+    s_out[...] = s
+    y_ref[...] = jnp.sum(c * s, axis=0, keepdims=True) + d_ref[...] * x
 
 
 def _pallas_conv(x, window, w, b, slots, active, layer, interpret=False):
-    """``window`` is the WHOLE store; the layer and each step's window
-    ride the scalar-prefetch lane into the index maps, and the store is
-    aliased to the output: the kernel rewrites the live slots' windows
-    of this layer where they lie."""
+    """``window`` is the WHOLE store, rewritten in place: the kernel
+    copies the live slots' windows of this layer in and out where they
+    lie."""
     Bt, Di = x.shape
     K = w.shape[0]
     tile = window.shape[3:]                               # (Di/128, 128)
-    order, at, n_live = _order(slots, active, window.shape[1] - 1)
-    meta = jnp.stack([jnp.asarray(layer, jnp.int32), n_live])
-    row = pl.BlockSpec((1,) + tile, lambda i, meta, at, order: (order[i], 0, 0))
-    whole = pl.BlockSpec((K,) + tile, lambda i, meta, at, order: (0, 0, 0))
-    bias = pl.BlockSpec(tile, lambda i, meta, at, order: (0, 0))
-    win = pl.BlockSpec((1, 1, K - 1) + tile,
-                       lambda i, meta, at, order: (meta[0], at[i], 0, 0, 0))
-    xc, window = pl.pallas_call(
-        _conv_kernel,
-        out_shape=(jax.ShapeDtypeStruct((Bt,) + tile, _F32),
-                   jax.ShapeDtypeStruct(window.shape, window.dtype)),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(Bt,),
-            in_specs=[row, whole, bias, win], out_specs=[row, win]),
-        # operands count the three prefetched scalars first
-        input_output_aliases={6: 1},
-        interpret=interpret,
-        name="mxtpu_mamba_decode",
-        compiler_params=_compiler(16 << 20),
-    )(meta, at, order, x.astype(_F32).reshape((Bt,) + tile),
-      w.astype(_F32).reshape((K,) + tile), b.astype(_F32).reshape(tile),
-      window)
+    xc, window = _walk(
+        _conv_slot,
+        [w.astype(_F32).reshape((K,) + tile), b.astype(_F32).reshape(tile)],
+        [x.astype(_F32).reshape((Bt,) + tile)], window, tile, slots,
+        active, layer, interpret)
     return xc.reshape(Bt, Di), window
 
 
@@ -284,39 +409,14 @@ def _pallas_scan(xc, dt, A, B, C, D, state, slots, active, layer,
     """``state`` is the WHOLE store, rewritten in place as
     :func:`_pallas_conv` rewrites the windows."""
     Bt, Di = xc.shape
-    N = A.shape[0]
-    order, at, n_live = _order(slots, active, state.shape[1] - 1)
-    meta = jnp.stack([jnp.asarray(layer, jnp.int32), n_live])
     xd = jnp.stack([xc.astype(_F32), dt.astype(_F32)], axis=1)  # (B, 2, Di)
-    bc = jnp.stack([B.astype(_F32), C.astype(_F32)], axis=2)    # (B, N, 2)
-    s_spec = pl.BlockSpec((1, 1, N, Di),
-                          lambda i, meta, at, order: (meta[0], at[i], 0, 0))
-    y, state = pl.pallas_call(
-        _scan_kernel,
-        out_shape=(jax.ShapeDtypeStruct((Bt, 1, Di), _F32),
-                   jax.ShapeDtypeStruct(state.shape, state.dtype)),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(Bt,),
-            in_specs=[
-                pl.BlockSpec((1, 2, Di),
-                             lambda i, meta, at, order: (order[i], 0, 0)),
-                pl.BlockSpec((1, N, 2),
-                             lambda i, meta, at, order: (order[i], 0, 0)),
-                pl.BlockSpec((N, Di), lambda i, meta, at, order: (0, 0)),
-                pl.BlockSpec((1, Di), lambda i, meta, at, order: (0, 0)),
-                s_spec],
-            out_specs=[
-                pl.BlockSpec((1, 1, Di),
-                             lambda i, meta, at, order: (order[i], 0, 0)),
-                s_spec]),
-        input_output_aliases={7: 1},
-        interpret=interpret,
-        name="mxtpu_mamba_decode",
-        # the state's block in and out, each double-buffered, A beside
-        # them, and the step's (N, d_inner) temporaries
-        compiler_params=_compiler(10 * N * Di * 4 + (8 << 20)),
-    )(meta, at, order, xd, bc, A.astype(_F32), D.astype(_F32).reshape(1, Di),
-      state)
+    # B and C as a slot's first two lanes: a copy takes whole lane tiles
+    bc = jnp.pad(jnp.stack([B.astype(_F32), C.astype(_F32)], axis=2),
+                 ((0, 0), (0, 0), (0, 126)))                    # (B, N, 128)
+    y, state = _walk(
+        _scan_slot,
+        [A.astype(_F32), D.astype(_F32).reshape(1, Di)], [xd, bc], state,
+        (1, Di), slots, active, layer, interpret)
     return y.reshape(Bt, Di), state
 
 

@@ -525,7 +525,10 @@ def test_mamba_decode_compiles_for_v5e(on_chip, call):
     donated its temporaries (the step's rows as the kernel takes them)
     are under a hundredth of ONE layer's store, let alone a copy of the
     store (the device keeps both stores as they are laid out: a window's
-    inputs in whole tiles, the state's rows ``d_state``)."""
+    inputs in whole tiles, the state's rows ``d_state``). The VMEM the
+    kernel's buffers take, two DMA groups of at least two slots'
+    entries coming in and two going out, lies within its
+    ``vmem_limit_bytes``."""
     from mxnet_tpu.ops import mamba as M
 
     L, B, Di, N, K = 26, 256, 5120, 16, 4
@@ -549,6 +552,12 @@ def test_mamba_decode_compiles_for_v5e(on_chip, call):
     assert calls[0].lstrip().startswith("%mxtpu_mamba_decode")
     assert exe.memory_analysis().temp_size_in_bytes \
         < 0.01 * 4 * math.prod(s_shape[1:])
+    limit, used = (int(re.search(
+        rf'"{key}":\[{{"memory_space":"1","offset":"0","size":"(\d+)"',
+        calls[0]).group(1)) for key in ("scoped_memory_configs",
+                                         "used_scoped_memory_configs"))
+    entry = 4 * math.prod((w_shape if call == "conv" else s_shape)[2:])
+    assert 2 * 2 * 2 * entry <= used <= limit
 
 
 def _jamba_net(on_chip, mamba_layers, vocab):

@@ -139,81 +139,125 @@ def test_a_state_carried_between_two_calls_is_one_call(seq):
     np.testing.assert_allclose(s2, whole_s, rtol=1e-5, atol=1e-6)
 
 
+# the decode kernel's walks over a store of six slots: its DMA group
+# held to two slots (at these widths every row would fit one group), and
+# the rows' live set and state slots at the grouping's edges
+ROWS, GROUP = 6, 2
+WALKS = {  # name: (the live rows, each row's state slot)
+    "no_row_live": ([], [2, 0, 1, 5, 3, 4]),
+    "every_row_live": ([0, 1, 2, 3, 4, 5], [2, 0, 1, 5, 3, 4]),
+    "one_whole_group": ([1, 4], [2, 0, 1, 5, 3, 4]),
+    "a_group_and_a_half": ([0, 2, 3], [2, 0, 1, 5, 3, 4]),
+    "rows_against_slot_order": ([0, 1, 3, 5], [5, 4, 3, 2, 1, 0]),
+}
+
+
+def _walk(name):
+    """``(active, slots, the live rows' slots, the other slots of a
+    layer with the null slot)``."""
+    live, at = WALKS[name]
+    active = np.isin(np.arange(ROWS), live)
+    at = np.asarray(at, np.int32)
+    touched = at[active]
+    return active, jnp.asarray(at), touched, [
+        s for s in range(ROWS + 1) if s not in touched]
+
+
+def _steps(step, monkeypatch):
+    """The two calls, the kernel's in the interpreter and compiled once."""
+    if step == "jnp":
+        return M._jnp_conv, M._jnp_scan
+    monkeypatch.setattr(M, "_group_slots", lambda *_: GROUP)
+    return (jax.jit(lambda *a: M._pallas_conv(*a, interpret=True)),
+            jax.jit(lambda *a: M._pallas_scan(*a, interpret=True)))
+
+
+@pytest.mark.parametrize("walk", sorted(WALKS))
 @pytest.mark.parametrize("step", ["jnp", "pallas_interpret"])
-def test_the_decode_step_is_the_prefill_form(seq, step):
-    """Token by token through stores of three slots: two rows live on
-    slots 2 and 1, one row not live. The live rows reproduce the prefill
-    form and leave its window and state; the dead row, the other layer
-    and the unused slot are never touched."""
+def test_the_decode_step_is_the_prefill_form(seq, step, walk, monkeypatch):
+    """Token by token through stores of six slots, every row given the
+    same token: the live rows reproduce the prefill form and leave its
+    window and state in their slots; the dead rows' outputs are zero,
+    and their slots and the other layer are never touched, nor, by the
+    kernel, the null slot."""
     x, dt, A, B, C, D = seq
     rng = np.random.RandomState(5)
     w = jnp.asarray(rng.randn(K, DI) * 0.5, jnp.float32)
     b = jnp.asarray(rng.randn(DI) * 0.1, jnp.float32)
-    conv, scan = (M._jnp_conv, M._jnp_scan) if step == "jnp" else (
-        lambda *a: M._pallas_conv(*a, interpret=True),
-        lambda *a: M._pallas_scan(*a, interpret=True))
-    s_shape, w_shape = M.state_shapes(2, 3, DI, N, K)
+    conv, scan = _steps(step, monkeypatch)
+    active, slots, touched, kept = _walk(walk)
+    if step == "jnp":  # its dead rows read and write the null slot
+        kept = kept[:-1]
+    s_shape, w_shape = M.state_shapes(2, ROWS, DI, N, K)
     state, window = jnp.zeros(s_shape), jnp.zeros(w_shape)
-    slots = jnp.asarray([2, 0, 1], jnp.int32)
-    active = jnp.asarray([True, False, True])
     pre, want_win = M.causal_conv(x, window[1, 0], w, b, T)
     xc = jax.nn.silu(pre)
     want_y, want_s = M.selective_scan(xc, dt, A, B, C, D, _zeros(), T, 4)
+
+    def rows(a):
+        return jnp.stack([a] * ROWS)
+
     ys = []
     for t in range(T):
-        xct, window = conv(jnp.stack([x[t]] * 3), window, w, b, slots,
-                           active, 1)
-        np.testing.assert_allclose(np.asarray(xct)[[0, 2]],
-                                   np.stack([np.asarray(xc[t])] * 2),
-                                   rtol=1e-5, atol=1e-6)
-        assert not np.asarray(xct)[1].any()
-        y, state = scan(xct, jnp.stack([dt[t]] * 3), A,
-                        jnp.stack([B[t]] * 3), jnp.stack([C[t]] * 3), D,
+        xct, window = conv(rows(x[t]), window, w, b, slots, active, 1)
+        np.testing.assert_allclose(
+            np.asarray(xct)[active],
+            np.broadcast_to(np.asarray(xc[t]), (active.sum(), DI)),
+            rtol=1e-5, atol=1e-6)
+        assert not np.asarray(xct)[~active].any()
+        y, state = scan(xct, rows(dt[t]), A, rows(B[t]), rows(C[t]), D,
                         state, slots, active, 1)
         ys.append(np.asarray(y))
     ys = np.stack(ys)
-    for row in (0, 2):
-        np.testing.assert_allclose(ys[:, row], want_y, rtol=1e-4, atol=1e-5)
-    assert not ys[:, 1].any()
-    for slot in (2, 1):
+    np.testing.assert_allclose(
+        ys[:, active],
+        np.broadcast_to(np.asarray(want_y)[:, None], (T, active.sum(), DI)),
+        rtol=1e-4, atol=1e-5)
+    assert not ys[:, ~active].any()
+    for slot in touched:
         np.testing.assert_allclose(state[1, slot], want_s, rtol=1e-4,
                                    atol=1e-5)
         np.testing.assert_array_equal(np.asarray(window[1, slot]),
                                       np.asarray(want_win))
     assert not np.asarray(state[0]).any() and not np.asarray(window[0]).any()
-    assert not np.asarray(state[1, 0]).any()
-    if step != "jnp":  # the kernel never visits the null slot either
-        assert not np.asarray(state[1, 3]).any()
-        assert not np.asarray(window[1, 3]).any()
+    assert not np.asarray(state[1, kept]).any()
+    assert not np.asarray(window[1, kept]).any()
 
 
-def test_dead_slots_are_left_bit_for_bit(seq):
-    """The kernel's two calls with one row live: every other slot of
-    every layer, the null slot too, is what it was, bit for bit; with
-    no row live nothing but the null slot may be written."""
-    x, dt, A, B, C, D = seq
+@pytest.mark.parametrize("walk", sorted(WALKS))
+def test_dead_slots_are_left_bit_for_bit(seq, walk, monkeypatch):
+    """The kernel's two calls once over random stores, each row on its
+    own input: the live rows' outputs, windows and states are the jnp
+    form's; every other slot of every layer, the null slot too, is
+    what it was, bit for bit, and the dead rows' outputs are zero."""
+    _, dt, A, _, _, D = seq
     rng = np.random.RandomState(6)
-    s_shape, w_shape = M.state_shapes(2, 3, DI, N, K)
+    s_shape, w_shape = M.state_shapes(2, ROWS, DI, N, K)
     state = jnp.asarray(rng.randn(*s_shape), jnp.float32)
     window = jnp.asarray(rng.randn(*w_shape), jnp.float32)
     w = jnp.asarray(rng.randn(K, DI), jnp.float32)
     b = jnp.asarray(rng.randn(DI), jnp.float32)
-    slots = jnp.asarray([1, 0, 2], jnp.int32)
-    for active, kept in ((jnp.asarray([False, True, False]), [1, 2, 3]),
-                         (jnp.zeros(3, bool), [0, 1, 2])):
-        xc, win1 = M._pallas_conv(jnp.stack([x[0]] * 3), window, w, b,
-                                  slots, active, 0, interpret=True)
-        y, s1 = M._pallas_scan(xc, jnp.stack([dt[0]] * 3), A,
-                               jnp.stack([B[0]] * 3), jnp.stack([C[0]] * 3),
-                               D, state, slots, active, 0, interpret=True)
-        for new, old in ((win1, window), (s1, state)):
-            np.testing.assert_array_equal(np.asarray(new)[0, kept],
-                                          np.asarray(old)[0, kept])
-            np.testing.assert_array_equal(np.asarray(new)[1],
-                                          np.asarray(old)[1])
-        dead = ~np.asarray(active)
-        assert not np.asarray(xc)[dead].any()
-        assert not np.asarray(y)[dead].any()
+    x = jnp.asarray(rng.randn(ROWS, DI), jnp.float32)
+    B, C = (jnp.asarray(rng.randn(ROWS, N), jnp.float32) for _ in "BC")
+    dts = jnp.asarray(dt[:ROWS])
+    conv, scan = _steps("pallas_interpret", monkeypatch)
+    active, slots, touched, kept = _walk(walk)
+    xc, win1 = conv(x, window, w, b, slots, active, 0)
+    y, s1 = scan(xc, dts, A, B, C, D, state, slots, active, 0)
+    want_xc, want_win = M._jnp_conv(x, window, w, b, slots, active, 0)
+    want_y, want_s = M._jnp_scan(want_xc, dts, A, B, C, D, state, slots,
+                                 active, 0)
+    for got, want in ((xc, want_xc), (y, want_y)):
+        np.testing.assert_allclose(np.asarray(got)[active],
+                                   np.asarray(want)[active], rtol=1e-5,
+                                   atol=1e-5)
+        assert not np.asarray(got)[~active].any()
+    for new, old, want in ((win1, window, want_win), (s1, state, want_s)):
+        new, old, want = map(np.asarray, (new, old, want))
+        np.testing.assert_array_equal(new[0, kept], old[0, kept])
+        np.testing.assert_array_equal(new[1], old[1])
+        np.testing.assert_allclose(new[0, touched], want[0, touched],
+                                   rtol=1e-5, atol=1e-5)
 
 
 def test_the_stores_have_their_layout():
